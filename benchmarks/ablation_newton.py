@@ -18,6 +18,7 @@ from __future__ import annotations
 from benchmarks.common import paper_cfg, realsim_like, save
 from repro.core.async_sgbdt import train_async, worker_round_robin
 from repro.core.sgbdt import train_loss
+from repro.launch.compile_cache import enable_compile_cache
 
 WORKERS = [1, 16, 32]
 
@@ -53,6 +54,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     res = run(quick)
     print("\npaper conclusion 2: Newton (xgboost-style) steps should degrade "
           "more under staleness than gradient steps.")

@@ -15,6 +15,7 @@ import repro.data as D
 from benchmarks.common import paper_cfg, save
 from repro.core.async_sgbdt import train_async, worker_round_robin
 from repro.core.sgbdt import init_state, train_loss
+from repro.launch.compile_cache import enable_compile_cache
 
 WORKERS = [1, 2, 4, 8, 16, 32]
 STEPS = [0.05, 0.1, 0.2, 0.4, 0.8, 1.2, 1.8, 2.5]
@@ -79,6 +80,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     res = run(quick)
     print(f"\nmax stable step: " + "  ".join(
         f"W{w}={res['max_stable_step'][str(w)]:.2f}" for w in res["workers"]

@@ -33,6 +33,7 @@ from repro.core.baselines import (
 from repro.core.simulator import ClusterSpec, simulate_async, simulate_sync
 from repro.core.sgbdt import init_state
 from repro.data.sampling import bernoulli_weights
+from repro.launch.compile_cache import enable_compile_cache
 from repro.ps import clear_trainers
 from repro.ps.worker import build_trees_batched
 from repro.trees.learner import build_tree, build_tree_multi
@@ -103,16 +104,22 @@ def measure_mesh2d_comm(cfg, data, shards: int = 8) -> dict | None:
     code = _MESH2D_CODE.format(
         N=n, F=f, E=sp.max_nnz_row, depth=cfg.learner.depth, shards=shards
     )
+    # The child traces on virtual CPU devices: it must never reach for the
+    # accelerator this process may hold.
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=1400, env={**os.environ, "PYTHONPATH": "src"},
+        timeout=1400,
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
     )
     for line in proc.stdout.splitlines():
         if line.startswith("MESH2D_JSON="):
             out = _json.loads(line.split("=", 1)[1])
             out["shards"] = shards
             return out
-    return None
+    raise RuntimeError(
+        f"2D-mesh accounting child failed (rc={proc.returncode}):\n"
+        f"{proc.stderr[-2000:]}"
+    )
 
 
 def measure_components(cfg, data) -> dict:
@@ -409,6 +416,7 @@ def run(quick: bool = True, objective: str | None = None) -> dict:
 
 
 def main(quick: bool = True, objective: str | None = None):
+    enable_compile_cache()
     res = run(quick, objective=objective)
     print("\npaper targets @32: async 14-20x, LightGBM 5-7x, DimBoost 4-6x")
     return res

@@ -12,6 +12,7 @@ import numpy as np
 from benchmarks.common import higgs_like, paper_cfg, realsim_like, save
 from repro.core.async_sgbdt import train_async, worker_round_robin
 from repro.core.sgbdt import train_loss
+from repro.launch.compile_cache import enable_compile_cache
 
 WORKERS = [1, 4, 8, 16, 32]
 
@@ -48,6 +49,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     res = run(quick)
     s = res["sensitivity"]
     print("\nsensitivity to workers (mean loss gap vs W=1; paper: higgs >> realsim)")

@@ -18,6 +18,7 @@ from repro.core.sgbdt import SGBDTConfig, train_loss
 from repro.trees import forest_predict
 from repro.trees.learner import LearnerConfig
 from repro.trees.losses import logistic_loss
+from repro.launch.compile_cache import enable_compile_cache
 
 WORKERS = [1, 16, 32]
 
@@ -51,6 +52,7 @@ def run(quick: bool = False) -> dict:
 
 
 def main(quick: bool = False):
+    enable_compile_cache()
     res = run(quick)
     print("\npaper C1: realsim test loss flat in W; higgs test loss rises "
           "monotonically with W.")

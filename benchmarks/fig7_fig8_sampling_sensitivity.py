@@ -10,6 +10,7 @@ from benchmarks.common import higgs_like, paper_cfg, realsim_like, save
 from repro.core.async_sgbdt import train_async, worker_round_robin
 from repro.core.sgbdt import train_loss
 from repro.data.sampling import diversity_stats
+from repro.launch.compile_cache import enable_compile_cache
 
 RATES = [0.2, 0.4, 0.6, 0.8]
 W = 16
@@ -52,6 +53,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     res = run(quick)
     print("\nasync gap should grow with sampling rate (conclusion 3),")
     print("and be larger on the low-diversity (higgs) dataset (conclusion 5).")

@@ -7,6 +7,7 @@ from __future__ import annotations
 from benchmarks.common import paper_cfg, realsim_like, save
 from repro.core.async_sgbdt import train_async, worker_round_robin
 from repro.core.sgbdt import train_loss
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run(quick: bool = True) -> dict:
@@ -33,6 +34,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     res = run(quick)
     c = res["curves"]
     keys = sorted(c)

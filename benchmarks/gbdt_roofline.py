@@ -19,6 +19,7 @@ import textwrap
 
 from benchmarks.common import save
 from benchmarks.roofline_common import roofline_terms
+from repro.launch.compile_cache import enable_compile_cache
 
 _CODE = textwrap.dedent(
     """
@@ -36,9 +37,9 @@ _CODE = textwrap.dedent(
     from repro.trees.binning import BinnedData
     from repro.trees.learner import LearnerConfig
     from repro.launch.hlo_analysis import analyze_hlo
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_mesh, make_production_mesh
 
-    mesh = jax.make_mesh(({mesh_shape}), ("data", "model"))
+    mesh = make_mesh(({mesh_shape}), ("data", "model"))
     N, F, T = {N}, {F}, {T}
     cfg = SGBDTConfig(
         n_trees=T, step_length=0.1, sampling_rate=0.8,
@@ -88,21 +89,32 @@ _CODE = textwrap.dedent(
 )
 
 
-def _run_mode(shape: dict, hist_mode: str) -> dict:
+def _run_child(code: str, marker: str) -> dict:
+    """Run an accounting child and return the JSON it prints after
+    ``marker``. The child traces on virtual CPU devices, so it never
+    reaches for an accelerator this process may hold; a child that fails
+    raises here."""
     proc = subprocess.run(
-        [sys.executable, "-c", _CODE.format(hist_mode=hist_mode, **shape)],
-        capture_output=True, text=True, timeout=1400,
-        env={**os.environ, "PYTHONPATH": "src"},
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=1400,
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
     )
     for line in proc.stdout.splitlines():
-        if line.startswith("GBDT_ROOFLINE_JSON="):
-            payload = json.loads(line.split("=", 1)[1])
-            payload.update(roofline_terms(
-                payload["dot_flops"], payload["hbm_bytes"],
-                payload["collective_bytes"],
-            ))
-            return payload
-    return {"error": proc.stderr[-800:]}
+        if line.startswith(marker):
+            return json.loads(line.split("=", 1)[1])
+    raise RuntimeError(
+        f"accounting child failed (rc={proc.returncode}):\n{proc.stderr[-2000:]}"
+    )
+
+
+def _run_mode(shape: dict, hist_mode: str) -> dict:
+    payload = _run_child(
+        _CODE.format(hist_mode=hist_mode, **shape), "GBDT_ROOFLINE_JSON="
+    )
+    payload.update(roofline_terms(
+        payload["dot_flops"], payload["hbm_bytes"], payload["collective_bytes"],
+    ))
+    return payload
 
 
 def run(quick: bool = True) -> dict:
@@ -119,26 +131,19 @@ def run(quick: bool = True) -> dict:
     payload = dict(modes["subtract"])
     payload["hist_modes"] = modes
     sub, reb = modes["subtract"], modes["rebuild"]
-    if "error" not in sub and "error" not in reb:
-        payload["hist_subtract_hbm_ratio"] = (
-            sub["hbm_bytes"] / max(reb["hbm_bytes"], 1)
-        )
-        payload["hist_subtract_collective_ratio"] = (
-            sub["collective_bytes"] / max(reb["collective_bytes"], 1)
-        )
-        save("gbdt_roofline", payload)
-        print(f"  GBDT sharded-histogram step on {shape['mesh_shape']} "
-              f"(hist_mode=subtract): "
-              f"compute {sub['compute_s']:.3e}s "
-              f"memory {sub['memory_s']:.3e}s "
-              f"collective {sub['collective_s']:.3e}s "
-              f"-> {sub['dominant']}-bound")
-        print(f"  vs rebuild: hbm x{payload['hist_subtract_hbm_ratio']:.3f} "
-              f"collective x{payload['hist_subtract_collective_ratio']:.3f}")
-        return payload
-    err = sub.get("error") or reb.get("error")
-    print("  gbdt roofline failed:", err)
+    payload["hist_subtract_hbm_ratio"] = sub["hbm_bytes"] / max(reb["hbm_bytes"], 1)
+    payload["hist_subtract_collective_ratio"] = (
+        sub["collective_bytes"] / max(reb["collective_bytes"], 1)
+    )
     save("gbdt_roofline", payload)
+    print(f"  GBDT sharded-histogram step on {shape['mesh_shape']} "
+          f"(hist_mode=subtract): "
+          f"compute {sub['compute_s']:.3e}s "
+          f"memory {sub['memory_s']:.3e}s "
+          f"collective {sub['collective_s']:.3e}s "
+          f"-> {sub['dominant']}-bound")
+    print(f"  vs rebuild: hbm x{payload['hist_subtract_hbm_ratio']:.3f} "
+          f"collective x{payload['hist_subtract_collective_ratio']:.3f}")
     return payload
 
 
@@ -158,7 +163,7 @@ _COLLECTIVES_CODE = textwrap.dedent(
     import jax
     import jax.numpy as jnp
 
-    from repro.launch.mesh import make_gbdt_mesh
+    from repro.launch.mesh import make_gbdt_mesh, make_mesh
     from repro.ps.sharded import collective_bytes_per_build
     from repro.trees.binning import SparseBins
     from repro.trees.learner import LearnerConfig
@@ -176,7 +181,7 @@ _COLLECTIVES_CODE = textwrap.dedent(
         feat_codes=jax.ShapeDtypeStruct((F, C), jnp.int32),
         zero_bin=jax.ShapeDtypeStruct((F,), jnp.int32),
     )
-    mesh_1d = jax.make_mesh((16,), ("data",))
+    mesh_1d = make_mesh((16,), ("data",))
     mesh_2d = make_gbdt_mesh(1, 16)
     row = {{"geometry": {{
         "N": N, "F": F, "B": B, "depth": depth, "nnz_row": E,
@@ -213,16 +218,10 @@ COLLECTIVE_GEOMETRIES = [
 
 
 def _run_collectives_row(N, F, B, E, depth) -> dict:
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         _COLLECTIVES_CODE.format(N=N, F=F, B=B, E=E, depth=depth)],
-        capture_output=True, text=True, timeout=1400,
-        env={**os.environ, "PYTHONPATH": "src"},
+    return _run_child(
+        _COLLECTIVES_CODE.format(N=N, F=F, B=B, E=E, depth=depth),
+        "GBDT_COLLECTIVES_JSON=",
     )
-    for line in proc.stdout.splitlines():
-        if line.startswith("GBDT_COLLECTIVES_JSON="):
-            return json.loads(line.split("=", 1)[1])
-    return {"error": proc.stderr[-800:]}
 
 
 def collectives(quick: bool = True) -> dict:
@@ -232,9 +231,6 @@ def collectives(quick: bool = True) -> dict:
     for name, N, F, B, E, depth in geoms:
         row = _run_collectives_row(N, F, B, E, depth)
         rows[name] = row
-        if "error" in row:
-            print(f"  {name}: FAILED {row['error'][:200]}")
-            continue
         print(f"  {name} (N={N} F={F} B={B} depth={depth}): "
               f"dense-psum {row['bytes_1d_dense_psum']:,}B "
               f"argmax-merge {row['bytes_2d_argmax_merge']:,}B "
@@ -247,6 +243,7 @@ def collectives(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     out = run(quick)
     out["collectives"] = collectives(quick)["rows"]
     return out

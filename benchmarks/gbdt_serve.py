@@ -30,6 +30,7 @@ from repro.serving import ForestEngine, ForestServer, PredictRequest, percentile
 from repro.trees.binning import make_bins
 from repro.trees.forest import Forest, quantization_atol
 from repro.trees.tree import tree_num_nodes
+from repro.launch.compile_cache import enable_compile_cache
 
 GATE_BATCH, GATE_TREES = 256, 32  # the geometry check_bench --serve pins
 
@@ -215,6 +216,7 @@ def quantized_record(edges, p, n_bins, backend, rng, seed) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", dest="quick", action="store_false", default=True)
     ap.add_argument("--backend", default="auto",

@@ -26,6 +26,7 @@ import numpy as np
 from benchmarks.common import save, time_call
 from repro.kernels import autotune, ops
 from repro.kernels.ref import level_build_ref
+from repro.launch.compile_cache import enable_compile_cache
 
 CASES = [
     # (n, f, n_bins, n_nodes)
@@ -267,7 +268,6 @@ def run_fused_level(quick: bool = True, retune: bool = True) -> dict:
             "staged_hbm_bytes": staged_level_hbm_bytes(n, f, n_bins, n_nodes),
             "fused_hbm_bytes": fused_level_hbm_bytes(n, f, n_bins, n_nodes),
             "sample_block": sb, "feature_block": fb,
-            "node_block": entry["node_block"],
             "parity_ok": parity,
         }
         rows.append(row)
@@ -362,6 +362,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True, check: bool = False):
+    enable_compile_cache()
     out = run(quick)
     if check:
         check_snapshot(out)

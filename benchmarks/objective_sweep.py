@@ -20,6 +20,7 @@ from benchmarks.common import save
 from repro.core.sgbdt import SGBDTConfig, init_state, train_metrics
 from repro.ps import clear_trainers, get_trainer
 from repro.trees.learner import LearnerConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 WORKERS = 8
 
@@ -89,6 +90,7 @@ def run(quick: bool = True) -> dict:
 
 
 def main(quick: bool = True):
+    enable_compile_cache()
     return run(quick)
 
 

@@ -64,7 +64,7 @@ _REDUCTION_PRIMS = {
 # single-device build.
 _NONADDITIVE_PRIMS = {"sub", "div", "max", "min", "pow", "rem"}
 _BARRIER_PRIMS = {"optimization_barrier", "opt_barrier"}
-_COLLECTIVE_PRIMS = {"psum", "psum2", "all_reduce", "allreduce"}
+_COLLECTIVE_PRIMS = {"psum", "psum2", "psum_invariant", "all_reduce", "allreduce"}
 # Non-additive COLLECTIVES — the 2D merged-argmax split search (pmax of
 # per-shard best gains, pmin of global flat indices; DESIGN.md §16). Their
 # outputs are merged like psum's, but feeding one a shard-local partial
@@ -463,17 +463,16 @@ def _check_sharded(cfg, data) -> list[Finding]:
     build, and the 2D (data × feature) build with its argmax-merge
     collective, on dense and on SparseBins data."""
     import jax
-    import numpy as np
-    from jax.sharding import Mesh
 
+    from repro.launch.mesh import make_gbdt_mesh, make_mesh
     from repro.ps.sharded import make_sharded_builder, make_sharded_builder_2d
     from repro.trees.binning import to_sparse
 
     g = jax.numpy.zeros((data.n_samples,), jax.numpy.float32)
     rng = jax.random.PRNGKey(0)  # analysis: ignore[prngkey-outside-ticket]
     findings = []
-    mesh_1d = Mesh(np.array(jax.devices()[:1]), ("data",))
-    mesh_2d = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "feature"))
+    mesh_1d = make_mesh((1,), ("data",))
+    mesh_2d = make_gbdt_mesh(1, 1)
     sparse_bins = to_sparse(data.bins)
     for mode in ("subtract", "rebuild"):
         cfg_m = cfg.learner._replace(hist_mode=mode)

@@ -22,7 +22,6 @@ KNOWN_FORMATS = {1}
 ENTRY_FIELDS = {
     "sample_block": (int, True),
     "feature_block": (int, True),
-    "node_block": (int, True),
     "fused_ms": (float, True),
     "split_ms": (float, True),
     "host": (str, False),

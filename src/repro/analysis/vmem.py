@@ -129,10 +129,13 @@ def check_tuning_table(table_path: pathlib.Path, relpath: str) -> list[Finding]:
         # budget pricing needs the jax stack — skip, the analysis CI job
         # runs the full check.
         return findings
+    from repro.kernels import autotune
+
     for key, entry in table.get("entries", {}).items():
         n, f, b, l = tuning_schema.parse_geometry(key)
+        f_pad, _ = autotune.feature_tiling(f, b, entry["feature_block"])
         nbytes = fused_level_vmem_bytes(
-            l, l, f, b, entry["sample_block"], entry["feature_block"]
+            l, l, f_pad, b, entry["sample_block"], entry["feature_block"]
         )
         if nbytes > FUSED_VMEM_BUDGET:
             findings.append(
@@ -151,12 +154,11 @@ def check_tuning_table(table_path: pathlib.Path, relpath: str) -> list[Finding]:
         # fits() must agree with pricing its own looked-up blocks: the
         # fast path and the byte model drifting apart means dispatch
         # decisions stop matching the documented budget math.
-        from repro.kernels import autotune
-
         blocks = autotune.lookup(n, f, b, l)
+        f_pad, _ = autotune.feature_tiling(f, b, blocks["feature_block"])
         direct = (
             fused_level_vmem_bytes(
-                l, l, f, b, blocks["sample_block"], blocks["feature_block"]
+                l, l, f_pad, b, blocks["sample_block"], blocks["feature_block"]
             )
             <= FUSED_VMEM_BUDGET
         )

@@ -5,9 +5,8 @@ on block-shape choices, and the right shapes depend on the problem
 geometry ``(N, F, B, L)`` — samples, features, bins, level nodes. This
 module owns that decision end to end:
 
-  * ``sweep_level_build`` times the fused kernel (and the staged split
-    kernel's ``node_block``) over a candidate grid for one geometry and
-    returns the winner;
+  * ``sweep_level_build`` times the fused kernel over the lane-legal
+    candidate grid for one geometry and returns the winner;
   * winners persist in ``tuning_table.json`` NEXT TO THIS FILE — a
     committed artifact, regenerated by ``benchmarks/kernel_bench.py``
     (``PYTHONPATH=src python -m benchmarks.kernel_bench``), so dispatch
@@ -18,6 +17,11 @@ module owns that decision end to end:
 Wall times recorded on a CPU container come from the Pallas interpreter
 and do NOT transfer to TPU — the table's ``host`` field records where a
 row was measured so a TPU regeneration can overwrite CPU-tuned rows.
+
+Every block shape this module hands out is one Mosaic can lower: the
+feature block is the whole (padded) feature width up to 128 features and a
+multiple of 128 above it (``feature_tiling``), and the sample block is a
+multiple of 128 sized so the one-hot factor fits ``ONEHOT_BYTES``.
 """
 from __future__ import annotations
 
@@ -29,17 +33,19 @@ from typing import Iterable
 
 import jax
 
+from repro.kernels.split_scan import LANES, scan_width
+
 TABLE_PATH = pathlib.Path(__file__).resolve().parent / "tuning_table.json"
 
-DEFAULT_BLOCKS = {"sample_block": 512, "feature_block": 8, "node_block": 8}
+# The (F_blk * B, S) f32 one-hot factor the histogram dot consumes is the
+# largest VMEM value of both histogram programs; the default sample block
+# keeps it under this many bytes.
+ONEHOT_BYTES = 4 * 2**20
+MAX_SAMPLE_BLOCK = 512
 
-# Candidate grids for the sweep. Sample blocks trade DMA granularity
-# against VMEM residency; feature blocks size the one-hot factor the MXU
-# consumes; node blocks tile the staged split kernel (the fused path holds
-# all level nodes resident, so node_block only matters on the fallback).
-SAMPLE_BLOCKS = (256, 512, 1024, 2048, 4096)
-FEATURE_BLOCKS = (4, 8, 16)
-NODE_BLOCKS = (4, 8, 16)
+# Candidate sample blocks for the sweep. They trade DMA granularity against
+# VMEM residency; feature blocks come from ``legal_feature_blocks``.
+SAMPLE_BLOCKS = (128, 256, 512, 1024, 2048, 4096)
 
 _cache: dict[str, dict] | None = None
 
@@ -73,13 +79,48 @@ def load_table(path: pathlib.Path | None = None, refresh: bool = False) -> dict:
     return table
 
 
+def feature_tiling(f: int, n_bins: int, feature_block: int | None = None) -> tuple[int, int]:
+    """``(padded F, feature block)`` for an F-wide dense bin matrix.
+
+    Mosaic tiles a block's last two dims in (8, 128): the feature-major
+    (F_blk, S) bins block needs a multiple of 8 features or the whole padded
+    width, and the (rows, F_blk * B) histogram block a multiple of 128
+    lanes or the whole row. The default block — the padded width up to 128
+    features, 128 above it — satisfies both. F is padded so that F * B
+    fills whole 128-lane tiles (``split_scan.scan_width``). An explicit
+    ``feature_block`` is kept as given (the interpreter takes any block).
+    """
+    align = scan_width(n_bins) // n_bins
+    if feature_block is None:
+        whole = -(-max(f, 1) // align) * align
+        feature_block = whole if whole <= LANES else LANES
+    unit = math.lcm(feature_block, align)
+    return -(-max(f, 1) // unit) * unit, feature_block
+
+
+def default_sample_block(feature_block: int, n_bins: int) -> int:
+    """The largest multiple of 128 (at most 512) whose one-hot factor fits
+    ``ONEHOT_BYTES`` — never below 128, the lane width of the per-sample
+    operand rows."""
+    rows = ONEHOT_BYTES // (4 * feature_block * n_bins)
+    return max(LANES, min(MAX_SAMPLE_BLOCK, rows // LANES * LANES))
+
+
+def legal_feature_blocks(f: int, n_bins: int) -> list[int]:
+    """Every feature block Mosaic can lower for this width: the default
+    and each multiple of 128 below the padded width."""
+    f_pad, default = feature_tiling(f, n_bins)
+    return sorted({default, *range(LANES, f_pad, LANES)})
+
+
 def lookup(n: int, f: int, b: int, l: int) -> dict:
-    """Dispatch-time block shapes for a geometry.
+    """Dispatch-time ``{"sample_block", "feature_block"}`` for a geometry.
 
     Exact key first; else the nearest recorded geometry by Euclidean
     distance in log-space over (N, F, B, L) — block-shape winners vary
     smoothly with scale, so the nearest measured neighbour beats a blind
-    default; else ``DEFAULT_BLOCKS``.
+    default; else the defaults. A recorded block is used only where it is
+    legal for this geometry.
     """
     table = load_table()
     key = geometry_key(n, f, b, l)
@@ -92,13 +133,14 @@ def lookup(n: int, f: int, b: int, l: int) -> dict:
             return sum((a - c) ** 2 for a, c in zip(target, other))
 
         entry = table[min(table, key=dist)]
-    blocks = dict(DEFAULT_BLOCKS)
-    if entry:
-        blocks.update({k: entry[k] for k in DEFAULT_BLOCKS if k in entry})
-    # Never exceed the geometry itself.
-    blocks["feature_block"] = min(blocks["feature_block"], max(f, 1))
-    blocks["node_block"] = min(blocks["node_block"], max(l, 1))
-    return blocks
+    entry = entry or {}
+    fb = entry.get("feature_block")
+    if fb not in legal_feature_blocks(f, b):
+        fb = feature_tiling(f, b)[1]
+    sb = entry.get("sample_block")
+    if not sb or sb % LANES:
+        sb = default_sample_block(fb, b)
+    return {"sample_block": sb, "feature_block": fb}
 
 
 def save_table(entries: dict[str, dict], path: pathlib.Path | None = None) -> pathlib.Path:
@@ -138,18 +180,18 @@ def sweep_level_build(
     n_nodes: int,
     n_bins: int,
     sample_blocks: Iterable[int] = SAMPLE_BLOCKS,
-    feature_blocks: Iterable[int] = FEATURE_BLOCKS,
-    node_blocks: Iterable[int] = NODE_BLOCKS,
     reps: int = 3,
 ) -> tuple[dict, list[dict]]:
-    """Sweep the fused kernel over (sample_block, feature_block) and the
-    staged split kernel over node_block for ONE geometry.
+    """Sweep the fused kernel over (sample_block, feature_block) for ONE
+    geometry, then time the staged split kernel at the winner.
 
     Returns ``(entry, records)``: the winning entry (ready for
     ``save_table``) and every measured point. The fused kernel is timed
     through the public ``ops.level_build`` dispatch (padding included), a
     full-level build — the shape every level of a rebuild-mode tree and
-    the root of every subtract-mode tree runs.
+    the root of every subtract-mode tree runs. Only lane-legal feature
+    blocks are tried, and sample blocks whose one-hot factor would exceed
+    ``ONEHOT_BYTES`` are skipped (the default block is always tried).
     """
     import jax.numpy as jnp
 
@@ -160,11 +202,11 @@ def sweep_level_build(
     mask = jnp.ones((f,), jnp.float32)
     records: list[dict] = []
     best: dict | None = None
-    for sb in sample_blocks:
-        if sb > max(n, 1):
-            continue
-        for fb in feature_blocks:
-            if fb > f:
+    for fb in legal_feature_blocks(f, n_bins):
+        default_sb = default_sample_block(fb, n_bins)
+        for sb in sorted({*sample_blocks, default_sb}):
+            too_big = sb * fb * n_bins * 4 > ONEHOT_BYTES and sb != default_sb
+            if too_big or sb > max(n, 1):
                 continue
 
             def run(sb=sb, fb=fb):
@@ -184,25 +226,17 @@ def sweep_level_build(
         bins, node_ids, grad, hess, n_nodes, n_bins, backend="pallas",
         sample_block=best["sample_block"], feature_block=best["feature_block"],
     )
-    best_nb, best_nb_ms = DEFAULT_BLOCKS["node_block"], None
-    for nb in node_blocks:
-        if nb > n_nodes:
-            continue
-        t = _time(
-            lambda nb=nb: ops.split_gain(hist, 1.0, 1e-3, backend="pallas",
-                                         node_block=nb),
-            reps=reps,
-        )
-        records.append({"node_block": nb, "split_ms": t * 1e3})
-        if best_nb_ms is None or t * 1e3 < best_nb_ms:
-            best_nb, best_nb_ms = nb, t * 1e3
-
+    split_s = _time(
+        lambda: ops.split_gain(
+            hist, 1.0, 1e-3, backend="pallas", feature_block=best["feature_block"]
+        ),
+        reps=reps,
+    )
     entry = {
         "sample_block": best["sample_block"],
         "feature_block": best["feature_block"],
-        "node_block": best_nb,
         "fused_ms": best["fused_ms"],
-        "split_ms": best_nb_ms,
+        "split_ms": split_s * 1e3,
         "host": jax.default_backend(),
     }
     return entry, records

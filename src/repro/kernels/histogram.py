@@ -5,20 +5,32 @@ per-(node, feature, bin) buckets — atomics into shared memory. TPUs have no
 atomics and weak scatter throughput, but a 128x128 systolic MXU. We therefore
 reformulate the whole level-histogram as a single dense contraction:
 
-    out[r, f*B + b] = sum_s GH[r, s] * onehot[s, f*B + b]
+    out[r, f*B + b] = sum_s GH[r, s] * onehot[f*B + b, s]
 
 where row r carries (node_of_row[r], grad-or-hess), GH masks each sample's
 grad/hess onto its current tree node, and onehot marks the sample's bin for
 feature f. Both factor matrices are built on the fly inside VMEM from
-integer inputs — nothing of size (N, F*B) ever touches HBM.
+integer inputs — nothing of size (N, F*B) ever touches HBM. The dot runs at
+f32 precision: the one-hot factor is exact in any precision, but the MXU's
+default would round grad/hess to bf16.
 
 The row -> node mapping is an explicit operand (``row_map``), not an iota:
-row r selects samples on node ``row_map[r]``. The full-level build passes
-``row_map = repeat(arange(n_nodes), 2)``; the histogram-subtraction tree
-builder (``trees.learner`` with ``hist_mode='subtract'``) passes the
-smaller child of every parent only, halving the GH rows — and therefore
-the MXU work — of every level below the root. Kernel cost is linear in
-``rows``, so the node subset IS the speedup.
+rows [0, R) carry the grad and rows [R, 2R) the hess of node
+``row_map[r]``. The full-level build passes ``arange(n_nodes)`` twice; the
+histogram-subtraction tree builder (``trees.learner`` with
+``hist_mode='subtract'``) passes the smaller child of every parent only,
+halving the GH rows — and therefore the MXU work — of every level below
+the root. Kernel cost is linear in ``rows``, so the node subset IS the
+speedup.
+
+Samples ride the lane axis everywhere: node, grad and hess arrive as
+(1, N) rows (the layout GH needs) and the bins as the feature-major (F, N)
+transpose of the (N, F) matrix. An (N, F) block would pad F = 28 to 128
+lanes in VMEM and in HBM, and an (N, 1) operand a single lane to 128;
+(F, N) keeps both dense, and is a free view of the feature-major layout
+XLA gives a narrow (N, F) array. Block shapes follow ``kernels.autotune``:
+the feature block spans the padded width up to 128 features and is a
+multiple of 128 above it.
 
 Grid: (feature_blocks, sample_blocks); sample axis is innermost and
 accumulates into the same output block (standard Pallas reduce pattern).
@@ -31,46 +43,55 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.vma import out_struct
+
+
+def gh_factor(row_node, node, grad, hess):
+    """The (rows, S) GH factor from a (rows, 1) row map and (1, S) sample
+    rows: row r < rows/2 carries the grad of samples on node
+    ``row_node[r]``, row r >= rows/2 their hess. Inactive samples
+    (node < 0) never match (row maps hold real node ids >= 0)."""
+    rows, s_blk = row_node.shape[0], node.shape[1]
+    row_is_h = jax.lax.broadcasted_iota(jnp.int32, (rows, s_blk), 0) >= rows // 2
+    gh_val = jnp.where(row_is_h, hess, grad)
+    return jnp.where(row_node == node, gh_val, 0.0)
+
+
+def onehot_factor(bins_t, n_bins: int):
+    """The (F_blk * B, S) one-hot factor of an (F_blk, S) feature-major bins
+    block: ``[f*B + b, s] = 1{bins_t[f, s] == b}``."""
+    f_blk, s_blk = bins_t.shape
+    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (f_blk, n_bins, s_blk), 1)
+    onehot = (bins_t[:, None, :] == bin_iota).astype(jnp.float32)
+    return onehot.reshape(f_blk * n_bins, s_blk)
+
+
+def hist_dot(gh, onehot):
+    """GH @ onehot^T at f32 precision, (rows, S) x (F_blk*B, S) ->
+    (rows, F_blk*B) — the one histogram contraction both histogram
+    programs issue."""
+    return jax.lax.dot_general(
+        gh, onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+
 
 def _hist_kernel(
-    bins_ref,  # (S_blk, F_blk) int32
-    node_ref,  # (S_blk, 1) int32, -1 = inactive
-    grad_ref,  # (S_blk, 1) f32
-    hess_ref,  # (S_blk, 1) f32
+    bins_ref,  # (F_blk, S_blk) int32 — feature-major
+    node_ref,  # (1, S_blk) int32, -1 = inactive
+    grad_ref,  # (1, S_blk) f32
+    hess_ref,  # (1, S_blk) f32
     rowmap_ref,  # (rows, 1) int32 — node id each GH row selects
     out_ref,  # (rows, F_blk*B) f32
     *,
     n_bins: int,
 ):
-    s_blk, f_blk = bins_ref.shape
-    rows = out_ref.shape[0]
-
-    sample_axis = pl.program_id(1)
-
-    @pl.when(sample_axis == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    node = node_ref[:, 0]  # (S,)
-    grad = grad_ref[:, 0]
-    hess = hess_ref[:, 0]
-    row_node = rowmap_ref[:, 0]  # (rows,)
-
-    # GH: (rows, S). Row r selects samples on node row_map[r]; even rows
-    # carry grad, odd rows carry hess. Inactive samples (node < 0) never
-    # match (row maps hold real node ids >= 0).
-    row_is_h = jax.lax.broadcasted_iota(jnp.int32, (rows, s_blk), 0) % 2
-    gh_val = jnp.where(row_is_h == 0, grad[None, :], hess[None, :])
-    gh = jnp.where(row_node[:, None] == node[None, :], gh_val, 0.0)
-
-    # One-hot: (S, F_blk*B), onehot[s, f*B + b] = 1{bins[s, f] == b}.
-    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (s_blk, f_blk, n_bins), 2)
-    onehot = (bins_ref[...][..., None] == bin_iota).astype(jnp.float32)
-    onehot = onehot.reshape(s_blk, f_blk * n_bins)
-
-    out_ref[...] += jax.lax.dot(
-        gh, onehot, preferred_element_type=jnp.float32
-    )
+    gh = gh_factor(rowmap_ref[...], node_ref[...], grad_ref[...], hess_ref[...])
+    out_ref[...] += hist_dot(gh, onehot_factor(bins_ref[...], n_bins))
 
 
 @functools.partial(
@@ -85,7 +106,7 @@ def histogram_pallas(
     n_nodes: int,
     n_bins: int,
     sample_block: int = 512,
-    feature_block: int = 8,
+    feature_block: int = 128,
     interpret: bool | None = None,
     active_nodes: jax.Array | None = None,  # (n_sub,) int32 node subset
 ) -> jax.Array:
@@ -104,6 +125,7 @@ def histogram_pallas(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n, f = bins.shape
+    feature_block = min(feature_block, f)
     assert n % sample_block == 0, "wrapper must pad samples"
     assert f % feature_block == 0, "wrapper must pad features"
     ns, nf = n // sample_block, f // feature_block
@@ -111,29 +133,31 @@ def histogram_pallas(
         active_nodes = jnp.arange(n_nodes, dtype=jnp.int32)
     n_sub = active_nodes.shape[0]
     rows = 2 * n_sub
-    row_map = jnp.repeat(active_nodes.astype(jnp.int32), 2)  # (rows,)
+    row_map = jnp.tile(active_nodes.astype(jnp.int32), 2)  # (rows,)
 
     out = pl.pallas_call(
         functools.partial(_hist_kernel, n_bins=n_bins),
         grid=(nf, ns),
         in_specs=[
-            pl.BlockSpec((sample_block, feature_block), lambda fb, sb: (sb, fb)),
-            pl.BlockSpec((sample_block, 1), lambda fb, sb: (sb, 0)),
-            pl.BlockSpec((sample_block, 1), lambda fb, sb: (sb, 0)),
-            pl.BlockSpec((sample_block, 1), lambda fb, sb: (sb, 0)),
+            pl.BlockSpec((feature_block, sample_block), lambda fb, sb: (fb, sb)),
+            pl.BlockSpec((1, sample_block), lambda fb, sb: (0, sb)),
+            pl.BlockSpec((1, sample_block), lambda fb, sb: (0, sb)),
+            pl.BlockSpec((1, sample_block), lambda fb, sb: (0, sb)),
             pl.BlockSpec((rows, 1), lambda fb, sb: (0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (rows, feature_block * n_bins), lambda fb, sb: (0, fb)
         ),
-        out_shape=jax.ShapeDtypeStruct((rows, f * n_bins), jnp.float32),
+        out_shape=out_struct(
+            (rows, f * n_bins), jnp.float32, bins, node_ids, grad, hess, row_map
+        ),
         interpret=interpret,
     )(
-        bins,
-        node_ids[:, None],
-        grad[:, None],
-        hess[:, None],
+        bins.T,
+        node_ids[None, :],
+        grad[None, :],
+        hess[None, :],
         row_map[:, None],
     )
-    # rows are (2*row + grad/hess) -> (row, gh, feature, bin) -> (gh, row, f, b)
-    return out.reshape(n_sub, 2, f, n_bins).transpose(1, 0, 2, 3)
+    # rows are (grad|hess, row) -> (gh, row, feature, bin)
+    return out.reshape(2, n_sub, f, n_bins)

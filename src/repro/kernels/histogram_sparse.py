@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.vma import out_struct
+
 
 def _sparse_hist_kernel(
     enode_ref,  # (F_blk, C_blk) int32 — node id of each entry's sample, -1 pad
@@ -149,7 +151,9 @@ def histogram_sparse_pallas(
         out_specs=pl.BlockSpec(
             (feature_block, rows * n_bins), lambda fb, cb: (fb, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((fpad, rows * n_bins), jnp.float32),
+        out_shape=out_struct(
+            (fpad, rows * n_bins), jnp.float32, e_node, e_grad, e_hess, e_code, row_map
+        ),
         interpret=interpret,
     )(e_node, e_grad, e_hess, e_code, row_map[:, None])
     # (Fpad, rows*B) -> (rows, F, B) -> (gh, sub, F, B), dropping feature pad

@@ -28,6 +28,14 @@ steps of partition. Scalars (lam, min_child_hess) ride in SMEM; everything
 data-dependent (the active-node subset, the feature mask) is an operand so
 one compiled program serves a whole training run.
 
+Layouts are the ones Mosaic lowers without relayouts: histograms stay
+(rows, F * B) end to end (the gain scan is ``split_scan.split_gain_tile``
+on that layout, never a lane-splitting reshape), per-sample operands come
+as (1, N) lane rows and the bins as their feature-major (F, N) transpose
+(as in ``histogram.py``: no lane padding in VMEM or HBM), split decisions
+leave as (L, 2) / (L, 1) columns, and row selections (sibling expansion,
+the split-table lookup) are 0/1 matmuls.
+
 The row -> node semantics, the gain formula, the validity mask, and the
 argmax tie-break (first maximum in (f * B + b) row-major order) all match
 ``ref.level_build_ref`` / the staged ``trees.learner`` path exactly.
@@ -41,20 +49,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.histogram import gh_factor, hist_dot, onehot_factor
+from repro.kernels.split_scan import split_gain_tile
+from repro.kernels.vma import out_struct
+
+
+def _select(sel, x):
+    """``sel @ x`` for a 0/1 row-selection matrix — exact at f32 precision."""
+    return jax.lax.dot(
+        sel, x, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
 
 def _level_kernel(
-    bins_ref,  # (S_blk, F_pad) int32
-    node_ref,  # (S_blk, 1) int32, -1 = inactive
-    grad_ref,  # (S_blk, 1) f32
-    hess_ref,  # (S_blk, 1) f32
+    bins_ref,  # (F_pad, S_blk) int32 — feature-major
+    node_ref,  # (1, S_blk) int32, -1 = inactive
+    grad_ref,  # (1, S_blk) f32
+    hess_ref,  # (1, S_blk) f32
     rowmap_ref,  # (2 * L_sub, 1) int32 — node id each GH row selects
-    parent_ref,  # (2, L_par, FB_pad) f32 — parent cache (zeros in full mode)
-    mask_ref,  # (1, F_pad) f32 — 1.0 = feature in this tree's subsample
+    parent_ref,  # (2, L_sub, FB_pad) f32 — parent cache (zeros in full mode)
+    mask_ref,  # (1, FB_pad) f32 — 1.0 = the lane's feature is in the subsample
     params_ref,  # (2,) f32 in SMEM — [lam, min_child_hess]
     hist_ref,  # out (2, L, FB_pad) f32 — the full level histogram
-    split_ref,  # out (2, L) int32 — [best_feature; best_bin] per node
-    gain_ref,  # out (1, L) f32 — best gain per node (pre pass-left fix)
-    node_out_ref,  # out (S_blk, 1) int32 — new row -> node map
+    split_ref,  # out (L, 2) int32 — [best_feature, best_bin] per node
+    gain_ref,  # out (L, 1) f32 — best gain per node (pre pass-left fix)
+    node_out_ref,  # out (1, S_blk) int32 — new row -> node map
     acc_ref,  # scratch (2 * L_sub, FB_pad) f32 — built-row accumulator
     *,
     ns: int,
@@ -64,11 +84,11 @@ def _level_kernel(
     derive_sibling: bool,
 ):
     t = pl.program_id(0)
-    s_blk, f_pad = bins_ref.shape
-    rows = acc_ref.shape[0]  # 2 * L_sub
-    l_sub = rows // 2
+    f_pad, s_blk = bins_ref.shape
+    l_sub = acc_ref.shape[0] // 2
     l = n_nodes
-    n_chunks = f_pad // feature_block
+    fb = f_pad * n_bins
+    chunk = feature_block * n_bins
 
     @pl.when(t == 0)
     def _init():
@@ -76,35 +96,20 @@ def _level_kernel(
 
     @pl.when(t < ns)
     def _accumulate():
-        # Same GH factor as histogram.py: row 2r carries grad, 2r+1 hess,
-        # both masked to samples currently on node rowmap[2r]. One dot of
-        # identical shape per (feature chunk, sample block) keeps the
-        # per-cell f32 accumulation order bit-compatible with the staged
-        # kernel's (feature_blocks, sample_blocks) grid.
-        node = node_ref[:, 0]
-        grad = grad_ref[:, 0]
-        hess = hess_ref[:, 0]
-        row_node = rowmap_ref[:, 0]
-        row_is_h = jax.lax.broadcasted_iota(jnp.int32, (rows, s_blk), 0) % 2
-        gh_val = jnp.where(row_is_h == 0, grad[None, :], hess[None, :])
-        gh = jnp.where(row_node[:, None] == node[None, :], gh_val, 0.0)
-        for c in range(n_chunks):
-            blk = bins_ref[:, c * feature_block : (c + 1) * feature_block]
-            bin_iota = jax.lax.broadcasted_iota(
-                jnp.int32, (s_blk, feature_block, n_bins), 2
-            )
-            onehot = (blk[..., None] == bin_iota).astype(jnp.float32)
-            onehot = onehot.reshape(s_blk, feature_block * n_bins)
-            lo, hi = c * feature_block * n_bins, (c + 1) * feature_block * n_bins
-            acc_ref[:, lo:hi] += jax.lax.dot(
-                gh, onehot, preferred_element_type=jnp.float32
+        # Same GH factor and dot as histogram.py, one dot of identical
+        # shape per (feature chunk, sample block): the per-cell f32
+        # accumulation order matches the staged kernel's grid bit for bit.
+        gh = gh_factor(rowmap_ref[...], node_ref[...], grad_ref[...], hess_ref[...])
+        for c in range(f_pad // feature_block):
+            blk = bins_ref[c * feature_block : (c + 1) * feature_block, :]
+            acc_ref[:, c * chunk : (c + 1) * chunk] += hist_dot(
+                gh, onehot_factor(blk, n_bins)
             )
 
     @pl.when(t == ns)
     def _decide():
-        fb = f_pad * n_bins
-        acc = acc_ref[...].reshape(l_sub, 2, f_pad, n_bins)
-        g_built, h_built = acc[:, 0], acc[:, 1]  # (L_sub, F_pad, B)
+        g_full = acc_ref[:l_sub, :]  # (L_sub, FB) grad rows
+        h_full = acc_ref[l_sub:, :]  # hess rows
         if derive_sibling:
             # Node n (parent p = n >> 1) is either the built child or the
             # derived sibling ``parent - built`` — the subtraction runs on
@@ -112,77 +117,62 @@ def _level_kernel(
             # learner keeps the collective BEFORE this kernel (see
             # ps/sharded.py); single-shard, this is the same arithmetic as
             # the staged learner's post-psum gather.
-            par = parent_ref[...].reshape(2, l_sub, f_pad, n_bins)
-            built2 = jnp.repeat(  # row p -> nodes 2p, 2p+1
-                jnp.stack([g_built, h_built]), 2, axis=1
-            )  # (2, L, F_pad, B)
-            par2 = jnp.repeat(par, 2, axis=1)
-            built_ids = rowmap_ref[:, 0].reshape(l_sub, 2)[:, 0]  # (L_sub,)
+            sel = (
+                jax.lax.broadcasted_iota(jnp.int32, (l, l_sub), 0) >> 1
+                == jax.lax.broadcasted_iota(jnp.int32, (l, l_sub), 1)
+            ).astype(jnp.float32)  # (L, L_sub): node n <- row n >> 1
+            built_of = _select(sel, rowmap_ref[:l_sub, :].astype(jnp.float32))
             is_built = (
-                jax.lax.broadcasted_iota(jnp.int32, (l,), 0)
-                == jnp.repeat(built_ids, 2)
+                jax.lax.broadcasted_iota(jnp.int32, (l, 1), 0)
+                == built_of.astype(jnp.int32)
             )
-            full = jnp.where(is_built[None, :, None, None], built2, par2 - built2)
-            g_full, h_full = full[0], full[1]
-        else:
-            g_full, h_full = g_built, h_built  # L_sub == L
-        hist_ref[0] = g_full.reshape(l, fb)
-        hist_ref[1] = h_full.reshape(l, fb)
+            g_b, h_b = _select(sel, g_full), _select(sel, h_full)
+            g_p, h_p = _select(sel, parent_ref[0]), _select(sel, parent_ref[1])
+            g_full = jnp.where(is_built, g_b, g_p - g_b)
+            h_full = jnp.where(is_built, h_b, h_p - h_b)
+        hist_ref[0] = g_full
+        hist_ref[1] = h_full
 
-        # In-register split scan — the exact split_scan.py / ref formula.
-        lam = params_ref[0]
-        min_h = params_ref[1]
-        gl = jnp.cumsum(g_full, axis=-1)
-        hl = jnp.cumsum(h_full, axis=-1)
-        gt, ht = gl[..., -1:], hl[..., -1:]
-        gr, hr = gt - gl, ht - hl
-        gain = gl * gl / (hl + lam) + gr * gr / (hr + lam) - gt * gt / (ht + lam)
-        bin_pos = jax.lax.broadcasted_iota(jnp.int32, gain.shape, 2)
-        valid = (hl >= min_h) & (hr >= min_h) & (bin_pos < n_bins - 1)
-        valid = valid & (mask_ref[0, :][None, :, None] > 0.0)
-        gain = jnp.where(valid, gain, -jnp.inf)
+        # The staged split kernel's gain formula on the same layout.
+        gain, valid = split_gain_tile(
+            g_full, h_full, params_ref[0], params_ref[1], n_bins
+        )
+        gain = jnp.where(valid & (mask_ref[...] > 0.0), gain, -jnp.inf)
 
         # Argmax with the first-maximum tie-break (== jnp.argmax): max,
         # then the smallest flat index attaining it.
-        flat = gain.reshape(l, fb)
-        best = jnp.max(flat, axis=-1, keepdims=True)  # (L, 1)
+        best = jnp.max(gain, axis=-1, keepdims=True)  # (L, 1)
         pos = jax.lax.broadcasted_iota(jnp.int32, (l, fb), 1)
-        idx = jnp.min(jnp.where(flat == best, pos, fb), axis=-1)  # (L,)
-        best = best[:, 0]
+        idx = jnp.min(jnp.where(gain == best, pos, fb), axis=-1, keepdims=True)
         ok = jnp.isfinite(best) & (best > 0.0)
-        feat = jnp.where(ok, idx // n_bins, 0).astype(jnp.int32)
-        thr = jnp.where(ok, idx % n_bins, n_bins - 1).astype(jnp.int32)
-        split_ref[0, :] = feat
-        split_ref[1, :] = thr
-        gain_ref[0, :] = best
+        feat = jnp.where(ok, idx // n_bins, 0)
+        thr = jnp.where(ok, idx % n_bins, n_bins - 1)
+        split_ref[...] = jnp.concatenate([feat, thr], axis=1).astype(jnp.int32)
+        gain_ref[...] = best
 
     @pl.when(t >= ns)
     def _partition():
-        # Route every sample: gather its node's winning (feature, bin)
-        # from the VMEM-resident split table (one-hot contractions — no
+        # Route every sample: look up its node's winning (feature, bin)
+        # in the VMEM-resident split table with a one-hot contraction (no
         # TPU gathers), read the sample's bin for that feature, go right
         # iff bin > threshold. Matches the staged learner's
         # ``2 * node + (bins[s, feat[node]] > thr[node])`` update.
-        node = node_ref[:, 0]  # (S,)
+        node = node_ref[...]  # (1, S)
         onehot_l = (
-            node[:, None] == jax.lax.broadcasted_iota(jnp.int32, (s_blk, l), 1)
+            jax.lax.broadcasted_iota(jnp.int32, (l, s_blk), 0) == node
         ).astype(jnp.float32)
-        table = jnp.concatenate(  # (L, 2): feature and threshold columns
-            [
-                split_ref[0, :][:, None].astype(jnp.float32),
-                split_ref[1, :][:, None].astype(jnp.float32),
-            ],
-            axis=1,
+        sel = jax.lax.dot_general(  # (2, S): [feature; threshold] per sample
+            split_ref[...].astype(jnp.float32), onehot_l,
+            (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
-        sel = jax.lax.dot(onehot_l, table, preferred_element_type=jnp.float32)
-        feat_s = sel[:, 0].astype(jnp.int32)  # exact: values < F_pad
-        thr_s = sel[:, 1]
-        f_iota = jax.lax.broadcasted_iota(jnp.int32, (s_blk, f_pad), 1)
+        feat_s = sel[0:1, :].astype(jnp.int32)  # exact: values < F_pad
+        f_iota = jax.lax.broadcasted_iota(jnp.int32, (f_pad, s_blk), 0)
         val = jnp.sum(
-            jnp.where(f_iota == feat_s[:, None], bins_ref[...], 0), axis=1
+            jnp.where(f_iota == feat_s, bins_ref[...], 0), axis=0, keepdims=True
         ).astype(jnp.float32)
-        go_right = (val > thr_s).astype(jnp.int32)
-        node_out_ref[:, 0] = 2 * node + go_right
+        go_right = (val > sel[1:2, :]).astype(jnp.int32)
+        node_out_ref[...] = 2 * node + go_right
 
 
 @functools.partial(
@@ -210,7 +200,7 @@ def level_build_pallas(
     n_bins: int,
     derive_sibling: bool = False,
     sample_block: int = 512,
-    feature_block: int = 8,
+    feature_block: int = 128,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """One fused level: (hist (2, L, F_pad, B), feat (L,), thr (L,),
@@ -225,6 +215,7 @@ def level_build_pallas(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n, f_pad = bins.shape
+    feature_block = min(feature_block, f_pad)
     assert n % sample_block == 0, "wrapper must pad samples"
     assert f_pad % feature_block == 0, "wrapper must pad features"
     ns = n // sample_block
@@ -235,7 +226,7 @@ def level_build_pallas(
         assert l_sub == n_nodes
         parent_hist = jnp.zeros((2, l_sub, f_pad, n_bins), jnp.float32)
     fb = f_pad * n_bins
-    row_map = jnp.repeat(active_nodes.astype(jnp.int32), 2)
+    row_map = jnp.tile(active_nodes.astype(jnp.int32), 2)
     params = jnp.stack(
         [jnp.asarray(lam, jnp.float32), jnp.asarray(min_child_hess, jnp.float32)]
     )
@@ -248,53 +239,55 @@ def level_build_pallas(
         n_nodes=n_nodes,
         derive_sibling=derive_sibling,
     )
-    sample_map = lambda t: (jax.lax.rem(t, ns), 0)
+    # Bins and node ids stream in both phases; grad/hess only in phase A
+    # and the new node map only in phase C — their maps park the block
+    # while the other phase runs, so no step DMAs a block it does not use.
+    operands = (
+        bins.T,
+        node_ids[None, :],
+        grad[None, :],
+        hess[None, :],
+        row_map[:, None],
+        parent_hist.reshape(2, l_sub, fb),
+        jnp.repeat(feat_mask.astype(jnp.float32), n_bins)[None, :],
+        params,
+    )
+    phase_a = lambda t: jnp.minimum(t, ns - 1)
+    phase_c = lambda t: jnp.where(t < ns, 0, t - ns)
     hist, split, gain, new_node = pl.pallas_call(
         kernel,
         grid=(2 * ns,),
         in_specs=[
-            pl.BlockSpec((sample_block, f_pad), sample_map),
-            pl.BlockSpec((sample_block, 1), sample_map),
-            pl.BlockSpec((sample_block, 1), sample_map),
-            pl.BlockSpec((sample_block, 1), sample_map),
+            pl.BlockSpec((f_pad, sample_block), lambda t: (0, jax.lax.rem(t, ns))),
+            pl.BlockSpec((1, sample_block), lambda t: (0, jax.lax.rem(t, ns))),
+            pl.BlockSpec((1, sample_block), lambda t: (0, phase_a(t))),
+            pl.BlockSpec((1, sample_block), lambda t: (0, phase_a(t))),
             pl.BlockSpec((2 * l_sub, 1), lambda t: (0, 0)),
             pl.BlockSpec((2, l_sub, fb), lambda t: (0, 0, 0)),
-            pl.BlockSpec((1, f_pad), lambda t: (0, 0)),
+            pl.BlockSpec((1, fb), lambda t: (0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((2, n_nodes, fb), lambda t: (0, 0, 0)),
-            pl.BlockSpec((2, n_nodes), lambda t: (0, 0)),
-            pl.BlockSpec((1, n_nodes), lambda t: (0, 0)),
-            pl.BlockSpec(
-                (sample_block, 1),
-                lambda t: (jnp.where(t < ns, 0, t - ns), 0),
-            ),
+            pl.BlockSpec((n_nodes, 2), lambda t: (0, 0)),
+            pl.BlockSpec((n_nodes, 1), lambda t: (0, 0)),
+            pl.BlockSpec((1, sample_block), lambda t: (0, phase_c(t))),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((2, n_nodes, fb), jnp.float32),
-            jax.ShapeDtypeStruct((2, n_nodes), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_nodes), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            out_struct((2, n_nodes, fb), jnp.float32, *operands),
+            out_struct((n_nodes, 2), jnp.int32, *operands),
+            out_struct((n_nodes, 1), jnp.float32, *operands),
+            out_struct((1, n), jnp.int32, *operands),
         ],
         scratch_shapes=[pltpu.VMEM((2 * l_sub, fb), jnp.float32)],
         interpret=interpret,
-    )(
-        bins,
-        node_ids[:, None],
-        grad[:, None],
-        hess[:, None],
-        row_map[:, None],
-        parent_hist.reshape(2, l_sub, fb),
-        feat_mask[None, :].astype(jnp.float32),
-        params,
-    )
+    )(*operands)
     return (
         hist.reshape(2, n_nodes, f_pad, n_bins),
-        split[0],
-        split[1],
-        gain[0],
-        new_node[:, 0],
+        split[:, 0],
+        split[:, 1],
+        gain[:, 0],
+        new_node[0],
     )
 
 
@@ -312,13 +305,15 @@ def fused_level_fits(
     n_bins: int,
     budget: int = FUSED_VMEM_BUDGET,
 ) -> bool:
-    """Whether one fused level fits the VMEM budget at its tuned blocks."""
+    """Whether one fused level fits the VMEM budget at its tuned blocks
+    (priced at the padded feature width the kernel runs)."""
     from repro.kernels import autotune
 
     blocks = autotune.lookup(n, n_feat, n_bins, n_nodes)
+    f_pad, _ = autotune.feature_tiling(n_feat, n_bins, blocks["feature_block"])
     return (
         fused_level_vmem_bytes(
-            n_nodes, n_sub, n_feat, n_bins,
+            n_nodes, n_sub, f_pad, n_bins,
             blocks["sample_block"], blocks["feature_block"],
         )
         <= budget
@@ -338,7 +333,7 @@ def fused_level_vmem_bytes(
     Resident blocks: the built-row accumulator (2*L_sub, F, B), the parent
     cache (2, L_sub, F, B), the level-histogram output window
     (2, L, F, B), the (S_blk, F) bins block, and phase B's scan
-    temporaries (~3 extra (L, F, B) values for cumsums and the gain).
+    temporaries (~3 extra (L, F, B) values for prefix sums and the gain).
     The learner falls back to the staged path for any level whose estimate
     exceeds the budget — deep wide levels, where histogram tiling is the
     right call anyway.

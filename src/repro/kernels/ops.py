@@ -129,7 +129,6 @@ def build_histogram_sparse(
     n_bins: int,
     backend: str = "auto",
     entry_block: int = 512,
-    feature_block: int = 8,
     axis_name: str | None = None,
     active_nodes: jax.Array | None = None,
 ) -> jax.Array:
@@ -163,7 +162,8 @@ def build_histogram_sparse(
             out = collectives.psum(out, axis_name)
         return out
     interpret = jax.default_backend() != "tpu"
-    fb = min(feature_block, max(feat_rows.shape[0], 1))
+    # Features ride the sublane axis here: blocks of 8, or all of them.
+    fb = min(8, max(feat_rows.shape[0], 1))
     stored = histogram_sparse_pallas(
         feat_rows, feat_codes, node_ids, grad, hess, n_nodes, n_bins,
         entry_block=entry_block, feature_block=fb, interpret=interpret,
@@ -184,8 +184,8 @@ def build_histogram(
     n_nodes: int,
     n_bins: int,
     backend: str = "auto",
-    sample_block: int = 512,
-    feature_block: int = 8,
+    sample_block: int | None = None,
+    feature_block: int | None = None,
     axis_name: str | None = None,
 ) -> jax.Array:
     """(2, n_nodes, F, n_bins) grad/hess histograms. See kernels/histogram.py.
@@ -200,33 +200,15 @@ def build_histogram(
     the kernel and the results merge with a psum across the axis — every
     cell is a sum over disjoint sample subsets, so partial sums compose
     exactly (the parameter-server aggregation as an all-reduce).
-    """
-    from repro.trees.binning import SparseBins  # lazy: trees imports kernels
 
-    if isinstance(bins, SparseBins):
-        return build_histogram_sparse(
-            bins.feat_rows, bins.feat_codes, bins.zero_bin,
-            node_ids, grad, hess, n_nodes, n_bins, backend=backend,
-            feature_block=feature_block, axis_name=axis_name,
-        )
-    backend = resolve_backend(backend)
-    if backend == "ref":
-        out = _ref.histogram_ref(bins, node_ids, grad, hess, n_nodes, n_bins)
-    else:
-        interpret = jax.default_backend() != "tpu"
-        n_feat = bins.shape[1]
-        fb = min(feature_block, n_feat)
-        binsp = _pad_to(_pad_to(bins, sample_block, 0, 0), fb, 1, 0)
-        nodep = _pad_to(node_ids, sample_block, 0, -1)  # padded samples inactive
-        gradp = _pad_to(grad, sample_block, 0, 0.0)
-        hessp = _pad_to(hess, sample_block, 0, 0.0)
-        out = histogram_pallas(
-            binsp, nodep, gradp, hessp, n_nodes, n_bins,
-            sample_block=sample_block, feature_block=fb, interpret=interpret,
-        )[:, :, :n_feat, :]
-    if axis_name is not None:
-        out = collectives.psum(out, axis_name)
-    return out
+    Blocks default to ``kernels.autotune.lookup`` for the geometry — the
+    same blocks the fused level program takes, so the two stay bitwise.
+    """
+    return build_histogram_subset(
+        bins, node_ids, grad, hess, None, n_nodes, n_bins, backend=backend,
+        sample_block=sample_block, feature_block=feature_block,
+        axis_name=axis_name,
+    )
 
 
 def build_histogram_subset(
@@ -234,12 +216,12 @@ def build_histogram_subset(
     node_ids: jax.Array,
     grad: jax.Array,
     hess: jax.Array,
-    active_nodes: jax.Array,  # (n_sub,) int32 node ids to build
+    active_nodes: jax.Array | None,  # (n_sub,) int32 node ids; None = all
     n_nodes: int,
     n_bins: int,
     backend: str = "auto",
-    sample_block: int = 512,
-    feature_block: int = 8,
+    sample_block: int | None = None,
+    feature_block: int | None = None,
     axis_name: str | None = None,
 ) -> jax.Array:
     """(2, n_sub, F, n_bins) histograms for the ``active_nodes`` subset only.
@@ -258,31 +240,38 @@ def build_histogram_subset(
     """
     from repro.trees.binning import SparseBins  # lazy: trees imports kernels
 
+    if active_nodes is not None:
+        active_nodes = active_nodes.astype(jnp.int32)
     if isinstance(bins, SparseBins):
         return build_histogram_sparse(
             bins.feat_rows, bins.feat_codes, bins.zero_bin,
             node_ids, grad, hess, n_nodes, n_bins, backend=backend,
-            feature_block=feature_block, axis_name=axis_name,
-            active_nodes=active_nodes.astype(jnp.int32),
+            axis_name=axis_name, active_nodes=active_nodes,
         )
     backend = resolve_backend(backend)
-    active_nodes = active_nodes.astype(jnp.int32)
-    if backend == "ref":
+    if backend == "ref" and active_nodes is None:
+        out = _ref.histogram_ref(bins, node_ids, grad, hess, n_nodes, n_bins)
+    elif backend == "ref":
         out = _ref.histogram_subset_ref(
             bins, node_ids, grad, hess, active_nodes, n_nodes, n_bins
         )
     else:
-        interpret = jax.default_backend() != "tpu"
-        n_feat = bins.shape[1]
-        fb = min(feature_block, n_feat)
-        binsp = _pad_to(_pad_to(bins, sample_block, 0, 0), fb, 1, 0)
-        nodep = _pad_to(node_ids, sample_block, 0, -1)  # padded samples inactive
-        gradp = _pad_to(grad, sample_block, 0, 0.0)
-        hessp = _pad_to(hess, sample_block, 0, 0.0)
+        from repro.kernels import autotune
+
+        n, n_feat = bins.shape
+        blocks = autotune.lookup(n, n_feat, n_bins, n_nodes)
+        sb = sample_block or blocks["sample_block"]
+        f_pad, fb = autotune.feature_tiling(
+            n_feat, n_bins, feature_block or blocks["feature_block"]
+        )
+        binsp = _pad_to(_pad_to(bins, sb, 0, 0), f_pad, 1, 0)
+        nodep = _pad_to(node_ids, sb, 0, -1)  # padded samples inactive
+        gradp = _pad_to(grad, sb, 0, 0.0)
+        hessp = _pad_to(hess, sb, 0, 0.0)
         out = histogram_pallas(
             binsp, nodep, gradp, hessp, n_nodes, n_bins,
-            sample_block=sample_block, feature_block=fb, interpret=interpret,
-            active_nodes=active_nodes,
+            sample_block=sb, feature_block=fb,
+            interpret=jax.default_backend() != "tpu", active_nodes=active_nodes,
         )[:, :, :n_feat, :]
     if axis_name is not None:
         out = collectives.psum(out, axis_name)
@@ -294,38 +283,24 @@ def split_gain(
     lam,
     min_child_hess,
     backend: str = "auto",
-    node_block: int = 8,
-    feature_block: int = 8,
+    feature_block: int | None = None,
 ) -> jax.Array:
-    """Gain surface (L, F, B), -inf where invalid."""
+    """Gain surface (L, F, B), -inf where invalid. The kernel takes all L
+    nodes per tile, like the fused level program (same dot shapes)."""
+    from repro.kernels import autotune
+
     backend = resolve_backend(backend)
     lam = jnp.asarray(lam, jnp.float32)
     minh = jnp.asarray(min_child_hess, jnp.float32)
     if backend == "ref":
-        return _split_gain_surface_ref(hist, lam, minh)
-    interpret = jax.default_backend() != "tpu"
-    _, l, f, _ = hist.shape
-    lb = min(node_block, l)
-    fb = min(feature_block, f)
-    histp = _pad_to(_pad_to(hist, lb, 1, 0.0), fb, 2, 0.0)
+        return _ref.split_gain_surface_ref(hist, lam, minh)
+    _, _, f, b = hist.shape
+    f_pad, fb = autotune.feature_tiling(f, b, feature_block)
     out = split_gain_pallas(
-        histp, lam, minh, node_block=lb, feature_block=fb, interpret=interpret
+        _pad_to(hist, f_pad, 2, 0.0), lam, minh, feature_block=fb,
+        interpret=jax.default_backend() != "tpu",
     )
-    return out[:l, :f, :]
-
-
-@jax.jit
-def _split_gain_surface_ref(hist, lam, min_h):
-    """Same surface as the kernel, via jnp (shared with split_scan_ref)."""
-    g, h = hist[0], hist[1]
-    gl = jnp.cumsum(g, axis=-1)
-    hl = jnp.cumsum(h, axis=-1)
-    gt, ht = gl[..., -1:], hl[..., -1:]
-    gr, hr = gt - gl, ht - hl
-    gain = gl**2 / (hl + lam) + gr**2 / (hr + lam) - gt**2 / (ht + lam)
-    valid = (hl >= min_h) & (hr >= min_h)
-    valid = valid.at[..., -1].set(False)
-    return jnp.where(valid, gain, -jnp.inf)
+    return out[:, :f, :]
 
 
 def best_split(
@@ -382,21 +357,20 @@ def level_build(
     from repro.kernels.level_build import level_build_pallas
 
     n, n_feat = bins.shape
-    if sample_block is None or feature_block is None:
-        tuned = autotune.lookup(n, n_feat, n_bins, n_nodes)
-        sample_block = sample_block or tuned["sample_block"]
-        feature_block = feature_block or tuned["feature_block"]
+    tuned = autotune.lookup(n, n_feat, n_bins, n_nodes)
+    sb = sample_block or tuned["sample_block"]
+    f_pad, fb = autotune.feature_tiling(
+        n_feat, n_bins, feature_block or tuned["feature_block"]
+    )
     interpret = jax.default_backend() != "tpu"
-    sb = min(sample_block, max(n, 1))
-    fb = min(feature_block, n_feat)
-    binsp = _pad_to(_pad_to(bins, sb, 0, 0), fb, 1, 0)
+    binsp = _pad_to(_pad_to(bins, sb, 0, 0), f_pad, 1, 0)
     nodep = _pad_to(node_ids, sb, 0, -1)  # padded samples inactive
     gradp = _pad_to(grad, sb, 0, 0.0)
     hessp = _pad_to(hess, sb, 0, 0.0)
-    maskp = _pad_to(feat_mask.astype(jnp.float32), fb, 0, 0.0)
+    maskp = _pad_to(feat_mask.astype(jnp.float32), f_pad, 0, 0.0)
     parentp = None
     if derive_sibling:
-        parentp = _pad_to(parent_hist, fb, 2, 0.0)
+        parentp = _pad_to(parent_hist, f_pad, 2, 0.0)
     hist, feat, thr, best, new_node = level_build_pallas(
         binsp, nodep, gradp, hessp, active_nodes.astype(jnp.int32), parentp,
         maskp, jnp.asarray(lam, jnp.float32),

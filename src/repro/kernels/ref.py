@@ -118,15 +118,7 @@ def level_build_ref(
     else:
         hist = built  # active_nodes must enumerate 0..n_nodes-1 in order
 
-    g, h = hist[0], hist[1]
-    gl = jnp.cumsum(g, axis=-1)
-    hl = jnp.cumsum(h, axis=-1)
-    gt, ht = gl[..., -1:], hl[..., -1:]
-    gr, hr = gt - gl, ht - hl
-    gain = gl**2 / (hl + lam) + gr**2 / (hr + lam) - gt**2 / (ht + lam)
-    valid = (hl >= min_child_hess) & (hr >= min_child_hess)
-    valid = valid.at[..., -1].set(False)
-    gain = jnp.where(valid, gain, -jnp.inf)
+    gain = split_gain_surface_ref(hist, lam, min_child_hess)
     gain = jnp.where(feat_mask[None, :, None] > 0, gain, -jnp.inf)
 
     flat = gain.reshape(n_nodes, -1)
@@ -182,21 +174,38 @@ def histogram_sparse_subset_ref(
     )
 
 
+def bin_prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum over the last (bin) axis as a log-step
+    (Hillis-Steele) scan: step k adds the value k bins to the left (zero
+    below bin k), for k = 1, 2, 4, ...
+
+    The values are ``cumsum``'s up to rounding; the fixed operand order is
+    the one the kernels' lane scan (``split_scan.bin_prefix_sums``) uses, so
+    equal histograms scan to bitwise-equal sums on every backend.
+    """
+    b = x.shape[-1]
+    k = 1
+    while k < b:
+        shifted = jnp.pad(x[..., :-k], [(0, 0)] * (x.ndim - 1) + [(k, 0)])
+        x = x + shifted
+        k *= 2
+    return x
+
+
 @jax.jit
-def split_scan_ref(
+def split_gain_surface_ref(
     hist: jax.Array,  # (2, L, F, B) f32 grad/hess histograms
     lam: jax.Array,  # scalar L2 regularizer
     min_child_hess: jax.Array,  # scalar: both children need >= this hessian mass
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Best split per node from histograms.
+) -> jax.Array:
+    """Gain surface (L, F, B), -inf where invalid — the split kernel's oracle.
 
-    Returns (best_gain (L,), best_feature (L,) int32, best_bin (L,) int32).
     gain = GL^2/(HL+lam) + GR^2/(HR+lam) - G^2/(H+lam); splitting at bin b
     sends bins <= b left. The last bin is not a valid split point.
     """
     g, h = hist[0], hist[1]  # (L, F, B)
-    gl = jnp.cumsum(g, axis=-1)  # left sums, inclusive
-    hl = jnp.cumsum(h, axis=-1)
+    gl = bin_prefix_sum(g)  # left sums, inclusive
+    hl = bin_prefix_sum(h)
     gt = gl[..., -1:]  # totals (L, F, 1)
     ht = hl[..., -1:]
     gr = gt - gl
@@ -205,7 +214,21 @@ def split_scan_ref(
     gain = gl**2 / (hl + lam) + gr**2 / (hr + lam) - parent  # (L, F, B)
     valid = (hl >= min_child_hess) & (hr >= min_child_hess)
     valid = valid.at[..., -1].set(False)
-    gain = jnp.where(valid, gain, -jnp.inf)
+    return jnp.where(valid, gain, -jnp.inf)
+
+
+@jax.jit
+def split_scan_ref(
+    hist: jax.Array,  # (2, L, F, B) f32 grad/hess histograms
+    lam: jax.Array,
+    min_child_hess: jax.Array,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Best split per node from histograms.
+
+    Returns (best_gain (L,), best_feature (L,) int32, best_bin (L,) int32),
+    the first maximum of ``split_gain_surface_ref`` in (f * B + b) order.
+    """
+    gain = split_gain_surface_ref(hist, lam, min_child_hess)
     flat = gain.reshape(gain.shape[0], -1)  # (L, F*B)
     idx = jnp.argmax(flat, axis=-1)
     best_gain = jnp.take_along_axis(flat, idx[:, None], axis=-1)[:, 0]

@@ -7,78 +7,123 @@ HBM (cumsum-g, cumsum-h, gain, validity). The kernel fuses the whole
 pipeline per VMEM tile so each histogram element is read from HBM exactly
 once and only the (L, F, B) gain surface is written back.
 
-Grid: (node_blocks, feature_blocks); each program owns a (L_blk, F_blk, B)
-tile — the bin axis is never split because the prefix sum runs along it.
+Layout: histograms travel as (L, F * B) — bins of one feature are adjacent
+lanes — so no kernel ever splits the lane dimension (Mosaic cannot).
+Mosaic has no ``cumsum`` lowering either; the prefix sum is a log-step
+(Hillis-Steele) scan of lane rolls and adds inside each B-lane run
+(``bin_prefix_sums``), and ``ref.bin_prefix_sum`` adds the same operands
+in the same order, so kernel and oracle scans agree bit for bit on equal
+histograms. ``level_build`` calls the same ``split_gain_tile``, so fused
+and staged gains agree bit for bit too.
+
+Grid: (feature_blocks,); each program owns an (L, F_blk * B) tile of all
+nodes.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.vma import out_struct
 
-def _split_kernel(g_ref, h_ref, params_ref, gain_ref):
-    g = g_ref[...]  # (L_blk, F_blk, B)
-    h = h_ref[...]
-    # Scalars ride in SMEM via scalar prefetch — available before the tile
-    # DMA lands, and never occupying a (1, 1) vector tile like the old
-    # ``pl.ANY`` placement did.
-    lam = params_ref[0]
-    min_h = params_ref[1]
+LANES = 128
 
-    gl = jnp.cumsum(g, axis=-1)
-    hl = jnp.cumsum(h, axis=-1)
-    gt = gl[..., -1:]
-    ht = hl[..., -1:]
+
+def scan_width(n_bins: int) -> int:
+    """Lanes per whole group of bin runs: the least common multiple of
+    ``n_bins`` and 128, so padded features fill whole lane tiles — or
+    ``n_bins`` itself when that multiple is large."""
+    width = math.lcm(n_bins, LANES)
+    return width if width <= 4 * LANES else n_bins
+
+
+def bin_prefix_sums(x: jax.Array, n_bins: int) -> tuple[jax.Array, jax.Array]:
+    """``(inclusive prefix, run total)`` of every B-lane run of ``x`` (R, F*B).
+
+    Step k of the scan adds each lane's value k lanes to its left within
+    the run (zero at the run's start): ``ref.bin_prefix_sum``'s operands
+    and order. The run total is then broadcast from the run's last lane by
+    the mirrored scan, which adds only zeros to it — exact.
+    """
+    lanes = x.shape[1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) % n_bins
+    k = 1
+    while k < n_bins:
+        x = x + jnp.where(pos >= k, pltpu.roll(x, k, 1), 0.0)
+        k *= 2
+    total = jnp.where(pos == n_bins - 1, x, 0.0)
+    k = 1
+    while k < n_bins:
+        total = total + jnp.where(
+            pos + k < n_bins, pltpu.roll(total, lanes - k, 1), 0.0
+        )
+        k *= 2
+    return x, total
+
+
+def split_gain_tile(g, h, lam, min_h, n_bins: int):
+    """``(gain, valid)`` over an (L, F*B) grad/hess tile pair — the one
+    gain formula both the split kernel and the fused level program run."""
+    gl, gt = bin_prefix_sums(g, n_bins)
+    hl, ht = bin_prefix_sums(h, n_bins)
     gr = gt - gl
     hr = ht - hl
     parent = gt * gt / (ht + lam)
     gain = gl * gl / (hl + lam) + gr * gr / (hr + lam) - parent
+    bin_pos = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1) % n_bins
+    valid = (hl >= min_h) & (hr >= min_h) & (bin_pos < n_bins - 1)
+    return gain, valid
 
-    nb = g.shape[-1]
-    bin_pos = jax.lax.broadcasted_iota(jnp.int32, g.shape, 2)
-    valid = (hl >= min_h) & (hr >= min_h) & (bin_pos < nb - 1)
+
+def _split_kernel(g_ref, h_ref, params_ref, gain_ref, *, n_bins: int):
+    # Scalars ride in SMEM via scalar prefetch — available before the tile
+    # DMA lands, and never occupying a (1, 1) vector tile.
+    gain, valid = split_gain_tile(
+        g_ref[...], h_ref[...], params_ref[0], params_ref[1], n_bins
+    )
     gain_ref[...] = jnp.where(valid, gain, -jnp.inf)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("node_block", "feature_block", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("feature_block", "interpret"))
 def split_gain_pallas(
     hist: jax.Array,  # (2, L, F, B) f32
     lam: jax.Array,  # scalar
     min_child_hess: jax.Array,
-    node_block: int = 8,
     feature_block: int = 8,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Gain surface (L, F, B); invalid split points are -inf.
 
+    ``F`` must be a multiple of ``feature_block`` and ``feature_block * B``
+    a multiple of ``scan_width(B)`` (the ``kernels.ops`` wrapper pads).
     ``interpret=None`` auto-detects (Mosaic on TPU, interpreter elsewhere).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     _, l, f, b = hist.shape
-    assert l % node_block == 0 and f % feature_block == 0
+    lanes = feature_block * b
+    assert f % feature_block == 0 and lanes % scan_width(b) == 0
     params = jnp.stack([
         jnp.asarray(lam, jnp.float32),
         jnp.asarray(min_child_hess, jnp.float32),
     ])  # (2,) SMEM-resident scalars
+    flat = hist.reshape(2, l, f * b)
 
-    return pl.pallas_call(
-        _split_kernel,
-        grid=(l // node_block, f // feature_block),
+    gain = pl.pallas_call(
+        functools.partial(_split_kernel, n_bins=b),
+        grid=(f // feature_block,),
         in_specs=[
-            pl.BlockSpec((node_block, feature_block, b), lambda lb, fb: (lb, fb, 0)),
-            pl.BlockSpec((node_block, feature_block, b), lambda lb, fb: (lb, fb, 0)),
+            pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
+            pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec(
-            (node_block, feature_block, b), lambda lb, fb: (lb, fb, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((l, f, b), jnp.float32),
+        out_specs=pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
+        out_shape=out_struct((l, f * b), jnp.float32, hist, params),
         interpret=interpret,
-    )(hist[0], hist[1], params)
+    )(flat[0], flat[1], params)
+    return gain.reshape(l, f, b)

@@ -32,6 +32,7 @@ import numpy as np
 
 import repro.configs as configs
 import repro.sharding as sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import init_params
@@ -216,6 +217,7 @@ def main() -> None:
     ap.add_argument("--slo-ms", type=float, default=50.0,
                     help="latency SLO for continuous-engine wave cutting")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "gbdt":
         return run_gbdt(args)

@@ -26,6 +26,7 @@ import numpy as np
 
 import repro.configs as configs
 import repro.sharding as sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import init_params
@@ -122,12 +123,12 @@ def run_gbdt(args) -> None:
         return run_gbdt_threads(args, cfg, data, obj)
     mesh = None
     if args.mesh != "none":
-        from repro.launch.mesh import make_gbdt_mesh
+        from repro.launch.mesh import make_gbdt_mesh, make_mesh
 
         shape = args.mesh_shape or ("2" if args.mesh == "1d" else "1x2")
         if args.mesh == "1d":
             pd, pf = int(shape.partition("x")[0]), 1
-            mesh = jax.make_mesh((pd,), ("data",))
+            mesh = make_mesh((pd,), ("data",))
         else:
             pd, _, pf = shape.partition("x")
             pd, pf = int(pd), int(pf or 1)
@@ -377,6 +378,7 @@ def main() -> None:
                     help="GBDT objective registry spec: logistic | mse | "
                          "quantile[:a] | huber | multiclass:K | lambdarank")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "gbdt":
         return run_gbdt(args)

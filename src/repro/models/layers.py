@@ -26,23 +26,6 @@ from repro.models.config import ModelConfig
 Params = dict[str, Any]
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map``/``check_vma`` on
-    newer releases, ``jax.experimental``/``check_rep`` on older ones. Both
-    flags disable the replication checker, which rejects the MoE body's
-    axis_index-dependent routing."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 # ------------------------------------------------------------------- basics
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     # NB: keep the f32 upcast as an explicit astype: the astype boundary is
@@ -380,7 +363,9 @@ def moe_ffn(
         return out.reshape(xb.shape), aux
 
     bspec = P(batch_axes or None, None, None)
-    out, aux = _shard_map(
+    # check_vma=False: the replication checker rejects the body's
+    # axis_index-dependent routing.
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(bspec, P(None, None),
@@ -388,5 +373,6 @@ def moe_ffn(
                   P(model_axis, None, ff_axis),
                   P(model_axis, ff_axis, None)),
         out_specs=(bspec, P()),
+        check_vma=False,
     )(x, p["wr"], p["wg"], p["wu"], p["wd"])
     return out, aux

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from repro.core.sgbdt import SGBDTConfig, TrainState, init_state
 from repro.data.sampling import bernoulli_weights
 from repro.ps.schedules import max_staleness, resolve_schedule
-from repro.trees.binning import BinnedData
+from repro.trees.binning import BinnedData, SparseBins
 from repro.trees.forest import forest_push
 from repro.trees.learner import build_tree, build_tree_multi
 from repro.trees.tree import Tree, apply_tree, apply_tree_stack
@@ -228,6 +228,31 @@ class Trainer:
         self._loop_cache: dict[int, Callable] = {}
         self._scan_cache: dict[int, Callable] = {}
 
+    def place(self, data: BinnedData) -> BinnedData:
+        """Lay the dataset out on the mesh once, the way the sharded
+        builder reads it (``sharding.gbdt_data_specs``): rows over the
+        data axis, feature columns over the feature axis. ``train`` and
+        ``scan_with`` call this, so no round reshards the bins. Without a
+        mesh the data is returned unchanged."""
+        if self.mesh is None:
+            return data
+        from jax.sharding import NamedSharding
+
+        from repro.sharding import gbdt_data_specs
+
+        specs = gbdt_data_specs(self.mesh, sparse=isinstance(data.bins, SparseBins))
+
+        def put(x, spec):
+            return jax.device_put(x, NamedSharding(self.mesh, spec))
+
+        return data._replace(
+            bins=jax.tree.map(put, data.bins, specs.bins),
+            bin_edges=put(data.bin_edges, specs.bin_edges),
+            labels=put(data.labels, specs.labels),
+            multiplicity=put(data.multiplicity, specs.multiplicity),
+            qid=None if data.qid is None else put(data.qid, specs.labels),
+        )
+
     def collective_bytes(self, data: BinnedData) -> dict | None:
         """MEASURED per-tree-build collective bytes on this trainer's mesh
         (trace-time accounting; see ``ps.sharded.collective_bytes_per_build``).
@@ -280,6 +305,7 @@ class Trainer:
         eval_fn: Callable[[TrainState, int], None] | None = None,
     ) -> TrainState:
         """Python-loop execution with per-round eval hooks."""
+        data = self.place(data)
         sched, ring_size, keys, state, ring = self._prep(data, schedule, seed)
         if ring_size not in self._loop_cache:
             self._loop_cache[ring_size] = jax.jit(self._step(ring_size))
@@ -316,6 +342,7 @@ class Trainer:
         """Whole run as one ``lax.scan`` over an explicit (k(j), keys) pair;
         returns per-round train losses too. The program the dry-run lowers."""
         cfg = self.cfg
+        data = self.place(data)
         if ring_size not in self._scan_cache:
             step = self._step(ring_size)
 

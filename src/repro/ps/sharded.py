@@ -27,11 +27,7 @@ from __future__ import annotations
 import functools
 
 import jax
-
-try:  # moved out of jax.experimental on newer jax releases
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import collectives

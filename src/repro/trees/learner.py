@@ -35,13 +35,12 @@ Backends (``LearnerConfig.backend``), resolved through the shared
     collective seam forces the staged order (see ``ps/sharded.py``);
   * ``'auto'`` — pallas on TPU, ref elsewhere.
 The fused program is bit-compatible with the staged pallas path at MATCHED
-block shapes (same dot shapes in the same order). In the learner the fused
-path takes its blocks from the committed autotuner table
-(``kernels/autotune.py``), which may group the accumulation differently
-than the staged defaults — cross-backend runs then agree like the hist
-modes do: identically wherever gains are decisively separated, with
-near-tied deep splits free to flip within f32 tolerance. DESIGN.md §13
-documents both contracts.
+block shapes (same dot shapes in the same order). In the learner both take
+their blocks from ``kernels.autotune.lookup`` for the level's geometry, so
+they match; against the ``ref`` oracle (scatter-add histograms) the
+backends agree like the hist modes do: identically wherever gains are
+decisively separated, with near-tied deep splits free to flip within f32
+tolerance. DESIGN.md §13 documents both contracts.
 
 Conventions:
   * Caller supplies per-sample (g_i, h_i). For the paper's plain gradient
@@ -225,7 +224,7 @@ def _staged_level(
         lo = jax.lax.axis_index(cfg.feature_axis) * f_local
         owned = (f_of >= lo) & (f_of < lo + f_local)
         col = jnp.clip(f_of - lo, 0, f_local - 1)
-        v = jnp.take_along_axis(route_bins, col[:, None], axis=1)[:, 0]
+        v = gather_feature_bins(route_bins, col)
         v = jnp.where(owned, v, 0).astype(jnp.uint8)
         val = collectives.psum(v, cfg.feature_axis).astype(jnp.int32)
     else:

@@ -9,10 +9,7 @@ leaves bitwise lockstep with the single-device build.
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -26,8 +26,8 @@ _SCRIPT = textwrap.dedent(
     from repro.launch.steps import make_decode_step, make_train_step
 
     assert jax.device_count() == 8
-    from repro.launch.mesh import _mesh
-    mesh = _mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
 
     results = {}
     key = jax.random.PRNGKey(0)

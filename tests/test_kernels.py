@@ -1,20 +1,11 @@
 """Per-kernel correctness: Pallas (interpret mode) vs the pure-jnp oracle,
-swept over shapes/dtypes, plus property tests on the semantics.
-
-The property tests run under hypothesis when it is installed (CI pins it in
-requirements.txt); on containers without it they degrade to a fixed-seed
-parametrized sweep of the same checks instead of dying at collection
-(see tests/_hypothesis_compat.py).
+swept over shapes/dtypes, plus hypothesis property tests on the semantics.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.histogram import histogram_pallas

@@ -1,10 +1,7 @@
 """Data pipeline: packing invariants + deterministic sharded resumption."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.pipeline import TokenPipeline, pack_documents
 

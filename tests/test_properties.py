@@ -1,8 +1,7 @@
 """Property-based invariants of the histogram/tree substrate.
 
-Randomized draws (hypothesis when installed, the deterministic fallback of
-``tests/_hypothesis_compat.py`` otherwise) over the algebraic contracts the
-subtraction builder leans on:
+Hypothesis draws over the algebraic contracts the subtraction builder
+leans on:
 
   * parent histogram == left child + right child (the subtraction identity);
   * histogram totals == masked ``segment_sum`` (no mass invented or lost);
@@ -15,10 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.trees.learner import LearnerConfig, build_tree, build_tree_multi

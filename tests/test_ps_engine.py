@@ -160,11 +160,12 @@ _SHARD_SCRIPT = textwrap.dedent(
 
     import repro.data as D
     from repro.kernels import ref
+    from repro.launch.mesh import make_mesh
     from repro.ps.sharded import build_histogram_sharded, make_sharded_builder
     from repro.trees.learner import LearnerConfig, build_tree
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
 
     key = jax.random.PRNGKey(0)
     k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -310,7 +311,7 @@ _SHARD2D_SCRIPT = textwrap.dedent(
     import repro.data as D
     from repro.core.sgbdt import init_state
     from repro import checkpoint
-    from repro.launch.mesh import make_gbdt_mesh
+    from repro.launch.mesh import make_gbdt_mesh, make_mesh
     from repro.ps.engine import Trainer
     from repro.ps.runtime import RunTrace, replay_trace
     from repro.ps.sharded import (
@@ -348,7 +349,7 @@ _SHARD2D_SCRIPT = textwrap.dedent(
 
     # (2, 4) vs a plain 2-shard 1D mesh: identical data-psum structure,
     # so adding the feature axis changes NOTHING — bitwise incl. leaves.
-    mesh_1d = jax.make_mesh((2,), ("data",))
+    mesh_1d = make_mesh((2,), ("data",))
     t_1d = make_sharded_builder(cfg, mesh_1d)(data.bins, g, h, key)
     mesh_24 = make_gbdt_mesh(2, 4)
     t_24 = make_sharded_builder_2d(cfg, mesh_24)(data.bins, g, h, key)
