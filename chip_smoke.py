@@ -15,9 +15,13 @@ through ``ForestEngine`` and checks the scores against
 ``ref.forest_traverse_ref``.
 
 Four chips (``--chips 4``): trains on the (4, 1), (2, 2) and (1, 4)
-(data x feature) meshes, the last with dense bins and with ``SparseBins``,
-and compares each forest with its contract: (1, P_f) is bitwise equal to
-the single-device Trainer, (P_d, P_f) to the 1D P_d-shard build.
+(data x feature) meshes, dense at the HIGGS geometry and, on (1, 4), a
+``SparseBins`` set at real-sim's published geometry, and compares each
+forest with its contract: (1, P_f) is bitwise equal to the single-device
+Trainer, (P_d, P_f) to the 1D P_d-shard build. It also checks that the
+(4, 1) layout draws the same per-round sampling weights and gradients as
+one device, and measures how far each one's first-round leaves sit from
+their float64 sums.
 
 Every phase prints its wall seconds, its compile seconds and its hits in
 JAX's persistent compilation cache. Any failed check raises. The script
@@ -41,10 +45,11 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 HIGGS_ROWS, HIGGS_FEATURES = 11_000_000, 28  # the published HIGGS geometry
-MIN_ROWS = 2_097_152
+# real-sim (LIBSVM binary datasets): 72,309 x 20,958 with 3,709,083
+# nonzeros, 51.3 per row.
+REALSIM_ROWS, REALSIM_FEATURES, REALSIM_NNZ = 72_309, 20_958, 51
 SEED = 0
 ROUNDS, WORKERS = 8, 4
-SPARSE_SHAPE = (65_536, 2_048, 52)  # rows, features, nnz per row: real-sim-like
 SERVE_SLOTS = 1000  # the paper's Higgs tree count
 REQUEST_ROWS = (1, 17, 256, 300, 1000)
 # Leaves are -G / (H + lam) over the same samples on every backend once the
@@ -130,6 +135,30 @@ def higgs_data(rows: int):
     return host_binned(
         lambda: D.make_dense_low_diversity(rows, HIGGS_FEATURES, rows, seed=SEED)
     )
+
+
+def realsim_data(feature_multiple: int):
+    """real-sim's geometry in ``SparseBins``, widened by empty features
+    (nothing stored, zero bin 0) until the width divides
+    ``feature_multiple``: ``shard_map`` splits the feature axis evenly. An
+    empty feature puts every sample in one bin, so it never wins a split."""
+    import repro.data as D
+
+    def make():
+        data = D.make_sparse_classification(
+            REALSIM_ROWS, REALSIM_FEATURES, REALSIM_NNZ, seed=SEED, sparse=True
+        )
+        sp, pad = data.bins, -REALSIM_FEATURES % feature_multiple
+        rows = lambda x, v: jnp.pad(x, ((0, pad), (0, 0)), constant_values=v)  # noqa: E731
+        return data._replace(
+            bins=sp._replace(
+                feat_rows=rows(sp.feat_rows, -1), feat_codes=rows(sp.feat_codes, 0),
+                zero_bin=jnp.pad(sp.zero_bin, (0, pad)),
+            ),
+            bin_edges=rows(data.bin_edges, 0.0),
+        )
+
+    return host_binned(make)
 
 
 def same_forest(a, b) -> bool:
@@ -243,22 +272,89 @@ def one_chip(meter: CompileMeter, report: dict) -> None:
 
 
 # --------------------------------------------------------------- four chips
+def sample_and_grad(cfg):
+    """``propose_tree``'s sampling and gradient step as one program: the
+    Bernoulli weights m' and the weighted grad/hess the tree build sums."""
+    from repro.data.sampling import bernoulli_weights
+
+    @jax.jit
+    def run(data, f, key):
+        r_sample, _ = jax.random.split(key)
+        m, _ = bernoulli_weights(r_sample, cfg.sampling_rate, data.multiplicity)
+        g, h = cfg.obj.grad_hess(data.labels, f, qid=data.qid)
+        return m, m * g, (m * h if cfg.step_kind == "newton" else m)
+
+    return run
+
+
+def row_layout_witness(cfg, dense, single, m41, f_single, report: dict) -> bool:
+    """Why the (4, 1) forest's leaves differ from one device's: are the
+    per-round draws and gradients the same, and how far is each forest's
+    first tree (built on the identical initial predictions) from the float64
+    leaf sums over its own routing?"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.sgbdt import init_state
+    from repro.launch.mesh import make_gbdt_mesh
+    from repro.ps import Trainer
+    from repro.trees.tree import Tree, leaf_indices
+
+    mesh = make_gbdt_mesh(4, 1)
+    placed = Trainer(cfg, mesh=mesh).place(dense)
+    rows = lambda x: jax.device_put(x, NamedSharding(mesh, P("data")))  # noqa: E731
+    run = sample_and_grad(cfg)
+    # The trainer's round keys (Trainer._prep), gradients at the trained f.
+    keys = jax.random.split(jax.random.PRNGKey(SEED), cfg.n_trees)
+    f_rows = rows(f_single)
+    same = []
+    for key in keys:
+        one, four = jax.device_get((run(dense, f_single, key), run(placed, f_rows, key)))
+        same.append([bool(np.array_equal(a, b)) for a, b in zip(one, four)])
+    print("mesh_4x1 vs single, per round [m', m'g, hess weight] bitwise: "
+          f"{same}", flush=True)
+
+    f0 = init_state(cfg, dense).f
+    _, gw, hw = jax.device_get(run(dense, f0, keys[0]))
+    leaf = np.asarray(leaf_indices(
+        Tree(single.feature[0], single.threshold[0], single.leaf_value[0]), dense.bins
+    ))
+    n_leaf = single.leaf_value.shape[1]
+    g64 = np.bincount(leaf, weights=gw.astype(np.float64), minlength=n_leaf)
+    h64 = np.bincount(leaf, weights=hw.astype(np.float64), minlength=n_leaf)
+    exact = cfg.step_length * np.where(h64 > 0, -g64 / (h64 + cfg.learner.lam), 0.0)
+    err = {name: float(np.max(np.abs(f.leaf_value[0] - exact)))
+           for name, f in (("single", single), ("mesh_4x1", m41))}
+    by_slot = np.max(np.abs(m41.leaf_value - single.leaf_value), axis=1)
+    by_slot = [float(x) for x in by_slot[: cfg.n_trees]]
+    print(f"round-0 tree, max |leaf - float64 leaf|: single {err['single']:.3e}, "
+          f"mesh_4x1 {err['mesh_4x1']:.3e}, max |leaf| {np.max(np.abs(exact)):.3e}; "
+          f"mesh_4x1 vs single max |leaf diff| by round {by_slot}", flush=True)
+    report["mesh_4x1_witness"] = {
+        "draws_bitwise_by_round": same, "round0_leaf_err_vs_f64": err,
+        "leaf_diff_by_round": by_slot,
+    }
+    return all(all(s) for s in same)
+
+
 def four_chips(meter: CompileMeter, report: dict) -> None:
-    import repro.data as D
     from repro.launch.mesh import make_gbdt_mesh, make_mesh
     from repro.ps import Trainer
 
     check(len(jax.devices()) >= 4, "four devices are visible")
-    rows = MIN_ROWS
-    n_sp, f_sp, nnz = SPARSE_SHAPE
-    print(f"rows: {rows} x {HIGGS_FEATURES} dense (published HIGGS "
-          f"{HIGGS_ROWS}; cut {HIGGS_ROWS - rows} rows); sparse {n_sp} x {f_sp}, "
-          f"{nnz} nnz/row", flush=True)
-    with meter.phase("data", report):
-        dense = higgs_data(rows)
-        sparse = host_binned(lambda: D.make_sparse_classification(
-            n_sp, f_sp, nnz, seed=SEED, sparse=True
-        ))
+    f_pad = REALSIM_FEATURES + (-REALSIM_FEATURES % 4)
+    print(f"dense: {HIGGS_ROWS} x {HIGGS_FEATURES} (published HIGGS, no cut); "
+          f"sparse: {REALSIM_ROWS} x {REALSIM_FEATURES}, {REALSIM_NNZ} nnz/row "
+          f"(published real-sim), widened by {f_pad - REALSIM_FEATURES} empty "
+          f"features to {f_pad} = 4 x {f_pad // 4}", flush=True)
+    with meter.phase("data_dense", report):
+        dense = higgs_data(HIGGS_ROWS)
+        jax.block_until_ready(dense.bins)
+    with meter.phase("data_sparse", report):
+        sparse = realsim_data(4)
+        jax.block_until_ready(sparse.bins)
+    sp = sparse.bins
+    print(f"sparse store: row ELL {sp.indices.shape}, feature ELL "
+          f"{sp.feat_rows.shape}", flush=True)
     cfg = higgs_config(4)
     schedule = ("round_robin", WORKERS)
 
@@ -270,16 +366,16 @@ def four_chips(meter: CompileMeter, report: dict) -> None:
         with meter.phase(f"train_{name}", report):
             state, _ = trainer.train_scan(placed, schedule, seed=SEED)
             jax.block_until_ready(state.f)
-        return jax.device_get(state.forest)
+        return jax.device_get(state.forest), state.f
 
-    single = train(dense, None, "single")
-    single_sparse = train(sparse, None, "single_sparse")
-    d4 = train(dense, make_mesh((4,), ("data",)), "1d_4")
-    d2 = train(dense, make_mesh((2,), ("data",)), "1d_2")
-    m41 = train(dense, make_gbdt_mesh(4, 1), "mesh_4x1")
-    m22 = train(dense, make_gbdt_mesh(2, 2), "mesh_2x2")
-    m14 = train(dense, make_gbdt_mesh(1, 4), "mesh_1x4")
-    m14s = train(sparse, make_gbdt_mesh(1, 4), "mesh_1x4_sparse")
+    single, f_single = train(dense, None, "single")
+    single_sparse, _ = train(sparse, None, "single_sparse")
+    d4, _ = train(dense, make_mesh((4,), ("data",)), "1d_4")
+    d2, _ = train(dense, make_mesh((2,), ("data",)), "1d_2")
+    m41, _ = train(dense, make_gbdt_mesh(4, 1), "mesh_4x1")
+    m22, _ = train(dense, make_gbdt_mesh(2, 2), "mesh_2x2")
+    m14, _ = train(dense, make_gbdt_mesh(1, 4), "mesh_1x4")
+    m14s, _ = train(sparse, make_gbdt_mesh(1, 4), "mesh_1x4_sparse")
 
     parity = {
         "mesh_1x4 == single (bitwise)": same_forest(m14, single),
@@ -291,12 +387,17 @@ def four_chips(meter: CompileMeter, report: dict) -> None:
                    and np.array_equal(m41.threshold, single.threshold))
     dleaf = float(np.max(np.abs(m41.leaf_value - single.leaf_value)))
     print(f"mesh_4x1 vs single: splits equal {split_equal}, max |leaf diff| "
-          f"{dleaf:.3e} (row psums reorder f32 sums)", flush=True)
+          f"{dleaf:.3e}", flush=True)
+    report["mesh_4x1_vs_single"] = {"splits_equal": split_equal, "max_leaf_diff": dleaf}
+    with meter.phase("witness", report):
+        parity["mesh_4x1 draws == single draws (bitwise)"] = row_layout_witness(
+            cfg, dense, single, m41, f_single, report
+        )
+    report["parity"] = parity
     for name, ok in parity.items():
         print(f"parity {name}: {ok}", flush=True)
+    for name, ok in parity.items():
         check(ok, name)
-    report["parity"] = parity
-    report["mesh_4x1_vs_single"] = {"splits_equal": split_equal, "max_leaf_diff": dleaf}
 
 
 def main() -> None:

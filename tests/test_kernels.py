@@ -103,6 +103,27 @@ def test_split_gain_pallas_matches_ref(key, l, f, b):
     np.testing.assert_allclose(ref_m, pal_m, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("b", [8, 64, 256])
+def test_bin_prefix_sum_matches_cumsum(key, b):
+    """The oracle's log-step scan fixes the kernels' summation order; it
+    must still be a prefix sum. Each output is at most ceil(log2 B)
+    roundings from the exact sum (float64 cumsum); jnp.cumsum's own order
+    adds up to B - 1 more, relative to the running sum of |x|."""
+    kg, kh = jax.random.split(key)
+    x = jnp.stack([
+        jax.random.normal(kg, (3, 7, b), jnp.float32),
+        jax.random.uniform(kh, (3, 7, b), jnp.float32),
+    ])
+    got = np.asarray(ref.bin_prefix_sum(x), np.float64)
+    xs = np.asarray(x, np.float64)
+    scale = np.cumsum(np.abs(xs), axis=-1)
+    eps = float(np.finfo(np.float32).eps)
+    depth = int(np.ceil(np.log2(b)))
+    assert np.all(np.abs(got - np.cumsum(xs, axis=-1)) <= depth * eps * scale)
+    want = np.asarray(jnp.cumsum(x, axis=-1), np.float64)
+    assert np.all(np.abs(got - want) <= (b + depth) * eps * scale)
+
+
 def test_split_gain_last_bin_invalid(key):
     hist = jax.random.uniform(key, (2, 2, 3, 8), jnp.float32)
     gain = ops.split_gain(hist, 1.0, 0.0, backend="ref")

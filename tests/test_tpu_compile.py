@@ -123,13 +123,22 @@ def test_histogram_sparse_compiles(one_chip):
     assert _kernels(fn, args) >= 1
 
 
-@pytest.mark.parametrize("leaves", ["f32", "int8"])
+# (threshold, leaf) dtypes of the layouts serving installs: the f32 forest
+# and the two ``Forest.quantize`` modes.
+TRAVERSAL_LAYOUTS = {
+    "f32": (jnp.int32, jnp.float32),
+    "int8": (jnp.int8, jnp.int8),
+    "fp16": (jnp.int16, jnp.float16),
+}
+
+
+@pytest.mark.parametrize("leaves", list(TRAVERSAL_LAYOUTS))
 def test_forest_traversal_compiles(one_chip, leaves):
     n_int, n_leaf = (1 << DEPTH) - 1, 1 << DEPTH
-    leaf_dtype = jnp.float32 if leaves == "f32" else jnp.int8
+    thr_dtype, leaf_dtype = TRAVERSAL_LAYOUTS[leaves]
     args = _shapes(
         one_chip, ((256, F), jnp.int32), ((SLOTS, n_int), jnp.int32),
-        ((SLOTS, n_int), jnp.int32), ((SLOTS, n_leaf), leaf_dtype), ((), jnp.int32),
+        ((SLOTS, n_int), thr_dtype), ((SLOTS, n_leaf), leaf_dtype), ((), jnp.int32),
         ((SLOTS,), jnp.float32),
     )
 
