@@ -178,5 +178,6 @@ def forest_traverse_pallas(
         out_specs=pl.BlockSpec((sample_block, n_outputs), lambda sb, tb: (sb, 0)),
         out_shape=out_struct((n, n_outputs), jnp.float32, *operands),
         interpret=interpret,
+        name="forest_traverse_pallas",  # its stable name in the device trace
     )(*operands)
     return out[:, 0] if n_outputs == 1 else out

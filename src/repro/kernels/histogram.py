@@ -152,6 +152,7 @@ def histogram_pallas(
             (rows, f * n_bins), jnp.float32, bins, node_ids, grad, hess, row_map
         ),
         interpret=interpret,
+        name="histogram_pallas",  # its stable name in the device trace
     )(
         bins.T,
         node_ids[None, :],

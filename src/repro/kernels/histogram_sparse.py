@@ -155,6 +155,7 @@ def histogram_sparse_pallas(
             (fpad, rows * n_bins), jnp.float32, e_node, e_grad, e_hess, e_code, row_map
         ),
         interpret=interpret,
+        name="histogram_sparse_pallas",  # its stable name in the device trace
     )(e_node, e_grad, e_hess, e_code, row_map[:, None])
     # (Fpad, rows*B) -> (rows, F, B) -> (gh, sub, F, B), dropping feature pad
     out = out[:f].reshape(f, rows, n_bins).transpose(1, 0, 2)

@@ -281,6 +281,7 @@ def level_build_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((2 * l_sub, fb), jnp.float32)],
         interpret=interpret,
+        name="level_build_pallas",  # its stable name in the device trace
     )(*operands)
     return (
         hist.reshape(2, n_nodes, f_pad, n_bins),

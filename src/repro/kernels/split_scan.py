@@ -125,5 +125,6 @@ def split_gain_pallas(
         out_specs=pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
         out_shape=out_struct((l, f * b), jnp.float32, hist, params),
         interpret=interpret,
+        name="split_gain_pallas",  # its stable name in the device trace
     )(flat[0], flat[1], params)
     return gain.reshape(l, f, b)

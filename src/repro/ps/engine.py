@@ -80,35 +80,46 @@ def propose_tree(
     elementwise, so the trained values are unchanged.
     """
     obj = cfg.obj
-    r_sample, r_feat = jax.random.split(rng)
-    m_prime, _ = bernoulli_weights(r_sample, cfg.sampling_rate, data.multiplicity)
-    g, h = obj.grad_hess(data.labels, f_target, qid=data.qid)
+    # Named scopes label the device operations of each step in the
+    # compiled program's metadata; they change no value.
+    with jax.named_scope("sample"):
+        r_sample, r_feat = jax.random.split(rng)
+        m_prime, _ = bernoulli_weights(r_sample, cfg.sampling_rate, data.multiplicity)
     v = jnp.float32(cfg.step_length)
     if obj.n_outputs == 1:
-        hess_w = m_prime * h if cfg.step_kind == "newton" else m_prime
-        if builder is None:
-            tree = build_tree(cfg.learner, data.bins, m_prime * g, hess_w, r_feat)
+        with jax.named_scope("gradient"):
+            g, h = obj.grad_hess(data.labels, f_target, qid=data.qid)
+            hess_w = m_prime * h if cfg.step_kind == "newton" else m_prime
+            g_w = m_prime * g
+        with jax.named_scope("build"):
+            if builder is None:
+                tree = build_tree(cfg.learner, data.bins, g_w, hess_w, r_feat)
+            else:
+                tree = builder(data.bins, g_w, hess_w, r_feat)
+        with jax.named_scope("delta"):
+            tree = tree._replace(leaf_value=v * tree.leaf_value)
+            return tree, apply_tree(tree, data.bins)
+    with jax.named_scope("gradient"):
+        g, h = obj.grad_hess(data.labels, f_target, qid=data.qid)
+        g_w = m_prime[:, None] * g
+        if cfg.step_kind == "newton":
+            h_w = m_prime[:, None] * h
         else:
-            tree = builder(data.bins, m_prime * g, hess_w, r_feat)
-        tree = tree._replace(leaf_value=v * tree.leaf_value)
-        return tree, apply_tree(tree, data.bins)
-    g_w = m_prime[:, None] * g
-    if cfg.step_kind == "newton":
-        h_w = m_prime[:, None] * h
-    else:
-        h_w = jnp.broadcast_to(m_prime[:, None], g.shape)
-    if builder is None:
-        trees = build_tree_multi(cfg.learner, data.bins, g_w, h_w, r_feat)
-    else:
-        # Builders (e.g. the shard_map data-parallel build) are defined on
-        # single-output signatures; run one per output and stack the group.
-        built = [
-            builder(data.bins, g_w[:, k], h_w[:, k], r_feat)
-            for k in range(obj.n_outputs)
-        ]
-        trees = jax.tree.map(lambda *xs: jnp.stack(xs), *built)
-    trees = trees._replace(leaf_value=v * trees.leaf_value)
-    return trees, apply_tree_stack(trees, data.bins)
+            h_w = jnp.broadcast_to(m_prime[:, None], g.shape)
+    with jax.named_scope("build"):
+        if builder is None:
+            trees = build_tree_multi(cfg.learner, data.bins, g_w, h_w, r_feat)
+        else:
+            # Builders (e.g. the shard_map data-parallel build) are defined on
+            # single-output signatures; run one per output and stack the group.
+            built = [
+                builder(data.bins, g_w[:, k], h_w[:, k], r_feat)
+                for k in range(obj.n_outputs)
+            ]
+            trees = jax.tree.map(lambda *xs: jnp.stack(xs), *built)
+    with jax.named_scope("delta"):
+        trees = trees._replace(leaf_value=v * trees.leaf_value)
+        return trees, apply_tree_stack(trees, data.bins)
 
 
 def server_fold(cfg, forest, f_live, tree, delta):
@@ -121,7 +132,8 @@ def server_fold(cfg, forest, f_live, tree, delta):
     (the threaded runtime's server program), in the per-round loop, in the
     fused lax.scan replay, or inside a vmapped worker block.
     """
-    return forest_push(forest, tree, jnp.float32(1.0)), f_live + delta
+    with jax.named_scope("fold"):
+        return forest_push(forest, tree, jnp.float32(1.0)), f_live + delta
 
 
 def staleness_scale(rho: float, staleness) -> jax.Array:

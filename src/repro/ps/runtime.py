@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import store as ckpt_store
 from repro.core.sgbdt import SGBDTConfig, TrainState, init_state
 from repro.data.sampling import bernoulli_weights
@@ -182,9 +183,16 @@ class RunTrace:
                       ``shard_pulls``);
       step_scale[j] — the staleness-adaptive deflation the server applied
                       at fold time (1.0 when ``adaptive_rho == 0``);
-      t_build[j]    — wall seconds of the (blocking) jitted build;
-      t_queue[j]    — push-to-fold-start wait in the server queue;
-      t_fold[j]     — wall seconds of the jitted server fold.
+      t_build[j]    — host seconds from the start of the worker's pull
+                      (``ps.pull``) to its build's device completion (end
+                      of ``ps.build``): the pull, any injected delay, the
+                      jitted build, and the wait behind other workers'
+                      builds in the device queue — not the build's own
+                      device time;
+      t_queue[j]    — push-to-fold-start wait in the server queue
+                      (``ps.push`` start to ``ps.fold`` start);
+      t_fold[j]     — host seconds of the jitted server fold, drained
+                      (``ps.fold``).
 
     ``events`` is the membership log: tuples of dicts with ``kind`` in
     ``join | leave | crash | resume``, the worker, the fold count and
@@ -499,9 +507,12 @@ class AsyncRuntime:
         # bit-compatible with the fused replay program. The fold takes the
         # observed staleness so the adaptive deflation (when enabled)
         # happens exactly where the physical program boundary sits.
-        self._propose = jax.jit(
-            lambda data, f_target, rng: propose_tree(cfg, data, f_target, rng)
-        )
+        # Named functions, so the device trace names their programs
+        # (``jit_propose``, ``jit_fold``).
+        def propose(data, f_target, rng):
+            return propose_tree(cfg, data, f_target, rng)
+
+        self._propose = jax.jit(propose)
         if cfg.adaptive_step:
 
             def fold(forest, f, tree, delta, stale):
@@ -721,12 +732,13 @@ class AsyncRuntime:
 
         # Warm the jit caches outside the timed region so the first worker
         # does not record a compile as a build.
-        tree0, delta0 = self._propose(data, f, keys[0])
-        jax.block_until_ready(
-            self._fold(forest, f, tree0, delta0, jnp.int32(0))
-        )
-        if self.shards is not None:
-            self.shards.pull(f, keys[0])
+        with obs.span("ps.run.prelude"):
+            tree0, delta0 = self._propose(data, f, keys[0])
+            jax.block_until_ready(
+                self._fold(forest, f, tree0, delta0, jnp.int32(0))
+            )
+            if self.shards is not None:
+                self.shards.pull(f, keys[0])
 
         lock = threading.Lock()
         pushes: "queue.Queue[tuple]" = queue.Queue()
@@ -757,7 +769,7 @@ class AsyncRuntime:
             delay = float(self._delay.get(w, 0.0))
             try:
                 while True:
-                    with lock:
+                    with obs.span("ps.ticket", worker=w) as draw, lock:
                         if not ticket_heap:
                             shared["live"].discard(w)
                             return
@@ -780,20 +792,24 @@ class AsyncRuntime:
                         f_snapshot = shared["f"]
                         refcnt[pulled_version] = refcnt.get(pulled_version, 0) + 1
                         my_epoch = shared["epoch"]
-                    t0 = time.perf_counter()
-                    if delay:
-                        time.sleep(delay)
-                    if self.shards is not None:
-                        f_used, nbytes = self.shards.pull(f_snapshot, keys[i])
-                    else:
-                        f_used, nbytes = f_snapshot, self.full_pull_bytes
-                    tree, delta = self._propose(data, f_used, keys[i])
-                    jax.block_until_ready(delta)
-                    t_build = time.perf_counter() - t0
-                    pushes.put(
-                        (i, pulled_version, w, my_epoch, nbytes, tree, delta,
-                         t_build, time.perf_counter())
-                    )
+                        draw.set(ticket=i)
+                    with obs.timed("ps.pull", ticket=i) as pull:
+                        if self.shards is not None:
+                            f_used, nbytes = self.shards.pull(f_snapshot, keys[i])
+                        else:
+                            f_used, nbytes = f_snapshot, self.full_pull_bytes
+                    with obs.timed("ps.build", ticket=i) as build:
+                        if delay:
+                            time.sleep(delay)
+                        with obs.span("ps.build.dispatch", ticket=i):
+                            tree, delta = self._propose(data, f_used, keys[i])
+                        with obs.span("ps.build.wait", ticket=i):
+                            jax.block_until_ready(delta)
+                    with obs.timed("ps.push", ticket=i) as push:
+                        pushes.put(
+                            (i, pulled_version, w, my_epoch, nbytes, tree, delta,
+                             build.t1 - pull.t0, push.t0)
+                        )
                     if i in plan.leave_tickets:
                         with lock:
                             shared["epoch"] += 1
@@ -859,7 +875,8 @@ class AsyncRuntime:
         j = start_fold
         while j < end_fold:
             try:
-                push = pushes.get(timeout=1.0)
+                with obs.span("ps.server.wait", fold=j):
+                    push = pushes.get(timeout=1.0)
             except queue.Empty:
                 with lock:
                     stuck = not shared["live"] and not joins
@@ -876,54 +893,55 @@ class AsyncRuntime:
                 raise RuntimeError("async worker failed") from errors[0]
             (i, pulled_version, w, my_epoch, nbytes, tree, delta,
              t_build, t_pushed) = push
-            t_fold0 = time.perf_counter()
-            forest, f = self._fold(
-                forest, f, tree, delta, jnp.int32(j - pulled_version)
-            )
-            jax.block_until_ready(f)
-            t_fold1 = time.perf_counter()
-            with lock:
-                shared["version"] = j + 1
-                shared["f"] = f
-                shared["fold"] = j + 1
-                f_by_version[j + 1] = f
-                refcnt[pulled_version] -= 1
-                for v in [v for v, c in refcnt.items() if c <= 0]:
-                    del refcnt[v]
-                # Keep only versions a still-in-flight build references,
-                # plus the current one; everything else is garbage.
-                for v in [
-                    v for v in f_by_version if v != j + 1 and v not in refcnt
-                ]:
-                    del f_by_version[v]
-                fire_joins(j + 1)
-                held = sorted(v for v, c in refcnt.items() if c > 0)
-                held_f = [f_by_version[v] for v in held]
-            rows["schedule"][j] = pulled_version
-            rows["key_index"][j] = i
-            rows["worker"][j] = w
-            rows["epoch"][j] = my_epoch
-            rows["pull_bytes"][j] = nbytes
-            # Same f32 rounding as engine.staleness_scale: 6*rho rounds
-            # once from python f64, then one f32 mul + add + divide.
-            rows["step_scale"][j] = (
-                np.float32(1.0)
-                / (np.float32(1.0) + np.float32(6.0 * rho) * np.float32(j - pulled_version))
-                if rho
-                else np.float32(1.0)
-            )
-            rows["t_build"][j] = t_build
-            rows["t_queue"][j] = t_fold0 - t_pushed
-            rows["t_fold"][j] = t_fold1 - t_fold0
-            j += 1
-            if checkpoint_dir is not None and checkpoint_every and (
-                j % checkpoint_every == 0 or j == end_fold
-            ):
-                self._save_checkpoint(checkpoint_dir, j, forest, f, held, held_f)
-            if trace_path is not None:
-                partial_trace(
-                    j, base_makespan + time.perf_counter() - t_start
-                ).save(trace_path)
+            with obs.timed("ps.fold", fold=j, ticket=i,
+                           staleness=j - pulled_version) as fold_span:
+                forest, f = self._fold(
+                    forest, f, tree, delta, jnp.int32(j - pulled_version)
+                )
+                jax.block_until_ready(f)
+            with obs.span("ps.commit", fold=j, ticket=i):
+                with lock:
+                    shared["version"] = j + 1
+                    shared["f"] = f
+                    shared["fold"] = j + 1
+                    f_by_version[j + 1] = f
+                    refcnt[pulled_version] -= 1
+                    for v in [v for v, c in refcnt.items() if c <= 0]:
+                        del refcnt[v]
+                    # Keep only versions a still-in-flight build references,
+                    # plus the current one; everything else is garbage.
+                    for v in [
+                        v for v in f_by_version if v != j + 1 and v not in refcnt
+                    ]:
+                        del f_by_version[v]
+                    fire_joins(j + 1)
+                    held = sorted(v for v, c in refcnt.items() if c > 0)
+                    held_f = [f_by_version[v] for v in held]
+                rows["schedule"][j] = pulled_version
+                rows["key_index"][j] = i
+                rows["worker"][j] = w
+                rows["epoch"][j] = my_epoch
+                rows["pull_bytes"][j] = nbytes
+                # Same f32 rounding as engine.staleness_scale: 6*rho rounds
+                # once from python f64, then one f32 mul + add + divide.
+                tau = np.float32(j - pulled_version)
+                rows["step_scale"][j] = (
+                    np.float32(1.0) / (np.float32(1.0) + np.float32(6.0 * rho) * tau)
+                    if rho
+                    else np.float32(1.0)
+                )
+                rows["t_build"][j] = t_build
+                rows["t_queue"][j] = fold_span.t0 - t_pushed
+                rows["t_fold"][j] = fold_span.seconds
+                j += 1
+                if checkpoint_dir is not None and checkpoint_every and (
+                    j % checkpoint_every == 0 or j == end_fold
+                ):
+                    self._save_checkpoint(checkpoint_dir, j, forest, f, held, held_f)
+                if trace_path is not None:
+                    partial_trace(
+                        j, base_makespan + time.perf_counter() - t_start
+                    ).save(trace_path)
 
         makespan = base_makespan + time.perf_counter() - t_start
         if halt_at_fold is None:

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.serving.forest_server import (
     ForestServer,
     PredictRequest,
@@ -98,6 +99,8 @@ class ForestEngine:
         # EWMA of observed wave compute seconds — the deadline budget's
         # estimate of "how long will the wave I cut now take".
         self._ewma_compute = 0.0  # guarded-by: self._lock
+        # Per version, its open ``serve.hold`` span (tracing only).
+        self._holds: dict = {}  # guarded-by: self._lock
         self._runner: threading.Thread | None = None
         self._runner_stop: threading.Event | None = None
 
@@ -212,11 +215,15 @@ class ForestEngine:
             while True:
                 queued = v.server.queued_rows()
                 if not queued:
+                    self._hold(v.name, False)
                     break
                 full = queued >= self.max_rows
                 due = v.server.oldest_wait() >= budget
                 if not (full or due or force):
+                    self._hold(v.name, True)
                     break
+                self._hold(v.name, False)
+                obs.count("serve.cut." + ("full" if full else "due" if due else "force"))
                 t0 = time.perf_counter()
                 res = v.server.serve_next_wave()
                 dt = time.perf_counter() - t0
@@ -234,6 +241,21 @@ class ForestEngine:
                 else:
                     out.extend(res)
         return out
+
+    def _hold(self, name: str, holding: bool) -> None:  # concurrent
+        """With tracing on, a version's ``serve.hold`` span: it opens at the
+        first step that declines to cut the version's non-empty queue and
+        closes at the next cut or when the queue empties. Only the changes
+        are recorded, never a span per step."""
+        if not obs.enabled():
+            return
+        with self._lock:
+            if holding:
+                if name not in self._holds:
+                    self._holds[name] = obs.begin("serve.hold", version=name)
+                return
+            opened = self._holds.pop(name, None)
+        obs.end(opened)
 
     def flush(self) -> list[PredictResult]:
         """Drain every queue regardless of SLO state."""

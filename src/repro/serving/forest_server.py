@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import checkpoint
+from repro import checkpoint, obs
 from repro.kernels import ops
 from repro.objectives import Objective, get_objective
 from repro.trees.binning import apply_bins
@@ -270,30 +270,30 @@ class ForestServer:
         """Validate and enqueue. Requests wider than ``max_rows`` are split
         into sub-waves here and reassembled under the original uid; arrival
         is stamped NOW, so reported ``queue_s`` includes every second the
-        request sits behind earlier traffic."""
-        x = np.asarray(req.x, np.float32)
-        if x.ndim != 2 or x.shape[1] != self.bin_edges.shape[0]:
-            raise ValueError(
-                f"request {req.uid}: expected (n, {self.bin_edges.shape[0]}) "
-                f"features, got {x.shape}"
-            )
-        bad = _nonfinite_rows(x)
-        if bad.size and self.on_nonfinite == "reject":
-            raise ValueError(
-                f"request {req.uid}: non-finite features in rows "
-                f"{bad.tolist()} (server runs on_nonfinite='reject'; "
-                f"use 'flag' to serve them with clamped/NaN-routed bins)"
-            )
-        n = x.shape[0]
-        cuts = list(range(0, n, self.max_rows)) or [0]
-        asm = _Assembly(
-            req=req, x=x, arrival_s=time.perf_counter(), parts_left=len(cuts)
-        )
-        # All parts land under ONE lock acquisition: a draining wave thread
-        # can never observe a half-enqueued request (drain completeness).
-        with self._qlock:
-            for lo in cuts:
-                self._queue.append(_Part(asm, lo, min(lo + self.max_rows, n)))
+        request sits behind earlier traffic (arrival is the start of the
+        ``serve.submit`` span)."""
+        with obs.timed("serve.submit", uid=req.uid) as admit:
+            x = np.asarray(req.x, np.float32)
+            if x.ndim != 2 or x.shape[1] != self.bin_edges.shape[0]:
+                raise ValueError(
+                    f"request {req.uid}: expected (n, {self.bin_edges.shape[0]}) "
+                    f"features, got {x.shape}"
+                )
+            bad = _nonfinite_rows(x)
+            if bad.size and self.on_nonfinite == "reject":
+                raise ValueError(
+                    f"request {req.uid}: non-finite features in rows "
+                    f"{bad.tolist()} (server runs on_nonfinite='reject'; "
+                    f"use 'flag' to serve them with clamped/NaN-routed bins)"
+                )
+            n = x.shape[0]
+            cuts = list(range(0, n, self.max_rows)) or [0]
+            asm = _Assembly(req=req, x=x, arrival_s=admit.t0, parts_left=len(cuts))
+            # All parts land under ONE lock acquisition: a draining wave thread
+            # can never observe a half-enqueued request (drain completeness).
+            with self._qlock:
+                for lo in cuts:
+                    self._queue.append(_Part(asm, lo, min(lo + self.max_rows, n)))
 
     # ------------------------------------------------------------------ waves
     def queued_rows(self) -> int:  # concurrent
@@ -330,24 +330,47 @@ class ForestServer:
         return self._run_wave(wave) if wave else []
 
     def _run_wave(self, wave: list[_Part]) -> list[PredictResult]:  # concurrent
+        """Serve one cut wave inside a ``serve.wave`` span: ``pack`` (copy
+        and pad to ``max_rows``), ``run`` (put, predict, wait), ``fetch``
+        (copy back), ``assemble`` (results). A request's ``compute_s`` runs
+        from the start of ``run`` to the end of ``fetch``."""
         sizes = [p.hi - p.lo for p in wave]
-        rows = np.zeros((self.max_rows, self.bin_edges.shape[0]), np.float32)
-        if sum(sizes):
-            rows[: sum(sizes)] = np.concatenate(
-                [p.asm.x[p.lo : p.hi] for p in wave], axis=0
-            )
-        # One consistent snapshot of the swap pair: every result in this
-        # wave is labeled with the step of the forest that computed it,
-        # even if a poller thread swaps mid-wave.
-        with self._lock:
-            forest, model_step = self.forest, self.model_step
-        t0 = time.perf_counter()
-        scores = self._predict(forest, self.bin_edges, jnp.asarray(rows))
-        scores = np.asarray(jax.block_until_ready(scores))
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self.waves_served += 1
-            waves = self.waves_served
+        n_rows = sum(sizes)
+        with obs.span("serve.wave", rows=n_rows, pad=self.max_rows - n_rows):
+            with obs.span("serve.wave.pack"):
+                rows = np.zeros((self.max_rows, self.bin_edges.shape[0]), np.float32)
+                if n_rows:
+                    rows[:n_rows] = np.concatenate(
+                        [p.asm.x[p.lo : p.hi] for p in wave], axis=0
+                    )
+            obs.count("serve.rows", n_rows)
+            obs.count("serve.pad_rows", self.max_rows - n_rows)
+            # One consistent snapshot of the swap pair: every result in this
+            # wave is labeled with the step of the forest that computed it,
+            # even if a poller thread swaps mid-wave.
+            with self._lock:
+                forest, model_step = self.forest, self.model_step
+            with obs.timed("serve.wave.run") as run:
+                scores = self._predict(forest, self.bin_edges, jnp.asarray(rows))
+                jax.block_until_ready(scores)
+            with obs.timed("serve.wave.fetch") as fetch:
+                scores = np.asarray(scores)
+            t0, dt = run.t0, fetch.t1 - run.t0
+            with self._lock:
+                self.waves_served += 1
+                waves = self.waves_served
+            with obs.span("serve.wave.assemble"):
+                results = self._assemble(wave, sizes, scores, t0, dt, model_step)
+            if waves % self.reload_every_waves == 0:
+                # Bounded-lag hot swap: the serving path itself polls, so a
+                # busy server can never fall more than reload_every_waves
+                # waves behind the newest checkpoint.
+                self.maybe_reload()
+        return results
+
+    def _assemble(self, wave, sizes, scores, t0, dt, model_step):  # concurrent
+        """Scatter a wave's scores into its requests; the results of every
+        request whose last part rode it."""
         results, off = [], 0
         for part, n in zip(wave, sizes):
             asm = part.asm
@@ -381,11 +404,6 @@ class ForestServer:
                         )
                     )
             off += n
-        if waves % self.reload_every_waves == 0:
-            # Bounded-lag hot swap: the serving path itself polls, so a
-            # busy server can never fall more than reload_every_waves
-            # waves behind the newest checkpoint.
-            self.maybe_reload()
         return results
 
     # --------------------------------------------------------------- hot swap

@@ -111,12 +111,13 @@ def _smaller_children(
     inert in the builder's control flow too, not just in its sums. Under
     shard_map the counts psum first: every shard must pick the SAME child.
     """
-    counts = jax.ops.segment_sum(h, node, num_segments=n_nodes)
-    if cfg.axis_name is not None:
-        counts = collectives.psum(counts, cfg.axis_name)
-    parents = jnp.arange(n_nodes // 2, dtype=jnp.int32)
-    go_odd = (counts[0::2] > counts[1::2]).astype(jnp.int32)
-    return 2 * parents + go_odd
+    with jax.named_scope("child_counts"):
+        counts = jax.ops.segment_sum(h, node, num_segments=n_nodes)
+        if cfg.axis_name is not None:
+            counts = collectives.psum(counts, cfg.axis_name)
+        parents = jnp.arange(n_nodes // 2, dtype=jnp.int32)
+        go_odd = (counts[0::2] > counts[1::2]).astype(jnp.int32)
+        return 2 * parents + go_odd
 
 
 def _level_histogram(
@@ -187,51 +188,53 @@ def _staged_level(
     ``feat`` is returned in GLOBAL feature ids either way.
     """
     n_nodes, n_bins = 1 << level, cfg.n_bins
-    hist = _level_histogram(cfg, hist_bins, node, g, h, level, parent_hist, backend)
-    gain = ops.split_gain(hist, cfg.lam, cfg.min_child_hess, backend=backend)
-    gain = jnp.where(feat_mask[None, :, None], gain, -jnp.inf)  # (L, F_loc, B)
+    with jax.named_scope("histogram"):
+        hist = _level_histogram(cfg, hist_bins, node, g, h, level, parent_hist, backend)
+    with jax.named_scope("split"):
+        gain = ops.split_gain(hist, cfg.lam, cfg.min_child_hess, backend=backend)
+        gain = jnp.where(feat_mask[None, :, None], gain, -jnp.inf)  # (L, F_loc, B)
 
-    f_local = gain.shape[1]
-    flat = gain.reshape(n_nodes, -1)
-    idx = jnp.argmax(flat, axis=-1)
-    best = jnp.take_along_axis(flat, idx[:, None], axis=-1)[:, 0]
+        f_local = gain.shape[1]
+        flat = gain.reshape(n_nodes, -1)
+        idx = jnp.argmax(flat, axis=-1)
+        best = jnp.take_along_axis(flat, idx[:, None], axis=-1)[:, 0]
 
-    if cfg.feature_axis is not None:
-        shard = jax.lax.axis_index(cfg.feature_axis)
-        gidx = idx.astype(jnp.int32) + shard * (f_local * n_bins)
-        best_g = collectives.pmax(best, cfg.feature_axis)
-        # Among shards holding the global max, the lowest global flat index
-        # wins — all--inf rows tie at shard 0's index 0, exactly like the
-        # 1D argmax, and the pass-left fix below overrides them anyway.
-        cand = jnp.where(best == best_g, gidx, jnp.iinfo(jnp.int32).max)
-        idx = collectives.pmin(cand, cfg.feature_axis)
-        best = best_g
+        if cfg.feature_axis is not None:
+            shard = jax.lax.axis_index(cfg.feature_axis)
+            gidx = idx.astype(jnp.int32) + shard * (f_local * n_bins)
+            best_g = collectives.pmax(best, cfg.feature_axis)
+            # Among shards holding the global max, the lowest global flat index
+            # wins — all--inf rows tie at shard 0's index 0, exactly like the
+            # 1D argmax, and the pass-left fix below overrides them anyway.
+            cand = jnp.where(best == best_g, gidx, jnp.iinfo(jnp.int32).max)
+            idx = collectives.pmin(cand, cfg.feature_axis)
+            best = best_g
 
-    feat = (idx // n_bins).astype(jnp.int32)
-    thr = (idx % n_bins).astype(jnp.int32)
+        feat = (idx // n_bins).astype(jnp.int32)
+        thr = (idx % n_bins).astype(jnp.int32)
 
-    # Unsplittable node -> pass-through: all samples go left.
-    ok = jnp.isfinite(best) & (best > 0.0)
-    feat = jnp.where(ok, feat, 0)
-    thr = jnp.where(ok, thr, n_bins - 1)
-
-    f_of = jnp.take(feat, node)  # (N,) global winning feature per sample
-    if cfg.feature_axis is not None and not isinstance(route_bins, SparseBins):
-        # Dense 2D partition: only the winning feature's owner shard holds
-        # its column, so each shard contributes its owned values and a
-        # one-byte-per-sample psum reconstructs the column everywhere
-        # (bin ids < n_bins <= 256 — uint8 is exact).
-        lo = jax.lax.axis_index(cfg.feature_axis) * f_local
-        owned = (f_of >= lo) & (f_of < lo + f_local)
-        col = jnp.clip(f_of - lo, 0, f_local - 1)
-        v = gather_feature_bins(route_bins, col)
-        v = jnp.where(owned, v, 0).astype(jnp.uint8)
-        val = collectives.psum(v, cfg.feature_axis).astype(jnp.int32)
-    else:
-        # 1D dense gather, or the sparse row-major store (replicated across
-        # feature shards: routing needs no collective at all).
-        val = gather_feature_bins(route_bins, f_of)
-    go_right = (val > jnp.take(thr, node)).astype(jnp.int32)
+        # Unsplittable node -> pass-through: all samples go left.
+        ok = jnp.isfinite(best) & (best > 0.0)
+        feat = jnp.where(ok, feat, 0)
+        thr = jnp.where(ok, thr, n_bins - 1)
+    with jax.named_scope("partition"):
+        f_of = jnp.take(feat, node)  # (N,) global winning feature per sample
+        if cfg.feature_axis is not None and not isinstance(route_bins, SparseBins):
+            # Dense 2D partition: only the winning feature's owner shard holds
+            # its column, so each shard contributes its owned values and a
+            # one-byte-per-sample psum reconstructs the column everywhere
+            # (bin ids < n_bins <= 256 — uint8 is exact).
+            lo = jax.lax.axis_index(cfg.feature_axis) * f_local
+            owned = (f_of >= lo) & (f_of < lo + f_local)
+            col = jnp.clip(f_of - lo, 0, f_local - 1)
+            v = gather_feature_bins(route_bins, col)
+            v = jnp.where(owned, v, 0).astype(jnp.uint8)
+            val = collectives.psum(v, cfg.feature_axis).astype(jnp.int32)
+        else:
+            # 1D dense gather, or the sparse row-major store (replicated across
+            # feature shards: routing needs no collective at all).
+            val = gather_feature_bins(route_bins, f_of)
+        go_right = (val > jnp.take(thr, node)).astype(jnp.int32)
     return hist, feat, thr, 2 * node + go_right
 
 
@@ -338,24 +341,29 @@ def build_tree(
         n_nodes = 1 << level
         n_sub = max(n_nodes // 2, 1) if (cfg.hist_mode == "subtract" and level) \
             else n_nodes
-        if use_fused and fused_level_fits(n, n_nodes, n_sub, f_local, n_bins):
-            hist, feat, thr, node = _fused_level(
-                cfg, bins, node, g, h, feat_mask, level, hist
-            )
-        else:
-            hist, feat, thr, node = _staged_level(
-                cfg, staged, hist_bins, bins, node, g, h, feat_mask, level, hist
-            )
+        # Scopes (level, then child_counts / histogram / split / partition
+        # inside it, and leaf_sums) name each step's device operations in
+        # the compiled program's metadata; they change no value.
+        with jax.named_scope(f"level{level}"):
+            if use_fused and fused_level_fits(n, n_nodes, n_sub, f_local, n_bins):
+                hist, feat, thr, node = _fused_level(
+                    cfg, bins, node, g, h, feat_mask, level, hist
+                )
+            else:
+                hist, feat, thr, node = _staged_level(
+                    cfg, staged, hist_bins, bins, node, g, h, feat_mask, level, hist
+                )
         features.append(feat)
         thresholds.append(thr)
 
     # Leaf statistics.
     n_leaves = 1 << depth
-    leaf_g = jax.ops.segment_sum(g, node, num_segments=n_leaves)
-    leaf_h = jax.ops.segment_sum(h, node, num_segments=n_leaves)
-    if cfg.axis_name is not None:  # merge leaf stats across data shards
-        leaf_g = collectives.psum(leaf_g, cfg.axis_name)
-        leaf_h = collectives.psum(leaf_h, cfg.axis_name)
+    with jax.named_scope("leaf_sums"):
+        leaf_g = jax.ops.segment_sum(g, node, num_segments=n_leaves)
+        leaf_h = jax.ops.segment_sum(h, node, num_segments=n_leaves)
+        if cfg.axis_name is not None:  # merge leaf stats across data shards
+            leaf_g = collectives.psum(leaf_g, cfg.axis_name)
+            leaf_h = collectives.psum(leaf_h, cfg.axis_name)
     leaf_value = -leaf_g / (leaf_h + cfg.lam)
     leaf_value = jnp.where(leaf_h > 0, leaf_value, 0.0)
 
