@@ -74,6 +74,24 @@ def _sparse_local_dense(
     return base.at[rows.reshape(-1), cols.reshape(-1)].add(delta.reshape(-1))
 
 
+def node_sums(node: jax.Array, values: jax.Array, n_nodes: int) -> jax.Array:
+    """Per-node f32 sums of ``values`` (N,) or (K, N) by ``node`` (N,):
+    (n_nodes,) or (K, n_nodes).
+
+    A compare-and-reduce, not a scatter: every row meets every node id and
+    adds its value where they match, in one fused pass over N that reads
+    each operand once. A TPU lowers a scatter-add of N updates into a few
+    slots almost serially; this lowers to a plain reduction. Rows whose id
+    is no node in [0, n_nodes) (the sparse path's -1) add nothing.
+    """
+    ids = jnp.arange(n_nodes, dtype=node.dtype)
+    # The compare takes the values' shape, so each value row makes its own
+    # and nothing of (.., N, n_nodes) is written out.
+    hit = jnp.broadcast_to(node, values.shape)[..., None] == ids
+    vals = values.astype(jnp.float32)[..., None]
+    return jnp.sum(jnp.where(hit, vals, 0.0), axis=-2)
+
+
 def _node_totals(
     node_ids: jax.Array,
     grad: jax.Array,
@@ -88,15 +106,7 @@ def _node_totals(
     inv = jnp.full((n_nodes,), -1, jnp.int32)
     inv = inv.at[active_nodes].set(jnp.arange(n_sub, dtype=jnp.int32))
     row = jnp.where(node_ids >= 0, inv[jnp.clip(node_ids, 0, n_nodes - 1)], -1)
-    active = row >= 0
-    rowc = jnp.where(active, row, 0)
-    tg = jax.ops.segment_sum(
-        jnp.where(active, grad, 0.0), rowc, num_segments=n_sub
-    )
-    th = jax.ops.segment_sum(
-        jnp.where(active, hess, 0.0), rowc, num_segments=n_sub
-    )
-    return jnp.stack([tg, th]).astype(jnp.float32)
+    return node_sums(row, jnp.stack([grad, hess]), n_sub)
 
 
 def _zero_bin_complement(

@@ -112,7 +112,7 @@ def _smaller_children(
     shard_map the counts psum first: every shard must pick the SAME child.
     """
     with jax.named_scope("child_counts"):
-        counts = jax.ops.segment_sum(h, node, num_segments=n_nodes)
+        counts = ops.node_sums(node, h, n_nodes)
         if cfg.axis_name is not None:
             counts = collectives.psum(counts, cfg.axis_name)
         parents = jnp.arange(n_nodes // 2, dtype=jnp.int32)
@@ -359,8 +359,7 @@ def build_tree(
     # Leaf statistics.
     n_leaves = 1 << depth
     with jax.named_scope("leaf_sums"):
-        leaf_g = jax.ops.segment_sum(g, node, num_segments=n_leaves)
-        leaf_h = jax.ops.segment_sum(h, node, num_segments=n_leaves)
+        leaf_g, leaf_h = ops.node_sums(node, jnp.stack([g, h]), n_leaves)
         if cfg.axis_name is not None:  # merge leaf stats across data shards
             leaf_g = collectives.psum(leaf_g, cfg.axis_name)
             leaf_h = collectives.psum(leaf_h, cfg.axis_name)

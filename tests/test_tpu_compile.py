@@ -2,7 +2,8 @@
 
 Every kernel is compiled for a described (not attached) TPU v5e chip at the
 widths of ``chip_smoke.py`` — HIGGS's 28 features, 64 bins, depth-5 trees —
-and the compiled program must hold a Mosaic kernel (``tpu_custom_call``).
+and the compiled program must hold a Mosaic kernel (``tpu_custom_call``);
+the whole tree build must also hold no ``scatter``.
 Nothing runs: these tests catch what only the TPU compiler refuses (block
 shapes off the (8, 128) tiling, primitives Mosaic cannot lower, VMEM
 overflow) without a chip. The topology is described inside a fixture, so
@@ -19,6 +20,7 @@ from repro.kernels.histogram import histogram_pallas
 from repro.kernels.histogram_sparse import histogram_sparse_pallas
 from repro.kernels.level_build import level_build_pallas
 from repro.kernels.split_scan import split_gain_pallas
+from repro.trees.learner import LearnerConfig, build_tree
 
 N, F, B, DEPTH = 65_536, 28, 64, 5
 L = 1 << (DEPTH - 1)  # nodes of the deepest split level
@@ -150,3 +152,29 @@ def test_forest_traversal_compiles(one_chip, leaves):
         )
 
     assert _kernels(fn, args) >= 1
+
+
+@pytest.mark.parametrize("backend", ["pallas", "fused"])
+@pytest.mark.parametrize("hist_mode", ["subtract", "rebuild"])
+def test_build_tree_has_no_scatter(one_chip, monkeypatch, hist_mode, backend):
+    """The whole tree build for the chip: its per-node row sums (child
+    counts, leaf sums) are reductions, not scatters. A TPU runs a
+    scatter-add of N rows into a few node slots almost serially."""
+    # The kernels pick Mosaic over the interpreter by the default backend:
+    # steer them to the chip's path, and keep these traces out of the
+    # caches the CPU tests share.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    cfg = LearnerConfig(depth=DEPTH, n_bins=B, feature_fraction=0.8,
+                        backend=backend, hist_mode=hist_mode)
+    args = _shapes(
+        one_chip, ((N, F), jnp.int32), ((N,), jnp.float32), ((N,), jnp.float32),
+        ((2,), jnp.uint32),
+    )
+    try:
+        text = jax.jit(lambda b, g, h, k: build_tree(cfg, b, g, h, k)).lower(
+            *args).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert text.count("tpu_custom_call") >= 1
+    assert "scatter(" not in text

@@ -2,8 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels.ops import node_sums
 from repro.trees import (
     LearnerConfig,
     apply_bins,
@@ -113,3 +115,54 @@ def test_unsplittable_node_passthrough(key):
     pred = np.asarray(apply_tree(tree, bins))
     expected = -100.0 / (100.0 + 1.0)
     np.testing.assert_allclose(pred, expected, rtol=1e-5)
+
+
+def _node_sum_case(n_nodes: int, rows: str, seed: int, n: int = 20_000):
+    """Node ids and (g, h) rows: h integer multiplicities, g = h * noise."""
+    r = np.random.default_rng(seed)
+    node = r.integers(0, n_nodes, n).astype(np.int32)
+    if rows == "empty_nodes":
+        node -= node % 2  # every odd node id is empty
+    elif rows == "no_node":
+        node[r.random(n) < 0.3] = -1  # the sparse path's rows outside every node
+    h = np.minimum(r.zipf(2.0, n), 50).astype(np.float32) * (r.random(n) < 0.8)
+    g = (r.standard_normal(n) * h).astype(np.float32)
+    return node, np.stack([g, h])
+
+
+def _float64_sums(node, values, n_nodes):
+    keep = node >= 0
+    return np.stack([np.bincount(node[keep], v[keep].astype(np.float64), minlength=n_nodes)
+                     for v in values])
+
+
+@pytest.mark.parametrize("mapped", [False, True], ids=["plain", "vmap"])
+@pytest.mark.parametrize("k", [1, 2])
+@pytest.mark.parametrize("rows", ["all", "empty_nodes", "no_node"])
+@pytest.mark.parametrize("n_nodes", [2, 32, 128])
+def test_node_sums_match_float64(n_nodes, rows, k, mapped):
+    """The learner's per-node sums equal float64 sums to f32 tolerance, and
+    integer-valued h (the multiplicities) sums exactly; empty nodes and rows
+    with no node sum to 0. (N,) values give (n_nodes,), (K, N) give (K,
+    n_nodes), also under vmap as in ``build_tree_multi``."""
+    cases = [_node_sum_case(n_nodes, rows, seed) for seed in (0, 1)]
+    nodes = np.stack([c[0] for c in cases])
+    values = np.stack([c[1][2 - k:] for c in cases])  # (2, K, N): h last
+    if k == 1:
+        values = values[:, 0]  # (2, N)
+    if mapped:
+        out = jax.vmap(lambda nd, v: node_sums(nd, v, n_nodes))(nodes, values)
+    else:
+        out = jnp.stack([node_sums(jnp.asarray(nd), jnp.asarray(v), n_nodes)
+                         for nd, v in zip(nodes, values)])
+    out = np.asarray(out)
+    assert out.dtype == np.float32
+    assert out.shape == values.shape[:-1] + (n_nodes,)
+    for nd, v, got in zip(nodes, values, out):
+        v2, got2 = v.reshape(-1, v.shape[-1]), got.reshape(-1, n_nodes)
+        want = _float64_sums(nd, v2, n_nodes)
+        scale = _float64_sums(nd, np.abs(v2), n_nodes)
+        assert np.all(np.abs(got2 - want) <= 1e-6 * scale + 1e-30)
+        np.testing.assert_array_equal(got2[-1], want[-1])  # h: exact
+        if rows == "empty_nodes":
+            assert not got2[:, 1::2].any()
