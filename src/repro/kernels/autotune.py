@@ -21,7 +21,8 @@ row was measured so a TPU regeneration can overwrite CPU-tuned rows.
 Every block shape this module hands out is one Mosaic can lower: the
 feature block is the whole (padded) feature width up to 128 features and a
 multiple of 128 above it (``feature_tiling``), and the sample block is a
-multiple of 128 sized so the one-hot factor fits ``ONEHOT_BYTES``.
+multiple of 128 sized so the one-hot factor fits ``ONEHOT_BYTES``. The
+split scan's blocks (``split_tiling``) come from the geometry alone.
 """
 from __future__ import annotations
 
@@ -46,6 +47,14 @@ MAX_SAMPLE_BLOCK = 512
 # Candidate sample blocks for the sweep. They trade DMA granularity against
 # VMEM residency; feature blocks come from ``legal_feature_blocks``.
 SAMPLE_BLOCKS = (128, 256, 512, 1024, 2048, 4096)
+
+# The split scan's scoped VMEM, in f32 (node_block, F_blk * B) blocks: its
+# three operands double-buffered and the scan's temporaries. v5e's
+# compiler reports 6 to 9.5 blocks for tiles of 0.25 to 1 MiB, and has
+# asked 14.7 for a whole 64-node tile of 2 MiB; the chooser keeps 15
+# blocks within half of v5e's 16 MiB scoped limit.
+SPLIT_VMEM_BLOCKS = 15
+SPLIT_VMEM_BUDGET = 8 * 2**20
 
 _cache: dict[str, dict] | None = None
 
@@ -96,6 +105,38 @@ def feature_tiling(f: int, n_bins: int, feature_block: int | None = None) -> tup
         feature_block = whole if whole <= LANES else LANES
     unit = math.lcm(feature_block, align)
     return -(-max(f, 1) // unit) * unit, feature_block
+
+
+def split_vmem_bytes(node_block: int, feature_block: int, n_bins: int) -> int:
+    """Modelled scoped VMEM of one split-scan tile: ``SPLIT_VMEM_BLOCKS``
+    f32 blocks of ``node_block`` rows (whole 8-row sublane tiles) by
+    ``feature_block * n_bins`` lanes."""
+    rows = -(-node_block // 8) * 8
+    return SPLIT_VMEM_BLOCKS * rows * feature_block * n_bins * 4
+
+
+def split_tiling(l: int, f: int, n_bins: int) -> tuple[int, int, int, int]:
+    """``(padded L, node block, padded F, feature block)`` of the split scan
+    over an (L, F, n_bins) level.
+
+    The feature block is ``feature_tiling``'s. The node block is the whole
+    level when its tile fits ``SPLIT_VMEM_BUDGET`` (one node block, as at
+    HIGGS's L <= 16 x 28 features); else the level splits into the fewest
+    blocks of a multiple of 8 nodes that fit, and L pads to whole blocks.
+    A geometry whose 8-node tile exceeds the budget (over 136 bins at
+    more than 128 features) raises.
+    """
+    f_pad, fb = feature_tiling(f, n_bins)
+    tile8 = split_vmem_bytes(8, fb, n_bins)
+    if tile8 > SPLIT_VMEM_BUDGET:
+        raise ValueError(
+            f"split scan: an 8-node tile of {fb} features x {n_bins} bins "
+            f"prices at {tile8} bytes, over the {SPLIT_VMEM_BUDGET}-byte budget")
+    if split_vmem_bytes(l, fb, n_bins) <= SPLIT_VMEM_BUDGET:
+        return l, l, f_pad, fb
+    n_blocks = -(-l // (SPLIT_VMEM_BUDGET // tile8 * 8))
+    node_block = -(-l // (8 * n_blocks)) * 8
+    return n_blocks * node_block, node_block, f_pad, fb
 
 
 def default_sample_block(feature_block: int, n_bins: int) -> int:
@@ -183,7 +224,8 @@ def sweep_level_build(
     reps: int = 3,
 ) -> tuple[dict, list[dict]]:
     """Sweep the fused kernel over (sample_block, feature_block) for ONE
-    geometry, then time the staged split kernel at the winner.
+    geometry, then time the staged split kernel (at its own blocks,
+    ``split_tiling``).
 
     Returns ``(entry, records)``: the winning entry (ready for
     ``save_table``) and every measured point. The fused kernel is timed
@@ -227,10 +269,7 @@ def sweep_level_build(
         sample_block=best["sample_block"], feature_block=best["feature_block"],
     )
     split_s = _time(
-        lambda: ops.split_gain(
-            hist, 1.0, 1e-3, backend="pallas", feature_block=best["feature_block"]
-        ),
-        reps=reps,
+        lambda: ops.split_gain(hist, 1.0, 1e-3, backend="pallas"), reps=reps
     )
     entry = {
         "sample_block": best["sample_block"],

@@ -103,8 +103,11 @@ def _node_totals(
     'what the stored entries are missing' term. Row-local (N work); under
     data sharding it psums alongside the stored histogram."""
     n_sub = active_nodes.shape[0]
-    inv = jnp.full((n_nodes,), -1, jnp.int32)
-    inv = inv.at[active_nodes].set(jnp.arange(n_sub, dtype=jnp.int32))
+    # Each node's slot among the (distinct) active nodes, or -1: a compare
+    # of the (n_nodes, n_sub) ids, not a scatter.
+    slots = jnp.arange(n_sub, dtype=jnp.int32)
+    hit = jnp.arange(n_nodes, dtype=jnp.int32)[:, None] == active_nodes[None, :]
+    inv = jnp.max(jnp.where(hit, slots[None, :], -1), axis=1)
     row = jnp.where(node_ids >= 0, inv[jnp.clip(node_ids, 0, n_nodes - 1)], -1)
     return node_sums(row, jnp.stack([grad, hess]), n_sub)
 
@@ -179,11 +182,13 @@ def build_histogram_sparse(
         entry_block=entry_block, feature_block=fb, interpret=interpret,
         active_nodes=None if active_nodes is None else active,
     )
-    totals = _node_totals(node_ids, grad, hess, active, n_nodes)
+    with jax.named_scope("histogram_sparse.complement"):
+        totals = _node_totals(node_ids, grad, hess, active, n_nodes)
     if axis_name is not None:
         stored = collectives.psum(stored, axis_name)
         totals = collectives.psum(totals, axis_name)
-    return _zero_bin_complement(stored, totals, zero_bin)
+    with jax.named_scope("histogram_sparse.complement"):
+        return _zero_bin_complement(stored, totals, zero_bin)
 
 
 def build_histogram(
@@ -293,10 +298,10 @@ def split_gain(
     lam,
     min_child_hess,
     backend: str = "auto",
-    feature_block: int | None = None,
 ) -> jax.Array:
-    """Gain surface (L, F, B), -inf where invalid. The kernel takes all L
-    nodes per tile, like the fused level program (same dot shapes)."""
+    """Gain surface (L, F, B), -inf where invalid. The kernel's node and
+    feature blocks come from the geometry (``autotune.split_tiling``);
+    padded nodes and features are dropped from the result."""
     from repro.kernels import autotune
 
     backend = resolve_backend(backend)
@@ -304,13 +309,13 @@ def split_gain(
     minh = jnp.asarray(min_child_hess, jnp.float32)
     if backend == "ref":
         return _ref.split_gain_surface_ref(hist, lam, minh)
-    _, _, f, b = hist.shape
-    f_pad, fb = autotune.feature_tiling(f, b, feature_block)
+    _, l, f, b = hist.shape
+    l_pad, nb, f_pad, fb = autotune.split_tiling(l, f, b)
     out = split_gain_pallas(
-        _pad_to(hist, f_pad, 2, 0.0), lam, minh, feature_block=fb,
-        interpret=jax.default_backend() != "tpu",
+        _pad_to(_pad_to(hist, l_pad, 1, 0.0), f_pad, 2, 0.0), lam, minh,
+        node_block=nb, feature_block=fb, interpret=jax.default_backend() != "tpu",
     )
-    return out[:, :f, :]
+    return out[:l, :f, :]
 
 
 def best_split(

@@ -16,8 +16,11 @@ in the same order, so kernel and oracle scans agree bit for bit on equal
 histograms. ``level_build`` calls the same ``split_gain_tile``, so fused
 and staged gains agree bit for bit too.
 
-Grid: (feature_blocks,); each program owns an (L, F_blk * B) tile of all
-nodes.
+Grid: (node_blocks, feature_blocks); each program owns an
+(L_blk, F_blk * B) tile. Every row of a tile is scanned on its own, so
+the tiling changes no value; ``kernels.autotune.split_tiling`` picks the
+blocks from the geometry so that the scoped VMEM stays bounded at any
+level width.
 """
 from __future__ import annotations
 
@@ -89,24 +92,31 @@ def _split_kernel(g_ref, h_ref, params_ref, gain_ref, *, n_bins: int):
     gain_ref[...] = jnp.where(valid, gain, -jnp.inf)
 
 
-@functools.partial(jax.jit, static_argnames=("feature_block", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("node_block", "feature_block", "interpret")
+)
 def split_gain_pallas(
     hist: jax.Array,  # (2, L, F, B) f32
     lam: jax.Array,  # scalar
     min_child_hess: jax.Array,
+    node_block: int | None = None,
     feature_block: int = 8,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Gain surface (L, F, B); invalid split points are -inf.
 
-    ``F`` must be a multiple of ``feature_block`` and ``feature_block * B``
-    a multiple of ``scan_width(B)`` (the ``kernels.ops`` wrapper pads).
+    ``L`` must be a multiple of ``node_block`` (``None``: all of ``L``),
+    which is a multiple of 8 or the whole ``L``; ``F`` a multiple of
+    ``feature_block``, and ``feature_block * B`` a multiple of
+    ``scan_width(B)`` (the ``kernels.ops`` wrapper pads).
     ``interpret=None`` auto-detects (Mosaic on TPU, interpreter elsewhere).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     _, l, f, b = hist.shape
+    node_block = l if node_block is None else node_block
     lanes = feature_block * b
+    assert l % node_block == 0 and (node_block % 8 == 0 or node_block == l)
     assert f % feature_block == 0 and lanes % scan_width(b) == 0
     params = jnp.stack([
         jnp.asarray(lam, jnp.float32),
@@ -116,13 +126,13 @@ def split_gain_pallas(
 
     gain = pl.pallas_call(
         functools.partial(_split_kernel, n_bins=b),
-        grid=(f // feature_block,),
+        grid=(l // node_block, f // feature_block),
         in_specs=[
-            pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
-            pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
+            pl.BlockSpec((node_block, lanes), lambda nb, fb: (nb, fb)),
+            pl.BlockSpec((node_block, lanes), lambda nb, fb: (nb, fb)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((l, lanes), lambda fb: (0, fb)),
+        out_specs=pl.BlockSpec((node_block, lanes), lambda nb, fb: (nb, fb)),
         out_shape=out_struct((l, f * b), jnp.float32, hist, params),
         interpret=interpret,
         name="split_gain_pallas",  # its stable name in the device trace

@@ -103,6 +103,96 @@ def test_split_gain_pallas_matches_ref(key, l, f, b):
     np.testing.assert_allclose(ref_m, pal_m, rtol=1e-4, atol=1e-4)
 
 
+def _signed_hist(key, l, f, b):
+    """(2, L, F, B) grad/hess histograms: signed grad, nonnegative hess,
+    with some empty bins so invalid split points appear."""
+    kg, kh, kz = jax.random.split(key, 3)
+    g = jax.random.normal(kg, (l, f, b), jnp.float32)
+    h = jax.random.uniform(kh, (l, f, b), jnp.float32)
+    empty = jax.random.uniform(kz, (l, f, b)) < 0.3
+    return jnp.stack([jnp.where(empty, 0.0, g), jnp.where(empty, 0.0, h)])
+
+
+@pytest.mark.parametrize(
+    "l,node_block",
+    [(1, 1), (8, 8), (64, 8), (64, 16), (64, 32), (64, 64)],
+)
+def test_split_gain_pallas_node_tiles_bitwise(key, l, node_block):
+    """Tiling the node axis changes no value: every node block's gains are
+    the oracle's, bit for bit (-inf at the same split points)."""
+    from repro.kernels.split_scan import split_gain_pallas
+
+    f, b = 6, 64  # 6 features: three 2-feature blocks of 128 lanes
+    hist = _signed_hist(key, l, f, b)
+    want = ref.split_gain_surface_ref(hist, jnp.float32(1.0), jnp.float32(1e-3))
+    got = split_gain_pallas(hist, 1.0, 1e-3, node_block=node_block, feature_block=2,
+                            interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("l", [1, 3, 8, 24, 64, 200])
+def test_split_gain_dispatch_pads_nodes_bitwise(key, monkeypatch, l):
+    """``ops.split_gain`` pads the level to whole node blocks and drops
+    the pad: with the budget cut so that a level needs several node
+    blocks, the surface is still the oracle's, bit for bit."""
+    from repro.kernels import autotune
+
+    f, b = 5, 16
+    monkeypatch.setattr(autotune, "SPLIT_VMEM_BUDGET",
+                        autotune.split_vmem_bytes(16, 8, b))
+    hist = _signed_hist(key, l, f, b)
+    want = ops.split_gain(hist, 1.0, 1e-3, backend="ref")
+    got = ops.split_gain(hist, 1.0, 1e-3, backend="pallas")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("f", [28, 300, 20958, 150360])
+@pytest.mark.parametrize("b", [16, 64, 128])
+def test_split_tiling_stays_in_budget(f, b):
+    """For every level width from 1 to 256 the chosen split blocks are ones
+    Mosaic lowers (a node block of a multiple of 8 or the whole level,
+    whole 128-lane groups of bins) and price within the VMEM budget."""
+    from repro.kernels import autotune
+    from repro.kernels.split_scan import scan_width
+
+    for l in range(1, 257):
+        l_pad, nb, f_pad, fb = autotune.split_tiling(l, f, b)
+        assert l_pad >= l and l_pad % nb == 0 and l_pad - l < max(nb, 8)
+        assert nb == l or nb % 8 == 0
+        assert f_pad >= f and f_pad % fb == 0 and (fb * b) % scan_width(b) == 0
+        assert f_pad - f < fb or fb == f_pad
+        assert autotune.split_vmem_bytes(nb, fb, b) <= autotune.SPLIT_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("f", [300, 20958])
+def test_split_tiling_refuses_a_tile_over_budget(f):
+    """256 bins over 128-feature blocks price an 8-node tile over the
+    budget: the chooser raises rather than hand Mosaic a block it would
+    refuse. The same bins over 28 features still fit."""
+    from repro.kernels import autotune
+
+    with pytest.raises(ValueError, match="over the"):
+        autotune.split_tiling(8, f, 256)
+    assert autotune.split_tiling(8, 28, 256) == (8, 8, 28, 28)
+
+
+@pytest.mark.parametrize("l", [1, 2, 4, 8, 16])
+def test_split_tiling_keeps_one_block_at_higgs(l):
+    """HIGGS's levels (L <= 16 nodes, 28 features, 64 bins) stay one block
+    in both axes: the whole level in one tile, as before node tiling."""
+    from repro.kernels import autotune
+
+    assert autotune.split_tiling(l, 28, 64) == (l, l, 28, 28)
+
+
+def test_split_tiling_realsim_depth7():
+    """real-sim's deepest split level (64 nodes x 20,958 features) splits
+    into node blocks of 16 over the 128-feature blocks."""
+    from repro.kernels import autotune
+
+    assert autotune.split_tiling(64, 20958, 64) == (64, 16, 20992, 128)
+
+
 @pytest.mark.parametrize("b", [8, 64, 256])
 def test_bin_prefix_sum_matches_cumsum(key, b):
     """The oracle's log-step scan fixes the kernels' summation order; it

@@ -3,7 +3,10 @@
 Every kernel is compiled for a described (not attached) TPU v5e chip at the
 widths of ``chip_smoke.py`` — HIGGS's 28 features, 64 bins, depth-5 trees —
 and the compiled program must hold a Mosaic kernel (``tpu_custom_call``);
-the whole tree build must also hold no ``scatter``.
+the whole tree build must also hold no ``scatter``. The split scan and the
+sparse tree build are compiled at real-sim's geometry too (72,309 rows x
+20,958 features, 51 stored entries a row, depth 7), where a level holds
+up to 64 nodes.
 Nothing runs: these tests catch what only the TPU compiler refuses (block
 shapes off the (8, 128) tiling, primitives Mosaic cannot lower, VMEM
 overflow) without a chip. The topology is described inside a fixture, so
@@ -26,6 +29,9 @@ N, F, B, DEPTH = 65_536, 28, 64, 5
 L = 1 << (DEPTH - 1)  # nodes of the deepest split level
 SLOTS = 1024  # a 1000-tree forest padded to whole 512-tree blocks
 ENTRIES = 4_096  # stored entries per feature for the sparse kernel
+# real-sim (arXiv:1804.04659 sec. VI.B): rows, features, stored entries a
+# row, and a feature-major width above its most-filled column.
+RS_N, RS_F, RS_NNZ, RS_WIDTH, RS_DEPTH = 72_309, 20_958, 51, 256, 7
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +97,39 @@ def test_split_scan_compiles(one_chip):
         return split_gain_pallas(hist, lam, min_h, feature_block=fb, interpret=False)
 
     assert _kernels(fn, args) >= 1
+
+
+@pytest.mark.parametrize("l,f", [(16, F), (64, RS_F), (256, RS_F), (256, F)])
+def test_split_scan_fits_half_vmem(one_chip, monkeypatch, l, f):
+    """At its chosen blocks the split scan compiles under a scoped VMEM
+    limit of ``SPLIT_VMEM_BUDGET`` (half of v5e's 16 MiB), from HIGGS's
+    16 nodes to the 64 of real-sim's depth 7 and the 256 of depth 9."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    call = pl.pallas_call
+
+    def limited(*args, **kwargs):
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=autotune.SPLIT_VMEM_BUDGET)
+        return call(*args, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", limited)
+    jax.clear_caches()  # trace anew, under the limit
+    l_pad, nb, f_pad, fb = autotune.split_tiling(l, f, B)
+    args = _shapes(
+        one_chip, ((2, l_pad, f_pad, B), jnp.float32), ((), jnp.float32),
+        ((), jnp.float32),
+    )
+
+    def fn(hist, lam, min_h):
+        return split_gain_pallas(hist, lam, min_h, node_block=nb, feature_block=fb,
+                                 interpret=False)
+
+    try:
+        assert _kernels(fn, args) >= 1
+    finally:
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("derive_sibling", [False, True], ids=["rebuild", "subtract"])
@@ -177,4 +216,31 @@ def test_build_tree_has_no_scatter(one_chip, monkeypatch, hist_mode, backend):
     finally:
         jax.clear_caches()
     assert text.count("tpu_custom_call") >= 1
+    assert "scatter(" not in text
+
+
+def test_sparse_build_tree_depth7_has_no_scatter(one_chip, monkeypatch):
+    """The sparse tree build at real-sim's geometry and published depth
+    compiles for the chip — 64-node split scans over 20,958 features, the
+    sparse histogram at every level — and holds no ``scatter``."""
+    from repro.trees.binning import SparseBins
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    cfg = LearnerConfig(depth=RS_DEPTH, n_bins=B, feature_fraction=0.8,
+                        backend="pallas", hist_mode="subtract")
+    bins = SparseBins(*_shapes(
+        one_chip, ((RS_N, RS_NNZ), jnp.int32), ((RS_N, RS_NNZ), jnp.int32),
+        ((RS_F, RS_WIDTH), jnp.int32), ((RS_F, RS_WIDTH), jnp.int32),
+        ((RS_F,), jnp.int32),
+    ))
+    args = _shapes(
+        one_chip, ((RS_N,), jnp.float32), ((RS_N,), jnp.float32), ((2,), jnp.uint32),
+    )
+    try:
+        text = jax.jit(lambda b, g, h, k: build_tree(cfg, b, g, h, k)).lower(
+            bins, *args).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert text.count("tpu_custom_call") >= 2  # histogram_sparse, split_scan
     assert "scatter(" not in text
