@@ -38,9 +38,11 @@ from repro.kernels.split_scan import LANES, scan_width
 
 TABLE_PATH = pathlib.Path(__file__).resolve().parent / "tuning_table.json"
 
-# The (F_blk * B, S) f32 one-hot factor the histogram dot consumes is the
+# The (F_blk * B, S) one-hot factor the histogram dot consumes is the
 # largest VMEM value of both histogram programs; the default sample block
-# keeps it under this many bytes.
+# keeps it under this many bytes priced at 4 bytes an entry. The one-hot is
+# bf16 (2 bytes), and the block stays at its f32 size on purpose: a larger
+# block is a choice of its own, to be timed on its own.
 ONEHOT_BYTES = 4 * 2**20
 MAX_SAMPLE_BLOCK = 512
 
@@ -140,9 +142,9 @@ def split_tiling(l: int, f: int, n_bins: int) -> tuple[int, int, int, int]:
 
 
 def default_sample_block(feature_block: int, n_bins: int) -> int:
-    """The largest multiple of 128 (at most 512) whose one-hot factor fits
-    ``ONEHOT_BYTES`` — never below 128, the lane width of the per-sample
-    operand rows."""
+    """The largest multiple of 128 (at most 512) whose one-hot factor, priced
+    at 4 bytes an entry, fits ``ONEHOT_BYTES`` — never below 128, the lane
+    width of the per-sample operand rows."""
     rows = ONEHOT_BYTES // (4 * feature_block * n_bins)
     return max(LANES, min(MAX_SAMPLE_BLOCK, rows // LANES * LANES))
 

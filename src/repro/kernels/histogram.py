@@ -10,18 +10,24 @@ reformulate the whole level-histogram as a single dense contraction:
 where row r carries (node_of_row[r], grad-or-hess), GH masks each sample's
 grad/hess onto its current tree node, and onehot marks the sample's bin for
 feature f. Both factor matrices are built on the fly inside VMEM from
-integer inputs — nothing of size (N, F*B) ever touches HBM. The dot runs at
-f32 precision: the one-hot factor is exact in any precision, but the MXU's
-default would round grad/hess to bf16.
+integer inputs — nothing of size (N, F*B) ever touches HBM.
+
+The dot is one bf16 MXU pass that loses nothing. The one-hot is 0/1, exact
+in bf16. Each f32 grad/hess splits exactly into three bf16 parts
+(``split_bf16``), stacked as three row blocks of GH: every product is exact
+and accumulates in f32, and the three blocks' sums are added at the end
+(``merge_parts``). At 3 * rows <= 128 the stack fills no more of the MXU's
+tile than GH alone; the one-hot, the big operand, passes through once, where
+an f32 dot at ``Precision.HIGHEST`` would pass it once per bf16 pass.
 
 The row -> node mapping is an explicit operand (``row_map``), not an iota:
 rows [0, R) carry the grad and rows [R, 2R) the hess of node
 ``row_map[r]``. The full-level build passes ``arange(n_nodes)`` twice; the
 histogram-subtraction tree builder (``trees.learner`` with
 ``hist_mode='subtract'``) passes the smaller child of every parent only,
-halving the GH rows — and therefore the MXU work — of every level below
-the root. Kernel cost is linear in ``rows``, so the node subset IS the
-speedup.
+halving the GH rows of every level below the root. That halves the MXU
+work only where the stacked 3 * rows pass one 128-row tile: below it, a
+level costs one pass of the one-hot whatever its node count.
 
 Samples ride the lane axis everywhere: node, grad and hess arrive as
 (1, N) rows (the layout GH needs) and the bins as the feature-major (F, N)
@@ -46,33 +52,58 @@ from jax.experimental import pallas as pl
 from repro.kernels.vma import out_struct
 
 
+def split_bf16(x):
+    """``(hi, mid, lo)`` bf16 with ``hi + mid + lo == x`` exactly, in f32.
+
+    ``hi`` takes the top 8 significant bits of ``x``, ``mid`` the next 8 of
+    the exact residual and ``lo`` the rest, which fits bf16's 8. Exact for
+    0 and for 2**-103 <= |x| up to half a bf16 step below f32's largest
+    value: there ``hi`` rounds to inf, the one limit a gradient could meet.
+    (Below 2**-103 ``lo`` would be subnormal, and what is lost lies under
+    2**-126.)"""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def merge_parts(acc):
+    """``(3 * rows, ...)`` sums of the hi, mid and lo row blocks ->
+    ``(rows, ...)``, added hi + mid + lo in that order."""
+    rows = acc.shape[0] // 3
+    return acc[:rows] + acc[rows : 2 * rows] + acc[2 * rows :]
+
+
 def gh_factor(row_node, node, grad, hess):
-    """The (rows, S) GH factor from a (rows, 1) row map and (1, S) sample
-    rows: row r < rows/2 carries the grad of samples on node
-    ``row_node[r]``, row r >= rows/2 their hess. Inactive samples
-    (node < 0) never match (row maps hold real node ids >= 0)."""
+    """The (3 * rows, S) bf16 GH factor from a (rows, 1) row map and (1, S)
+    sample rows: row r < rows/2 carries the grad of samples on node
+    ``row_node[r]``, row r >= rows/2 their hess, and the three row blocks
+    are the hi, mid and lo parts of those f32 values (``split_bf16``).
+    Inactive samples (node < 0) never match (row maps hold real node ids
+    >= 0)."""
     rows, s_blk = row_node.shape[0], node.shape[1]
     row_is_h = jax.lax.broadcasted_iota(jnp.int32, (rows, s_blk), 0) >= rows // 2
     gh_val = jnp.where(row_is_h, hess, grad)
-    return jnp.where(row_node == node, gh_val, 0.0)
+    gh = jnp.where(row_node == node, gh_val, 0.0)
+    return jnp.concatenate(split_bf16(gh), axis=0)
 
 
 def onehot_factor(bins_t, n_bins: int):
-    """The (F_blk * B, S) one-hot factor of an (F_blk, S) feature-major bins
-    block: ``[f*B + b, s] = 1{bins_t[f, s] == b}``."""
+    """The (F_blk * B, S) bf16 one-hot factor of an (F_blk, S) feature-major
+    bins block: ``[f*B + b, s] = 1{bins_t[f, s] == b}``."""
     f_blk, s_blk = bins_t.shape
     bin_iota = jax.lax.broadcasted_iota(jnp.int32, (f_blk, n_bins, s_blk), 1)
-    onehot = (bins_t[:, None, :] == bin_iota).astype(jnp.float32)
+    onehot = (bins_t[:, None, :] == bin_iota).astype(jnp.bfloat16)
     return onehot.reshape(f_blk * n_bins, s_blk)
 
 
 def hist_dot(gh, onehot):
-    """GH @ onehot^T at f32 precision, (rows, S) x (F_blk*B, S) ->
-    (rows, F_blk*B) — the one histogram contraction both histogram
-    programs issue."""
+    """GH @ onehot^T, (3 * rows, S) x (F_blk*B, S) -> (3 * rows, F_blk*B)
+    f32: one bf16 pass whose products are exact, accumulated in f32 — the
+    one histogram contraction both histogram programs issue."""
     return jax.lax.dot_general(
-        gh, onehot, (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+        gh, onehot, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
 
 
@@ -82,7 +113,7 @@ def _hist_kernel(
     grad_ref,  # (1, S_blk) f32
     hess_ref,  # (1, S_blk) f32
     rowmap_ref,  # (rows, 1) int32 — node id each GH row selects
-    out_ref,  # (rows, F_blk*B) f32
+    out_ref,  # (3 * rows, F_blk*B) f32 — hi, mid and lo row blocks
     *,
     n_bins: int,
 ):
@@ -146,10 +177,10 @@ def histogram_pallas(
             pl.BlockSpec((rows, 1), lambda fb, sb: (0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (rows, feature_block * n_bins), lambda fb, sb: (0, fb)
+            (3 * rows, feature_block * n_bins), lambda fb, sb: (0, fb)
         ),
         out_shape=out_struct(
-            (rows, f * n_bins), jnp.float32, bins, node_ids, grad, hess, row_map
+            (3 * rows, f * n_bins), jnp.float32, bins, node_ids, grad, hess, row_map
         ),
         interpret=interpret,
         name="histogram_pallas",  # its stable name in the device trace
@@ -160,5 +191,5 @@ def histogram_pallas(
         hess[None, :],
         row_map[:, None],
     )
-    # rows are (grad|hess, row) -> (gh, row, feature, bin)
-    return out.reshape(2, n_sub, f, n_bins)
+    # rows are (part, grad|hess, row) -> (gh, row, feature, bin)
+    return merge_parts(out).reshape(2, n_sub, f, n_bins)

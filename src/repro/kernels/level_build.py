@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.histogram import gh_factor, hist_dot, onehot_factor
+from repro.kernels.histogram import gh_factor, hist_dot, merge_parts, onehot_factor
 from repro.kernels.split_scan import split_gain_tile
 from repro.kernels.vma import out_struct
 
@@ -75,7 +75,7 @@ def _level_kernel(
     split_ref,  # out (L, 2) int32 — [best_feature, best_bin] per node
     gain_ref,  # out (L, 1) f32 — best gain per node (pre pass-left fix)
     node_out_ref,  # out (1, S_blk) int32 — new row -> node map
-    acc_ref,  # scratch (2 * L_sub, FB_pad) f32 — built-row accumulator
+    acc_ref,  # scratch (6 * L_sub, FB_pad) f32 — built rows' hi, mid, lo sums
     *,
     ns: int,
     n_bins: int,
@@ -85,7 +85,7 @@ def _level_kernel(
 ):
     t = pl.program_id(0)
     f_pad, s_blk = bins_ref.shape
-    l_sub = acc_ref.shape[0] // 2
+    l_sub = acc_ref.shape[0] // 6
     l = n_nodes
     fb = f_pad * n_bins
     chunk = feature_block * n_bins
@@ -108,8 +108,9 @@ def _level_kernel(
 
     @pl.when(t == ns)
     def _decide():
-        g_full = acc_ref[:l_sub, :]  # (L_sub, FB) grad rows
-        h_full = acc_ref[l_sub:, :]  # hess rows
+        acc = merge_parts(acc_ref[...])  # as the staged wrapper adds them
+        g_full = acc[:l_sub, :]  # (L_sub, FB) grad rows
+        h_full = acc[l_sub:, :]  # hess rows
         if derive_sibling:
             # Node n (parent p = n >> 1) is either the built child or the
             # derived sibling ``parent - built`` — the subtraction runs on
@@ -279,7 +280,7 @@ def level_build_pallas(
             out_struct((n_nodes, 1), jnp.float32, *operands),
             out_struct((1, n), jnp.int32, *operands),
         ],
-        scratch_shapes=[pltpu.VMEM((2 * l_sub, fb), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((6 * l_sub, fb), jnp.float32)],
         interpret=interpret,
         name="level_build_pallas",  # its stable name in the device trace
     )(*operands)
@@ -331,19 +332,20 @@ def fused_level_vmem_bytes(
 ) -> int:
     """The fused program's peak VMEM footprint model (DESIGN.md §13).
 
-    Resident blocks: the built-row accumulator (2*L_sub, F, B), the parent
-    cache (2, L_sub, F, B), the level-histogram output window
-    (2, L, F, B), the (S_blk, F) bins block, and phase B's scan
+    Resident blocks: the built-row accumulator (6*L_sub, F, B) (hi, mid
+    and lo sums), the parent cache (2, L_sub, F, B), the level-histogram
+    output window (2, L, F, B), the (S_blk, F) bins block, the bf16
+    (S_blk, F_blk * B) one-hot, and phase B's scan
     temporaries (~3 extra (L, F, B) values for prefix sums and the gain).
     The learner falls back to the staged path for any level whose estimate
     exceeds the budget — deep wide levels, where histogram tiling is the
     right call anyway.
     """
     fb = n_feat * n_bins
-    acc = 2 * n_sub * fb
+    acc = 6 * n_sub * fb
     parent = 2 * n_sub * fb
     hist_out = 2 * n_nodes * fb
     bins_blk = sample_block * n_feat
     onehot = sample_block * feature_block * n_bins
     scan_tmp = 3 * n_nodes * fb
-    return 4 * (acc + parent + hist_out + bins_blk + onehot + scan_tmp)
+    return 4 * (acc + parent + hist_out + bins_blk + scan_tmp) + 2 * onehot

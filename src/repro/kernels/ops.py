@@ -243,9 +243,9 @@ def build_histogram_subset(
 
     The histogram-subtraction builder's entry point: at each level it
     histograms one child per parent and derives the sibling as
-    ``parent - built``. Kernel work is linear in the GH row count
-    (2 * n_sub vs 2 * n_nodes), so building half the nodes halves the MXU
-    contraction per level.
+    ``parent - built``. It halves the GH rows (2 * n_sub vs 2 * n_nodes),
+    which halves the kernel's MXU work once its stacked rows pass one
+    128-row tile (``kernels.histogram``).
 
     ``axis_name``: as in ``build_histogram`` — per-shard subset histograms
     merge with a psum across the data axis. The SUBTRACTION does not live

@@ -92,6 +92,83 @@ def test_histogram_mass_conservation(n, f, n_bins, n_nodes, seed):
     np.testing.assert_allclose(per_feature_h, th, rtol=1e-3, atol=1e-3)
 
 
+def test_split_bf16_is_exact(key):
+    """hi + mid + lo rebuilds every f32 value bit for bit: random grads over
+    a wide range of magnitudes, hessians, 0, negatives, 1e-30 and the
+    smallest normal. The one limit is |x| near f32's largest value, where
+    ``hi`` rounds to inf."""
+    from repro.kernels.histogram import split_bf16
+
+    k1, k2, k3 = jax.random.split(key, 3)
+    n = 1 << 16
+    scale = jnp.exp2(jax.random.randint(k2, (n,), -60, 60).astype(jnp.float32))
+    tiny = np.finfo(np.float32).tiny
+    x = jnp.concatenate([
+        jax.random.normal(k1, (n,)) * scale,
+        jax.random.uniform(k3, (n,)) * 0.25,
+        jnp.asarray([0.0, 1e-30, -1e-30, tiny, -tiny, 1.0, -1.0, 3e38, -3e38],
+                    jnp.float32),
+    ])
+    parts = split_bf16(x)
+    assert all(p.dtype == jnp.bfloat16 for p in parts)
+    hi, mid, lo = (np.asarray(p, np.float32) for p in parts)
+    xs = np.asarray(x)
+    np.testing.assert_array_equal((hi + mid) + lo, xs)
+    assert np.any(hi != xs)  # the parts below hi carry bits
+    assert np.isinf(np.asarray(split_bf16(jnp.float32(3.4e38))[0], np.float32))
+
+
+def _histogram_f64(bins, node, grad, hess, active, n_bins):
+    """(2, R, F, B) float64 sums and absolute masses of the rows of
+    ``active`` nodes."""
+    bins, node = np.asarray(bins), np.asarray(node)
+    r_of = {int(a): r for r, a in enumerate(np.asarray(active))}
+    val = np.zeros((2, len(r_of), bins.shape[1], n_bins))
+    mass = np.zeros_like(val)
+    for s in np.flatnonzero(np.isin(node, list(r_of))):
+        r = r_of[int(node[s])]
+        for i, w in enumerate((float(grad[s]), float(hess[s]))):
+            val[i, r, np.arange(bins.shape[1]), bins[s]] += w
+            mass[i, r, np.arange(bins.shape[1]), bins[s]] += abs(w)
+    return val, mass
+
+
+@pytest.mark.parametrize("parts", ["hi_mid_lo", "hi_only"])
+@pytest.mark.parametrize("subset", [False, True], ids=["full_level", "node_subset"])
+def test_histogram_error_vs_float64(key, monkeypatch, subset, parts):
+    """Against float64 sums the kernel's error stays under 1e-6 of each
+    cell's absolute mass, full level and node subset alike. A planted
+    variant that keeps only the hi part of grad/hess (their bf16 rounding)
+    fails the same bound: the test sees bf16 rounding."""
+    import repro.kernels.histogram as H
+
+    n, f, n_bins, n_nodes = 2048, 6, 16, 4
+    bins, node, grad, hess = _rand_case(key, n, f, n_bins, n_nodes)
+    grad = grad * jnp.exp2(jax.random.randint(key, (n,), -8, 8).astype(jnp.float32))
+    active = jnp.asarray([3, 0], jnp.int32) if subset else jnp.arange(n_nodes)
+    if parts == "hi_only":
+        def hi_only(x):
+            hi = x.astype(jnp.bfloat16)
+            return hi, jnp.zeros_like(hi), jnp.zeros_like(hi)
+
+        monkeypatch.setattr(H, "split_bf16", hi_only)
+    jax.clear_caches()  # trace the kernel anew, with or without the plant
+    try:
+        out = histogram_pallas(
+            bins, node, grad, hess, n_nodes, n_bins, sample_block=512,
+            interpret=True, active_nodes=active if subset else None,
+        )
+    finally:
+        jax.clear_caches()
+    val, mass = _histogram_f64(bins, node, grad, hess, active, n_bins)
+    err = np.abs(np.asarray(out, np.float64) - val)
+    rel = np.max(err[mass > 0] / mass[mass > 0])
+    if parts == "hi_mid_lo":
+        assert rel <= 1e-6, rel
+    else:
+        assert rel > 1e-6, rel
+
+
 # --------------------------------------------------------------- split gain
 @pytest.mark.parametrize("l,f,b", [(1, 4, 8), (4, 8, 16), (8, 16, 64), (16, 7, 32)])
 def test_split_gain_pallas_matches_ref(key, l, f, b):

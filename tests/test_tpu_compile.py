@@ -70,21 +70,53 @@ def _tiling():
     return f_pad, fb, autotune.default_sample_block(fb, B)
 
 
-@pytest.mark.parametrize("subset", [False, True], ids=["full_level", "node_subset"])
-def test_histogram_compiles(one_chip, subset):
+def _kernel_dots(fn, args):
+    """The operand dtypes and precision of every ``dot_general`` inside the
+    Pallas kernels ``fn`` traces to."""
+    found = []
+
+    def walk(jaxpr, in_kernel):
+        for eqn in jaxpr.eqns:
+            if in_kernel and eqn.primitive.name == "dot_general":
+                found.append((tuple(v.aval.dtype for v in eqn.invars),
+                              eqn.params["precision"]))
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):  # cond branches
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, in_kernel or eqn.primitive.name == "pallas_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return found
+
+
+BF16_DOT = ((jnp.bfloat16, jnp.bfloat16), None)
+
+
+# (level nodes, nodes built): HIGGS's subtract-mode levels with 2 and 16 GH
+# rows, the full 16-node level, and a full 64-node level (128 rows, 384
+# stacked: past one MXU tile).
+@pytest.mark.parametrize("l,l_sub", [(L, L), (L, L // 2), (2, 1), (64, 64)],
+                         ids=["full_level", "node_subset", "rows2", "full_level64"])
+def test_histogram_compiles(one_chip, l, l_sub):
+    """The dense histogram compiles for v5e at HIGGS's geometry (28
+    features, 64 bins, 512-sample blocks), and its one dot takes bf16
+    operands at default precision."""
     f_pad, fb, sb = _tiling()
+    assert sb == 512
     args = _shapes(
         one_chip, ((N, f_pad), jnp.int32), ((N,), jnp.int32),
-        ((N,), jnp.float32), ((N,), jnp.float32), ((L // 2,), jnp.int32),
+        ((N,), jnp.float32), ((N,), jnp.float32), ((l_sub,), jnp.int32),
     )
 
     def fn(bins, node, g, h, active):
         return histogram_pallas(
-            bins, node, g, h, L, B, sample_block=sb, feature_block=fb,
-            interpret=False, active_nodes=active if subset else None,
+            bins, node, g, h, l, B, sample_block=sb, feature_block=fb,
+            interpret=False, active_nodes=active if l_sub < l else None,
         )
 
     assert _kernels(fn, args) >= 1
+    assert _kernel_dots(fn, args) == [BF16_DOT]
 
 
 def test_split_scan_compiles(one_chip):
@@ -150,6 +182,7 @@ def test_level_build_compiles(one_chip, derive_sibling):
         )
 
     assert _kernels(fn, args) >= 1
+    assert BF16_DOT in _kernel_dots(fn, args)  # the histogram's, as staged
 
 
 def test_histogram_sparse_compiles(one_chip):
