@@ -9,10 +9,9 @@ bins, feature fraction 0.8, sampling rate 0.8, logistic loss) through
 backend, W=4 round-robin workers, 8 rounds, on data at the published HIGGS
 geometry (11,000,000 x 28). It checks that the train loss is finite and
 falls, that the compiled round runs Mosaic kernels, and that one tree
-build under the staged (``pallas``) and the fused kernels matches the jnp
-oracle. It then serves requests of several sizes from a 1000-slot forest
-through ``ForestEngine`` and checks the scores against
-``ref.forest_traverse_ref``.
+build under the ``pallas`` kernels matches the jnp oracle. It then serves
+requests of several sizes from a 1000-slot forest through ``ForestEngine``
+and checks the scores against ``ref.forest_traverse_ref``.
 
 Four chips (``--chips 4``): trains on the (4, 1), (2, 2) and (1, 4)
 (data x feature) meshes, dense at the HIGGS geometry and, on (1, 4), a
@@ -181,7 +180,6 @@ def widen(forest, capacity: int):
 # ------------------------------------------------------------------ one chip
 def one_chip(meter: CompileMeter, report: dict) -> None:
     from repro.kernels import ref
-    from repro.kernels.level_build import fused_level_fits
     from repro.ps import Trainer
     from repro.ps.engine import propose_tree, round_body
     from repro.serving.continuous import ForestEngine
@@ -219,29 +217,23 @@ def one_chip(meter: CompileMeter, report: dict) -> None:
     check(kernels > 0, "the compiled round runs Pallas kernels (tpu_custom_call)")
     report["round_kernels"] = kernels
 
-    n_nodes = [1 << level for level in range(cfg.learner.depth)]
-    fits = [fused_level_fits(rows, n, max(n // 2, 1), HIGGS_FEATURES, 64)
-            for n in n_nodes]
-    print(f"fused program fits VMEM per level: {fits}", flush=True)
-    check(all(fits), "every level runs the fused program")
     trees = {}
-    for backend in ("ref", "pallas", "fused"):
+    for backend in ("ref", "pallas"):
         cfg_b = cfg._replace(learner=cfg.learner._replace(backend=backend))
         with meter.phase(f"tree_{backend}", report):
             tree, _ = propose_tree(cfg_b, data, state.f, key)
             trees[backend] = jax.device_get(tree)
-    for backend in ("pallas", "fused"):
-        got, want = trees[backend], trees["ref"]
-        same = (np.array_equal(got.feature, want.feature)
-                and np.array_equal(got.threshold, want.threshold))
-        dleaf = float(np.max(np.abs(got.leaf_value - want.leaf_value)))
-        print(f"tree {backend} vs ref: splits equal {same}, "
-              f"max |leaf diff| {dleaf:.3e}", flush=True)
-        check(same, f"{backend} tree splits equal the ref tree's")
-        check(np.allclose(got.leaf_value, want.leaf_value,
-                          rtol=LEAF_RTOL, atol=LEAF_ATOL),
-              f"{backend} leaves within rtol {LEAF_RTOL} atol {LEAF_ATOL}")
-        report[f"tree_{backend}_max_leaf_diff"] = dleaf
+    got, want = trees["pallas"], trees["ref"]
+    same = (np.array_equal(got.feature, want.feature)
+            and np.array_equal(got.threshold, want.threshold))
+    dleaf = float(np.max(np.abs(got.leaf_value - want.leaf_value)))
+    print(f"tree pallas vs ref: splits equal {same}, "
+          f"max |leaf diff| {dleaf:.3e}", flush=True)
+    check(same, "pallas tree splits equal the ref tree's")
+    check(np.allclose(got.leaf_value, want.leaf_value,
+                      rtol=LEAF_RTOL, atol=LEAF_ATOL),
+          f"pallas leaves within rtol {LEAF_RTOL} atol {LEAF_ATOL}")
+    report["tree_pallas_max_leaf_diff"] = dleaf
 
     forest = widen(state.forest, SERVE_SLOTS)
     rng = np.random.default_rng(SEED + 2)

@@ -10,8 +10,7 @@ Four checkers turn the repo's hand-enforced rules into a CI gate:
       (the subtract-after-psum invariant).
   locks — ``# guarded-by:`` lock-discipline AST pass over the threaded
       runtime and the serving hot-swap pair.
-  vmem — BlockSpec scalar/SMEM placement plus tuning-table schema and
-      VMEM-budget pricing (absorbs ``benchmarks/check_tuning_table``).
+  vmem — BlockSpec scalar/SMEM placement in the kernel modules.
   lints — hardcoded ``interpret=True``, stray ``PRNGKey`` minting outside
       the ticket-key derivation sites, unknown trace-v2 row fields.
 

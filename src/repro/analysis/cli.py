@@ -8,8 +8,7 @@ only ever tightens, and loosening it is a reviewed one-line diff to the
 baseline file.
 
     python -m repro.analysis                    # full run vs baseline
-    python -m repro.analysis --only vmem        # one checker (the old
-                                                #   check_tuning_table)
+    python -m repro.analysis --only vmem        # one checker
     python -m repro.analysis --write-baseline   # accept current findings
     python -m repro.analysis --json out.json    # CI artifact
     python -m repro.analysis --selftest         # inject a violation,
@@ -26,7 +25,6 @@ import argparse
 import importlib
 import json
 import pathlib
-import sys
 import textwrap
 
 from repro.analysis import findings as F

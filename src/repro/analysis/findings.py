@@ -15,9 +15,8 @@ offending line silences that code there — for the rare case where the
 checker is right about the pattern but wrong about the instance; the
 comment itself is the written-down justification.
 
-This module is stdlib-only (no jax) so the lint-tier shim
-``benchmarks/check_tuning_table.py`` can import through the package on a
-runner that never installed the ML stack.
+This module is stdlib-only (no jax) so the lint tier can import it
+through the package on a runner that never installed the ML stack.
 """
 from __future__ import annotations
 
@@ -36,8 +35,8 @@ class Finding:
     """One violation of a machine-checked invariant.
 
     ``ident`` is the stable within-file identifier the fingerprint uses
-    instead of the line number: a function/variable name, a tuning-table
-    geometry key, a primitive name — whatever survives unrelated edits.
+    instead of the line number: a function/variable name, a primitive
+    name — whatever survives unrelated edits.
     """
 
     checker: str  # 'determinism' | 'locks' | 'vmem' | 'lints'

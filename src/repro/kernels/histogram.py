@@ -100,8 +100,7 @@ def onehot_factor(bins_t, n_bins: int):
 
 def hist_dot(gh, onehot):
     """GH @ onehot^T, (3 * rows, S) x (F_blk*B, S) -> (3 * rows, F_blk*B)
-    f32: one bf16 pass whose products are exact, accumulated in f32 — the
-    one histogram contraction both histogram programs issue."""
+    f32: one bf16 pass whose products are exact, accumulated in f32."""
     return jax.lax.dot_general(
         gh, onehot, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )

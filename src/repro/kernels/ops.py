@@ -13,34 +13,22 @@ import jax
 import jax.numpy as jnp
 
 from repro import collectives
+from repro.kernels import autotune
 from repro.kernels import ref as _ref
 from repro.kernels.histogram import histogram_pallas
 from repro.kernels.histogram_sparse import histogram_sparse_pallas
 from repro.kernels.split_scan import split_gain_pallas
 
-BACKENDS = ("auto", "ref", "pallas", "fused")
+BACKENDS = ("auto", "ref", "pallas")
 
 
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
-def resolve_backend(backend: str, allow_fused: bool = False) -> str:
-    """THE backend normalization — learner, ops, and the fused path share it.
-
-    ``'auto'`` resolves to ``'pallas'`` on TPU and ``'ref'`` elsewhere.
-    ``'fused'`` (the whole-level program) survives only where a caller can
-    actually run it (``allow_fused=True``: the tree learner's level loop
-    and ``level_build``); staged kernel entry points degrade it to
-    ``'pallas'`` — the fused pipeline IS the pallas kernel family, so a
-    staged call inside a fused build stays in the same numerics.
-    """
+def resolve_backend(backend: str) -> str:
+    """The one kernel choice: ``'auto'`` resolves to ``'pallas'`` on a TPU
+    and ``'ref'`` elsewhere; an unknown name raises."""
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r} (want one of {BACKENDS})")
     if backend == "auto":
-        return _default_backend()
-    if backend == "fused" and not allow_fused:
-        return "pallas"
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
     return backend
 
 
@@ -199,8 +187,6 @@ def build_histogram(
     n_nodes: int,
     n_bins: int,
     backend: str = "auto",
-    sample_block: int | None = None,
-    feature_block: int | None = None,
     axis_name: str | None = None,
 ) -> jax.Array:
     """(2, n_nodes, F, n_bins) grad/hess histograms. See kernels/histogram.py.
@@ -215,13 +201,9 @@ def build_histogram(
     the kernel and the results merge with a psum across the axis — every
     cell is a sum over disjoint sample subsets, so partial sums compose
     exactly (the parameter-server aggregation as an all-reduce).
-
-    Blocks default to ``kernels.autotune.lookup`` for the geometry — the
-    same blocks the fused level program takes, so the two stay bitwise.
     """
     return build_histogram_subset(
         bins, node_ids, grad, hess, None, n_nodes, n_bins, backend=backend,
-        sample_block=sample_block, feature_block=feature_block,
         axis_name=axis_name,
     )
 
@@ -235,8 +217,6 @@ def build_histogram_subset(
     n_nodes: int,
     n_bins: int,
     backend: str = "auto",
-    sample_block: int | None = None,
-    feature_block: int | None = None,
     axis_name: str | None = None,
 ) -> jax.Array:
     """(2, n_sub, F, n_bins) histograms for the ``active_nodes`` subset only.
@@ -252,6 +232,10 @@ def build_histogram_subset(
     here: it commutes with the psum (both are linear), and the learner
     subtracts after the collective so every shard derives the sibling from
     identical merged values and stays in lockstep.
+
+    The kernel's blocks come from the geometry alone: the feature block
+    from ``autotune.feature_tiling``, the sample block from
+    ``autotune.default_sample_block``.
     """
     from repro.trees.binning import SparseBins  # lazy: trees imports kernels
 
@@ -271,14 +255,9 @@ def build_histogram_subset(
             bins, node_ids, grad, hess, active_nodes, n_nodes, n_bins
         )
     else:
-        from repro.kernels import autotune
-
-        n, n_feat = bins.shape
-        blocks = autotune.lookup(n, n_feat, n_bins, n_nodes)
-        sb = sample_block or blocks["sample_block"]
-        f_pad, fb = autotune.feature_tiling(
-            n_feat, n_bins, feature_block or blocks["feature_block"]
-        )
+        n_feat = bins.shape[1]
+        f_pad, fb = autotune.feature_tiling(n_feat, n_bins)
+        sb = autotune.default_sample_block(fb, n_bins)
         binsp = _pad_to(_pad_to(bins, sb, 0, 0), f_pad, 1, 0)
         nodep = _pad_to(node_ids, sb, 0, -1)  # padded samples inactive
         gradp = _pad_to(grad, sb, 0, 0.0)
@@ -302,8 +281,6 @@ def split_gain(
     """Gain surface (L, F, B), -inf where invalid. The kernel's node and
     feature blocks come from the geometry (``autotune.split_tiling``);
     padded nodes and features are dropped from the result."""
-    from repro.kernels import autotune
-
     backend = resolve_backend(backend)
     lam = jnp.asarray(lam, jnp.float32)
     minh = jnp.asarray(min_child_hess, jnp.float32)
@@ -328,72 +305,6 @@ def best_split(
     idx = jnp.argmax(flat, axis=-1)
     best = jnp.take_along_axis(flat, idx[:, None], axis=-1)[:, 0]
     return best, (idx // nb).astype(jnp.int32), (idx % nb).astype(jnp.int32)
-
-
-def level_build(
-    bins: jax.Array,  # (N, F) int32
-    node_ids: jax.Array,  # (N,) int32 level-local node per sample
-    grad: jax.Array,  # (N,) f32
-    hess: jax.Array,  # (N,) f32
-    active_nodes: jax.Array,  # (L_sub,) int32 nodes to histogram
-    parent_hist: jax.Array | None,  # (2, L_sub, F, B) cache (subtract mode)
-    feat_mask: jax.Array,  # (F,) bool/f32 — the tree's feature subsample
-    lam,
-    min_child_hess,
-    n_nodes: int,
-    n_bins: int,
-    backend: str = "fused",
-    derive_sibling: bool = False,
-    sample_block: int | None = None,
-    feature_block: int | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """ONE fused tree level: histogram -> (sibling derive) -> gain scan ->
-    argmax -> partition, without staging any surface through HBM.
-
-    Returns ``(hist (2, n_nodes, F, B), best_feature (n_nodes,), best_bin
-    (n_nodes,), best_gain (n_nodes,), new_node (N,))`` — everything
-    ``trees.learner.build_tree`` needs from a level: the histogram is the
-    next level's subtraction cache, feat/thr are final (unsplittable nodes
-    already fixed to pass-left), and ``new_node`` is the re-routed
-    row -> node map. ``backend='ref'`` is the staged jnp oracle
-    (``ref.level_build_ref``); ``'pallas'``/``'fused'`` run the fused
-    kernel. Block shapes default to the persistent autotuner table
-    (``kernels.autotune``) for the (N, F, B, L) geometry.
-    """
-    backend = resolve_backend(backend, allow_fused=True)
-    if backend == "ref":
-        return _ref.level_build_ref(
-            bins, node_ids, grad, hess, active_nodes.astype(jnp.int32),
-            parent_hist, feat_mask, jnp.asarray(lam, jnp.float32),
-            jnp.asarray(min_child_hess, jnp.float32), n_nodes, n_bins,
-            derive_sibling=derive_sibling,
-        )
-    from repro.kernels import autotune
-    from repro.kernels.level_build import level_build_pallas
-
-    n, n_feat = bins.shape
-    tuned = autotune.lookup(n, n_feat, n_bins, n_nodes)
-    sb = sample_block or tuned["sample_block"]
-    f_pad, fb = autotune.feature_tiling(
-        n_feat, n_bins, feature_block or tuned["feature_block"]
-    )
-    interpret = jax.default_backend() != "tpu"
-    binsp = _pad_to(_pad_to(bins, sb, 0, 0), f_pad, 1, 0)
-    nodep = _pad_to(node_ids, sb, 0, -1)  # padded samples inactive
-    gradp = _pad_to(grad, sb, 0, 0.0)
-    hessp = _pad_to(hess, sb, 0, 0.0)
-    maskp = _pad_to(feat_mask.astype(jnp.float32), f_pad, 0, 0.0)
-    parentp = None
-    if derive_sibling:
-        parentp = _pad_to(parent_hist, f_pad, 2, 0.0)
-    hist, feat, thr, best, new_node = level_build_pallas(
-        binsp, nodep, gradp, hessp, active_nodes.astype(jnp.int32), parentp,
-        maskp, jnp.asarray(lam, jnp.float32),
-        jnp.asarray(min_child_hess, jnp.float32), n_nodes, n_bins,
-        derive_sibling=derive_sibling, sample_block=sb, feature_block=fb,
-        interpret=interpret,
-    )
-    return hist[:, :, :n_feat, :], feat, thr, best, new_node[:n]
 
 
 apply_forest = _ref.apply_forest_ref  # unmasked train-time form (zero-padded slots)

@@ -92,15 +92,16 @@ def level_build_ref(
     n_bins: int,
     derive_sibling: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """The fused level-build oracle: (hist (2, L, F, B), best_feature (L,),
-    best_bin (L,), best_gain (L,), new_node (N,)).
+    """The oracle of one staged tree level: (hist (2, L, F, B),
+    best_feature (L,), best_bin (L,), best_gain (L,), new_node (N,)).
 
-    The staged ``trees.learner`` level body as one function — histogram
-    (subset + sibling derivation in subtract mode), gain scan, feature
-    mask, argmax with the first-maximum tie-break, the unsplittable
-    pass-left fix (feature 0, threshold ``n_bins - 1``), and the
-    ``2 * node + go_right`` re-route. ``kernels.level_build`` must match
-    this to f32 tolerance (bitwise at a single sample block).
+    ``trees.learner._staged_level`` written independently as one function
+    — histogram (subset + sibling derivation in subtract mode), gain scan,
+    feature mask, argmax with the first-maximum tie-break, the
+    unsplittable pass-left fix (feature 0, threshold ``n_bins - 1``), and
+    the ``2 * node + go_right`` re-route. The staged level must match its
+    histogram to f32 tolerance and its splits and routing exactly wherever
+    the gains are decisively separated.
     """
     built = histogram_subset_ref(
         bins, node_ids, grad, hess, active_nodes, n_nodes, n_bins

@@ -13,8 +13,7 @@ Mosaic has no ``cumsum`` lowering either; the prefix sum is a log-step
 (Hillis-Steele) scan of lane rolls and adds inside each B-lane run
 (``bin_prefix_sums``), and ``ref.bin_prefix_sum`` adds the same operands
 in the same order, so kernel and oracle scans agree bit for bit on equal
-histograms. ``level_build`` calls the same ``split_gain_tile``, so fused
-and staged gains agree bit for bit too.
+histograms.
 
 Grid: (node_blocks, feature_blocks); each program owns an
 (L_blk, F_blk * B) tile. Every row of a tile is scanned on its own, so
@@ -70,8 +69,7 @@ def bin_prefix_sums(x: jax.Array, n_bins: int) -> tuple[jax.Array, jax.Array]:
 
 
 def split_gain_tile(g, h, lam, min_h, n_bins: int):
-    """``(gain, valid)`` over an (L, F*B) grad/hess tile pair — the one
-    gain formula both the split kernel and the fused level program run."""
+    """``(gain, valid)`` over an (L, F*B) grad/hess tile pair."""
     gl, gt = bin_prefix_sums(g, n_bins)
     hl, ht = bin_prefix_sums(h, n_bins)
     gr = gt - gl

@@ -352,11 +352,9 @@ def main() -> None:
                          "each split's sibling from the cached parent "
                          "histogram (~half the kernel work); 'rebuild' "
                          "re-histograms every node (exact reference mode)")
-    ap.add_argument("--backend", choices=("auto", "ref", "pallas", "fused"),
+    ap.add_argument("--backend", choices=("auto", "ref", "pallas"),
                     default="auto",
-                    help="GBDT kernel backend: 'fused' runs one Pallas "
-                         "program per tree level (histogram+scan+partition "
-                         "without HBM staging); 'pallas' is the staged "
+                    help="GBDT kernel backend: 'pallas' is the staged "
                          "kernel pipeline; 'ref' the jnp oracles; 'auto' "
                          "picks pallas on TPU, ref elsewhere")
     ap.add_argument("--mesh", choices=("none", "1d", "2d"), default="none",

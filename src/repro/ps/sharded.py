@@ -44,16 +44,9 @@ def make_sharded_builder(cfg: LearnerConfig, mesh: Mesh, axis_name: str = "data"
     is replicated — histograms and leaf stats are psum'd, and split search
     is deterministic on the merged values.
 
-    The fused level-build backend is normalized to the STAGED pipeline in
-    here: the fused program scans the histograms it holds in VMEM, but
-    under shard_map those are shard-LOCAL, and every shard must take the
-    split decision on the psum-MERGED level. The collective is the seam
-    that pins the staged order (histogram kernel -> psum -> scan kernel);
-    ``build_tree`` enforces the fallback whenever ``axis_name`` is set, so
-    ``backend='fused'`` is safe to pass here — it just buys nothing.
-    Subtraction mode stays in lockstep for the same reason: the sibling is
-    derived AFTER the psum (subtraction commutes with it), so every
-    shard's derived rows are identical (see trees/learner.py).
+    Subtraction mode stays in lockstep: the sibling is derived AFTER the
+    psum (subtraction commutes with it), so every shard's derived rows are
+    identical (see trees/learner.py).
     """
     local = functools.partial(build_tree, cfg._replace(axis_name=axis_name))
     fn = shard_map(

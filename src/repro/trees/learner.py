@@ -21,26 +21,9 @@ Histogram modes (``LearnerConfig.hist_mode``):
 Either mode is deterministic WITHIN itself: the threaded runtime's
 record-and-replay contract (DESIGN.md §11) holds bit-for-bit per mode.
 
-Backends (``LearnerConfig.backend``), resolved through the shared
-``kernels.ops.resolve_backend``:
-  * ``'ref'`` — pure-jnp oracles (production CPU path);
-  * ``'pallas'`` — the STAGED kernel pipeline: histogram kernel, split-gain
-    kernel, jnp partition, one HBM round-trip between each;
-  * ``'fused'`` — ONE Pallas program per level (``kernels.level_build``):
-    histogram accumulation, sibling derivation, gain scan, argmax, and the
-    row re-route without staging any surface through HBM. Falls back to the
-    staged pallas pipeline per level when the level's resident set exceeds
-    the VMEM budget, and entirely under ``shard_map`` (``axis_name`` set):
-    the split decision must see the psum-MERGED histograms, so the
-    collective seam forces the staged order (see ``ps/sharded.py``);
-  * ``'auto'`` — pallas on TPU, ref elsewhere.
-The fused program is bit-compatible with the staged pallas path at MATCHED
-block shapes (same dot shapes in the same order). In the learner both take
-their blocks from ``kernels.autotune.lookup`` for the level's geometry, so
-they match; against the ``ref`` oracle (scatter-add histograms) the
-backends agree like the hist modes do: identically wherever gains are
-decisively separated, with near-tied deep splits free to flip within f32
-tolerance. DESIGN.md §13 documents both contracts.
+Backends (``LearnerConfig.backend``, resolved once by
+``kernels.ops.resolve_backend``): ``'auto'`` is ``'pallas'`` on a TPU, the
+staged kernel pipeline, and ``'ref'``, the jnp oracle, elsewhere.
 
 Conventions:
   * Caller supplies per-sample (g_i, h_i). For the paper's plain gradient
@@ -70,7 +53,7 @@ class LearnerConfig(NamedTuple):
     lam: float = 1.0  # L2 on leaf values
     min_child_hess: float = 1e-3
     feature_fraction: float = 0.8  # paper samples 80% of features per tree
-    backend: str = "ref"  # 'ref' | 'pallas' | 'fused' | 'auto'
+    backend: str = "auto"  # 'auto' | 'ref' | 'pallas'
     # Mesh axis samples are sharded over when building under shard_map
     # (repro.ps.sharded): histograms and leaf stats psum across it; the rng
     # must be replicated so every shard draws the same feature mask.
@@ -128,12 +111,11 @@ def _level_histogram(
     h: jax.Array,
     level: int,
     parent_hist: jax.Array | None,  # (2, 2^(level-1), F, B) from last level
-    backend: str | None = None,
+    backend: str,
 ) -> jax.Array:
     """The (2, 2^level, F, B) histogram of one level, by the config's mode."""
     n_nodes = 1 << level
     _check_hist_mode(cfg)
-    backend = cfg.backend if backend is None else backend
     if cfg.hist_mode == "rebuild" or level == 0:
         return ops.build_histogram(
             bins, node, g, h, n_nodes, n_bins=cfg.n_bins,
@@ -238,35 +220,6 @@ def _staged_level(
     return hist, feat, thr, 2 * node + go_right
 
 
-def _fused_level(
-    cfg: LearnerConfig,
-    bins: jax.Array,
-    node: jax.Array,
-    g: jax.Array,
-    h: jax.Array,
-    feat_mask: jax.Array,
-    level: int,
-    parent_hist: jax.Array | None,
-):
-    """One level as ONE Pallas program (``kernels.level_build``): the level
-    histogram never leaves VMEM between build, scan, and partition; only
-    the next level's subtraction cache and the (L,)-sized split vectors
-    reach HBM. Same returns as ``_staged_level``."""
-    n_nodes = 1 << level
-    _check_hist_mode(cfg)
-    derive = cfg.hist_mode == "subtract" and level > 0
-    if derive:
-        active = _smaller_children(cfg, node, h, n_nodes)
-    else:
-        active = jnp.arange(n_nodes, dtype=jnp.int32)
-    hist, feat, thr, _, new_node = ops.level_build(
-        bins, node, g, h, active, parent_hist if derive else None,
-        feat_mask.astype(jnp.float32), cfg.lam, cfg.min_child_hess,
-        n_nodes, cfg.n_bins, backend="fused", derive_sibling=derive,
-    )
-    return hist, feat, thr, new_node
-
-
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def build_tree(
     cfg: LearnerConfig,
@@ -275,12 +228,8 @@ def build_tree(
     h: jax.Array,  # (N,) f32 — weighted hessian / sample weight
     rng: jax.Array,  # feature-subsampling key
 ) -> Tree:
-    from repro.kernels.level_build import fused_level_fits
-
-    depth, n_bins = cfg.depth, cfg.n_bins
-    sparse = isinstance(bins, SparseBins)
     feature_sharded = cfg.feature_axis is not None
-    if sparse:
+    if isinstance(bins, SparseBins):
         # Under feature sharding only the feature-major store is sharded;
         # the row-major store + zero_bin stay replicated (they route
         # samples through GLOBAL feature ids). The histogram view gets the
@@ -298,27 +247,7 @@ def build_tree(
         f_global = f_local * (cfg.feature_shards if feature_sharded else 1)
         hist_bins = bins
 
-    backend = ops.resolve_backend(cfg.backend, allow_fused=True)
-    # The fused program computes split decisions from the histograms it
-    # holds in VMEM — under shard_map those are LOCAL, and the decision
-    # must see the psum-merged level (data axis) / argmax-merged decision
-    # (feature axis). The collective seam therefore pins the staged order
-    # (histogram -> psum -> scan -> merge); see ps/sharded.py. The sparse
-    # layout is staged-only too (the fused kernel is the dense program).
-    use_fused = (
-        backend == "fused"
-        and cfg.axis_name is None
-        and not feature_sharded
-        and not sparse
-    )
-    if backend == "fused":
-        # The staged fallback: matched-block pallas when the fused program
-        # is merely over VMEM budget for a level; the platform default
-        # under shard_map, where interpret-mode pallas_call has no
-        # replication rule (the collective seam, see ps/sharded.py).
-        staged = "pallas" if use_fused else ops.resolve_backend("auto")
-    else:
-        staged = backend
+    backend = ops.resolve_backend(cfg.backend)
 
     # The feature mask is drawn over the GLOBAL feature space from the
     # replicated rng — a 2D run consumes the key exactly like its 1D twin
@@ -337,27 +266,19 @@ def build_tree(
     thresholds = []
     hist = None  # the previous level's histograms (the subtraction cache)
 
-    for level in range(depth):
-        n_nodes = 1 << level
-        n_sub = max(n_nodes // 2, 1) if (cfg.hist_mode == "subtract" and level) \
-            else n_nodes
+    for level in range(cfg.depth):
         # Scopes (level, then child_counts / histogram / split / partition
         # inside it, and leaf_sums) name each step's device operations in
         # the compiled program's metadata; they change no value.
         with jax.named_scope(f"level{level}"):
-            if use_fused and fused_level_fits(n, n_nodes, n_sub, f_local, n_bins):
-                hist, feat, thr, node = _fused_level(
-                    cfg, bins, node, g, h, feat_mask, level, hist
-                )
-            else:
-                hist, feat, thr, node = _staged_level(
-                    cfg, staged, hist_bins, bins, node, g, h, feat_mask, level, hist
-                )
+            hist, feat, thr, node = _staged_level(
+                cfg, backend, hist_bins, bins, node, g, h, feat_mask, level, hist
+            )
         features.append(feat)
         thresholds.append(thr)
 
     # Leaf statistics.
-    n_leaves = 1 << depth
+    n_leaves = 1 << cfg.depth
     with jax.named_scope("leaf_sums"):
         leaf_g, leaf_h = ops.node_sums(node, jnp.stack([g, h]), n_leaves)
         if cfg.axis_name is not None:  # merge leaf stats across data shards

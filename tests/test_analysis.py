@@ -16,10 +16,9 @@ import sys
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from repro.analysis import determinism, findings, lints, locks, tuning_schema, vmem
+from repro.analysis import determinism, findings, lints, locks, vmem
 from repro.launch.mesh import make_mesh
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -163,35 +162,6 @@ def test_vmem_flags_corpus_blockspecs():
 def test_vmem_kernels_are_clean():
     for rel in vmem.KERNEL_FILES:
         assert vmem.check_blockspecs(ROOT / rel, rel) == [], rel
-
-
-def test_tuning_schema_flags_corpus_table():
-    table = json.loads((CORPUS / "bad_table.json").read_text())
-    errors = tuning_schema.validate(table)
-    joined = "\n".join(errors)
-    assert "N128_F8" in joined  # malformed key
-    assert "missing field" in joined
-    assert "must be > 0" in joined
-    assert "unknown fields" in joined
-
-
-def test_vmem_prices_over_budget_row(tmp_path):
-    from repro.kernels.level_build import FUSED_VMEM_BUDGET, fused_level_vmem_bytes
-
-    key = "N16384_F256_B64_L32"
-    n, f, b, l = tuning_schema.parse_geometry(key)
-    entry = {
-        "sample_block": 4096, "feature_block": 128,
-        "fused_ms": 1.0, "split_ms": 1.0, "host": "test",
-    }
-    assert (
-        fused_level_vmem_bytes(l, l, f, b, 4096, 128) > FUSED_VMEM_BUDGET
-    ), "geometry stopped exceeding the budget; pick a bigger corpus row"
-    p = tmp_path / "table.json"
-    p.write_text(json.dumps({"format": 1, "entries": {key: entry}}))
-    fs = vmem.check_tuning_table(p, "table.json")
-    assert "tuning-over-budget" in _codes(fs)
-    assert any(f.ident == key for f in fs)
 
 
 # ------------------------------------------------------------------- lints

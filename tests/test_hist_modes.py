@@ -29,10 +29,7 @@ from repro.trees.learner import LearnerConfig, build_tree
 from repro.trees.tree import apply_tree
 
 DEPTHS = (1, 3, 7)
-# 'fused' runs the whole-level Pallas program through the same parity
-# sweeps; in the histogram-only tests ops.resolve_backend folds it onto
-# the staged pallas kernel (level_build is the only fused-aware op).
-BACKENDS = ("ref", "pallas", "fused")
+BACKENDS = ("ref", "pallas")
 
 
 def _case(seed, n=700, f=9, n_bins=32):

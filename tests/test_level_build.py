@@ -1,35 +1,28 @@
-"""Fused level-build parity: the one-program level vs the staged pipeline.
+"""One tree level on the chip's path: the staged ``pallas`` pipeline
+(histogram kernel -> ``split_gain_pallas`` -> partition) against the jnp
+oracle, from a single level up to whole training runs.
 
-``kernels/level_build.py`` runs histogram accumulation, sibling
-derivation, the gain scan, argmax, and the row re-route as ONE Pallas
-program. Its contracts, pinned here:
+Contracts, pinned here:
 
-  * vs the staged PALLAS pipeline at matched block shapes: BITWISE — the
-    fused program issues the same dots in the same order (including the
-    K=1 single-sample-block case the acceptance floor names), so
-    histograms, split structure, and the row map are exactly equal;
-  * vs the jnp REF oracle: split structure and row map exactly equal on
-    continuous random data (gains decisively separated), histograms and
-    gains to f32 tolerance — rtol 1e-5 / atol 1e-4, the same budget the
-    staged kernels carry (one ulp per accumulated O(1..100) cell, dot
-    reduction order differs from segment_sum's);
-  * through training: the learner consults the committed autotuner table,
-    so fused block shapes need NOT match the staged defaults — the
-    cross-backend contract there is the same quantitative one the hist
-    modes carry (different f32 accumulation orders can flip argmax only
-    on near-ties): exact structure at well-populated levels, >= 90% of
-    nodes identical overall, and RMS payload drift <= 2% of scale, across
-    logistic / multiclass:3 / quantile:0.5 and both hist modes at depths
-    1/3/7 (multiclass lanes are the near-tie-prone ones: softmax splits
-    each node's gradient mass K ways);
-  * the PR-4/5 determinism contracts survive the new backend: threaded
-    record -> replay is bit-identical under ``backend='fused'``, and the
-    committed golden trace replays to the committed forest (structure
-    exact, leaves atol 1e-6 — the corpus was recorded on the ref
-    backend, so this calibrates fused-vs-ref drift end to end);
-  * levels over the VMEM budget fall back to the staged path with no
-    numeric change (fused == pallas stays bitwise when the budget forces
-    a mid-tree switch).
+  * one level (``trees.learner._staged_level``) vs ``ref.level_build_ref``,
+    on the dense bins and on the ``SparseBins`` of the same data: split
+    structure and row map exactly equal on continuous random data (gains
+    decisively separated), histograms to f32 tolerance — rtol 1e-5 /
+    atol 1e-4, one ulp per accumulated O(1..100) cell (the kernels' dot
+    and entry orders differ from segment_sum's);
+  * whole trees, worker rounds and training runs, ``pallas`` vs ``ref``:
+    the contract the hist modes carry (different f32 accumulation orders
+    can flip an argmax only on near-ties) — a tree's well-populated heap
+    prefix exact, >= 97% of its nodes identical and <= 1% RMS prediction
+    drift; a round's root split exact, >= 90% of nodes identical and its
+    loss within rel 1e-3, across logistic / multiclass:3 / quantile:0.5
+    and both hist modes (multiclass lanes are the near-tie-prone ones:
+    softmax splits each node's gradient mass K ways);
+  * the determinism contracts hold under ``backend='pallas'``: threaded
+    record -> replay is bit-identical, and the committed golden trace
+    replays to the committed forest (structure exact, leaves atol 1e-6 —
+    the corpus was recorded on the ref backend, so this calibrates
+    pallas-vs-ref drift end to end).
 """
 import jax
 import jax.numpy as jnp
@@ -38,15 +31,16 @@ import pytest
 
 from repro.core.sgbdt import SGBDTConfig, init_state
 from repro.kernels import ops
-from repro.kernels.level_build import (
-    FUSED_VMEM_BUDGET,
-    fused_level_fits,
-    fused_level_vmem_bytes,
-)
 from repro.kernels.ref import level_build_ref
 from repro.ps.engine import get_trainer, propose_tree
 from repro.ps.runtime import AsyncRuntime
-from repro.trees.learner import LearnerConfig, build_tree
+from repro.trees import binning
+from repro.trees.learner import (
+    LearnerConfig,
+    _smaller_children,
+    _staged_level,
+    build_tree,
+)
 
 DEPTHS = (1, 3, 7)
 OBJECTIVES = ("logistic", "multiclass:3", "quantile:0.5")
@@ -69,117 +63,106 @@ def _level_inputs(seed, n, f, n_bins, n_nodes):
     return bins, node, g, h
 
 
-# ------------------------------------------------------ kernel-level parity
-@pytest.mark.parametrize("n_nodes", [1, 2, 4, 8])
-@pytest.mark.parametrize("n,f", [(640, 8), (700, 9), (515, 3)])
-def test_fused_matches_ref_full_level(n, f, n_nodes):
-    """Full-level builds (derive_sibling=False) across ragged geometries
-    (515/700 exercise sample padding, 9/3 feature padding)."""
-    n_bins = 16
-    bins, node, g, h = _level_inputs(5, n, f, n_bins, n_nodes)
-    active = jnp.arange(n_nodes, dtype=jnp.int32)
-    mask = jnp.ones((f,), jnp.float32)
-    args = (bins, node, g, h, active, None, mask, 1.0, 1e-3, n_nodes, n_bins)
-    h_r, f_r, t_r, _, n_r = level_build_ref(*args)
-    h_f, f_f, t_f, _, n_f = ops.level_build(*args, backend="fused")
-    np.testing.assert_array_equal(np.asarray(f_f), np.asarray(f_r))
-    np.testing.assert_array_equal(np.asarray(t_f), np.asarray(t_r))
-    np.testing.assert_array_equal(np.asarray(n_f), np.asarray(n_r))
+def _staged(layout, bins, node, g, h, mask, level, parent, hist_mode):
+    """One staged ``pallas`` level on ``layout``'s view of ``bins``, and the
+    dense bins the oracle reads (``binning.to_dense`` of the sparse view).
+    Returns ((hist, feat, thr, new_node), oracle bins, config)."""
+    cfg = LearnerConfig(n_bins=16, backend="pallas", hist_mode=hist_mode)
+    view = binning.to_sparse(bins) if layout == "sparse" else bins
+    oracle_bins = binning.to_dense(view) if layout == "sparse" else bins
+    out = _staged_level(
+        cfg, "pallas", view, view, node, g, h, mask > 0, level, parent
+    )
+    return out, oracle_bins, cfg
+
+
+def _assert_level_matches(got, want):
+    h_s, f_s, t_s, n_s = got
+    h_r, f_r, t_r, _, n_r = want
+    np.testing.assert_array_equal(np.asarray(f_s), np.asarray(f_r))
+    np.testing.assert_array_equal(np.asarray(t_s), np.asarray(t_r))
+    np.testing.assert_array_equal(np.asarray(n_s), np.asarray(n_r))
     np.testing.assert_allclose(
-        np.asarray(h_f), np.asarray(h_r), rtol=1e-5, atol=1e-4
+        np.asarray(h_s), np.asarray(h_r), rtol=1e-5, atol=1e-4
     )
 
 
+# ------------------------------------------------------ kernel-level parity
+@pytest.mark.parametrize("layout", ["dense", "sparse"])
+@pytest.mark.parametrize("n_nodes", [1, 2, 4, 8])
+@pytest.mark.parametrize("n,f", [(640, 8), (700, 9), (515, 3)])
+def test_staged_matches_ref_full_level(n, f, n_nodes, layout):
+    """Full-level builds (``hist_mode='rebuild'``) across ragged geometries
+    (515/700 exercise sample padding, 9/3 feature padding)."""
+    n_bins = 16
+    bins, node, g, h = _level_inputs(5, n, f, n_bins, n_nodes)
+    level = n_nodes.bit_length() - 1
+    mask = jnp.ones((f,), jnp.float32)
+    got, dense, _ = _staged(layout, bins, node, g, h, mask, level, None, "rebuild")
+    active = jnp.arange(n_nodes, dtype=jnp.int32)
+    want = level_build_ref(
+        dense, node, g, h, active, None, mask, 1.0, 1e-3, n_nodes, n_bins
+    )
+    _assert_level_matches(got, want)
+
+
+@pytest.mark.parametrize("layout", ["dense", "sparse"])
 @pytest.mark.parametrize("level", [1, 2, 3])
-def test_fused_matches_ref_subtract_level(level):
-    """Subtraction levels: only the active children are accumulated, the
-    sibling comes from the parent cache inside the kernel."""
+def test_staged_matches_ref_subtract_level(level, layout):
+    """Subtraction levels: only the smaller child of every parent is
+    histogrammed, the sibling is derived from the parent cache."""
     n, f, n_bins = 640, 8, 16
     n_nodes = 1 << level
     bins, node, g, h = _level_inputs(7, n, f, n_bins, n_nodes)
     parent = ops.build_histogram(
         bins, node >> 1, g, h, n_nodes // 2, n_bins, backend="ref"
     )
-    active = 2 * jnp.arange(n_nodes // 2, dtype=jnp.int32)
     mask = jnp.ones((f,), jnp.float32)
-    args = (bins, node, g, h, active, parent, mask, 1.0, 1e-3, n_nodes, n_bins)
-    h_r, f_r, t_r, _, n_r = level_build_ref(*args, derive_sibling=True)
-    h_f, f_f, t_f, _, n_f = ops.level_build(
-        *args, backend="fused", derive_sibling=True
+    got, dense, cfg = _staged(
+        layout, bins, node, g, h, mask, level, parent, "subtract"
     )
-    np.testing.assert_array_equal(np.asarray(f_f), np.asarray(f_r))
-    np.testing.assert_array_equal(np.asarray(t_f), np.asarray(t_r))
-    np.testing.assert_array_equal(np.asarray(n_f), np.asarray(n_r))
-    np.testing.assert_allclose(
-        np.asarray(h_f), np.asarray(h_r), rtol=1e-5, atol=1e-4
+    active = _smaller_children(cfg, node, h, n_nodes)
+    want = level_build_ref(
+        dense, node, g, h, active, parent, mask, 1.0, 1e-3, n_nodes, n_bins,
+        derive_sibling=True,
     )
+    _assert_level_matches(got, want)
 
 
-@pytest.mark.parametrize("sample_block", [1024, 512, 256])
-def test_fused_bitwise_vs_staged_pallas(sample_block):
-    """The acceptance floor and beyond: at matched blocks the fused program
-    is BITWISE the staged pallas pipeline — 1024 is the K=1 single-block
-    case, 512/256 stream 2 and 4 blocks through the same accumulator."""
-    n, f, n_bins, n_nodes = 1024, 16, 16, 4
-    bins, node, g, h = _level_inputs(9, n, f, n_bins, n_nodes)
-    hist_s = ops.build_histogram(
-        bins, node, g, h, n_nodes, n_bins, backend="pallas",
-        sample_block=sample_block, feature_block=8,
-    )
-    gain_s = ops.split_gain(hist_s, 1.0, 1e-3, backend="pallas")
-    flat = gain_s.reshape(n_nodes, -1)
-    idx = jnp.argmax(flat, axis=-1)
-    feat_s = (idx // n_bins).astype(jnp.int32)
-    thr_s = (idx % n_bins).astype(jnp.int32)
-
-    active = jnp.arange(n_nodes, dtype=jnp.int32)
-    mask = jnp.ones((f,), jnp.float32)
-    hist_f, feat_f, thr_f, best_f, _ = ops.level_build(
-        bins, node, g, h, active, None, mask, 1.0, 1e-3, n_nodes, n_bins,
-        backend="fused", sample_block=sample_block, feature_block=8,
-    )
-    np.testing.assert_array_equal(np.asarray(hist_f), np.asarray(hist_s))
-    np.testing.assert_array_equal(np.asarray(feat_f), np.asarray(feat_s))
-    np.testing.assert_array_equal(np.asarray(thr_f), np.asarray(thr_s))
-    np.testing.assert_array_equal(
-        np.asarray(best_f),
-        np.asarray(jnp.take_along_axis(flat, idx[:, None], axis=-1)[:, 0]),
-    )
-
-
-def test_feature_mask_respected():
-    """Masked features never win a split, matching the staged argmax."""
+@pytest.mark.parametrize("layout", ["dense", "sparse"])
+def test_feature_mask_respected(layout):
+    """Masked features never win a split, matching the oracle's argmax."""
     n, f, n_bins, n_nodes = 512, 8, 16, 2
     bins, node, g, h = _level_inputs(13, n, f, n_bins, n_nodes)
     mask = jnp.asarray([1, 0, 1, 0, 1, 0, 1, 0], jnp.float32)
+    (_, f_s, t_s, _), dense, _ = _staged(
+        layout, bins, node, g, h, mask, 1, None, "rebuild"
+    )
     active = jnp.arange(n_nodes, dtype=jnp.int32)
-    args = (bins, node, g, h, active, None, mask, 1.0, 1e-3, n_nodes, n_bins)
-    _, f_r, t_r, _, _ = level_build_ref(*args)
-    _, f_f, t_f, _, _ = ops.level_build(*args, backend="fused")
-    np.testing.assert_array_equal(np.asarray(f_f), np.asarray(f_r))
-    np.testing.assert_array_equal(np.asarray(t_f), np.asarray(t_r))
-    assert np.all(np.asarray(f_f) % 2 == 0), "a masked feature won a split"
+    _, f_r, t_r, _, _ = level_build_ref(
+        dense, node, g, h, active, None, mask, 1.0, 1e-3, n_nodes, n_bins
+    )
+    np.testing.assert_array_equal(np.asarray(f_s), np.asarray(f_r))
+    np.testing.assert_array_equal(np.asarray(t_s), np.asarray(t_r))
+    assert np.all(np.asarray(f_s) % 2 == 0), "a masked feature won a split"
 
 
 # ------------------------------------------------- learner-level differential
 @pytest.mark.parametrize("hist_mode", ["subtract", "rebuild"])
 @pytest.mark.parametrize("depth", DEPTHS)
-def test_build_tree_fused_parity(key, depth, hist_mode):
-    """Whole trees, both hist modes, depths 1/3/7, fused vs pallas AND ref.
+def test_build_tree_backend_parity(key, depth, hist_mode):
+    """Whole trees, both hist modes, depths 1/3/7, pallas vs ref.
 
-    The learner picks the fused program's blocks from the committed
-    autotuner table, so its accumulation grouping legitimately differs
-    from both staged backends — the cross-backend contract is the
-    hist-mode one: bitwise structure on the well-populated heap prefix
-    (levels 0..3; decisively separated gains on continuous random data),
-    >= 97% of nodes identical overall, and <= 1% RMS prediction drift.
-    The BITWISE fused contract lives at matched blocks
-    (test_fused_bitwise_vs_staged_pallas)."""
+    The kernels' dot and entry orders differ from the oracle's
+    segment_sum, so the contract is the hist-mode one: bitwise structure
+    on the well-populated heap prefix (levels 0..3; decisively separated
+    gains on continuous random data), >= 97% of nodes identical overall,
+    and <= 1% RMS prediction drift."""
     from repro.trees.tree import apply_tree
 
     bins, g, h = _case(23)
     trees = {}
-    for backend in ("ref", "pallas", "fused"):
+    for backend in ("ref", "pallas"):
         cfg = LearnerConfig(
             depth=depth, n_bins=32, feature_fraction=1.0, backend=backend,
             hist_mode=hist_mode,
@@ -187,18 +170,16 @@ def test_build_tree_fused_parity(key, depth, hist_mode):
         trees[backend] = build_tree(cfg, bins, g, h, key)
     exact_nodes = (1 << min(depth, 4)) - 1  # heap prefix: levels 0..3
     pred = {b: np.asarray(apply_tree(t, bins)) for b, t in trees.items()}
-    for other in ("pallas", "ref"):
-        for name in ("feature", "threshold"):
-            a = np.asarray(getattr(trees["fused"], name))
-            b = np.asarray(getattr(trees[other], name))
-            np.testing.assert_array_equal(
-                a[:exact_nodes], b[:exact_nodes],
-                err_msg=f"fused vs {other}: {name} prefix",
-            )
-            assert np.mean(a == b) >= 0.97, f"fused vs {other}: {name} flips"
-        scale = np.sqrt(np.mean(pred[other] ** 2)) + 1e-12
-        drift = np.sqrt(np.mean((pred["fused"] - pred[other]) ** 2))
-        assert drift <= 0.01 * scale, f"fused vs {other}: drift {drift:.3e}"
+    for name in ("feature", "threshold"):
+        a = np.asarray(getattr(trees["pallas"], name))
+        b = np.asarray(getattr(trees["ref"], name))
+        np.testing.assert_array_equal(
+            a[:exact_nodes], b[:exact_nodes], err_msg=f"{name} prefix"
+        )
+        assert np.mean(a == b) >= 0.97, f"{name} flips"
+    scale = np.sqrt(np.mean(pred["ref"] ** 2)) + 1e-12
+    drift = np.sqrt(np.mean((pred["pallas"] - pred["ref"]) ** 2))
+    assert drift <= 0.01 * scale, f"drift {drift:.3e}"
 
 
 def _objective_cfg(objective, backend, hist_mode="subtract"):
@@ -219,14 +200,13 @@ def _objective_data(objective, sparse_data):
 
 @pytest.mark.parametrize("hist_mode", ["subtract", "rebuild"])
 @pytest.mark.parametrize("objective", OBJECTIVES)
-def test_propose_round_fused_parity(objective, hist_mode, sparse_data, key):
-    """One worker round per objective x hist mode: the fused backend's
+def test_propose_round_backend_parity(objective, hist_mode, sparse_data, key):
+    """One worker round per objective x hist mode: the pallas backend's
     pushed (tree, delta) payload vs ref (K-output shapes included).
 
     Multiclass lanes split each node's gradient mass K ways, so deep
     splits near-tie and one ulp of cross-backend accumulation drift can
-    flip an argmax (the learner's fused blocks come from the autotuner
-    table, not the staged defaults). A flipped near-tie re-routes real
+    flip an argmax. A flipped near-tie re-routes real
     samples, so the PAYLOAD may differ — what cannot differ is its
     QUALITY: the contract is exact root split per lane, >= 90% of nodes
     identical, and the post-update objective loss within rel 1e-3
@@ -247,56 +227,56 @@ def test_propose_round_fused_parity(objective, hist_mode, sparse_data, key):
     data = _objective_data(objective, sparse_data)
     obj = get_objective(objective)
     out = {}
-    for backend in ("ref", "fused"):
+    for backend in ("ref", "pallas"):
         cfg = _objective_cfg(objective, backend, hist_mode)
         state = init_state(cfg, data)
         out[backend] = (state.f, propose_tree(cfg, data, state.f, key))
-    (f0, (tree_r, delta_r)), (_, (tree_f, delta_f)) = out["ref"], out["fused"]
-    feat_r, feat_f = (np.asarray(t.feature) for t in (tree_r, tree_f))
-    thr_r, thr_f = (np.asarray(t.threshold) for t in (tree_r, tree_f))
+    (f0, (tree_r, delta_r)), (_, (tree_p, delta_p)) = out["ref"], out["pallas"]
+    feat_r, feat_p = (np.asarray(t.feature) for t in (tree_r, tree_p))
+    thr_r, thr_p = (np.asarray(t.threshold) for t in (tree_r, tree_p))
     # Root split of every output lane is decisively separated.
-    np.testing.assert_array_equal(feat_f[..., 0], feat_r[..., 0])
-    np.testing.assert_array_equal(thr_f[..., 0], thr_r[..., 0])
-    agree = np.mean((feat_f == feat_r) & (thr_f == thr_r))
+    np.testing.assert_array_equal(feat_p[..., 0], feat_r[..., 0])
+    np.testing.assert_array_equal(thr_p[..., 0], thr_r[..., 0])
+    agree = np.mean((feat_p == feat_r) & (thr_p == thr_r))
     assert agree >= 0.90, f"only {agree:.0%} of split nodes identical"
     loss_r = float(obj.loss(data.labels, f0 + delta_r))
-    loss_f = float(obj.loss(data.labels, f0 + delta_f))
-    assert abs(loss_f - loss_r) <= 1e-3 * abs(loss_r), (
-        f"update quality diverged: {loss_f:.6f} vs {loss_r:.6f}"
+    loss_p = float(obj.loss(data.labels, f0 + delta_p))
+    assert abs(loss_p - loss_r) <= 1e-3 * abs(loss_r), (
+        f"update quality diverged: {loss_p:.6f} vs {loss_r:.6f}"
     )
     if agree == 1.0:
         np.testing.assert_allclose(
-            np.asarray(tree_f.leaf_value), np.asarray(tree_r.leaf_value),
+            np.asarray(tree_p.leaf_value), np.asarray(tree_r.leaf_value),
             rtol=1e-5, atol=1e-6,
         )
         np.testing.assert_allclose(
-            np.asarray(delta_f), np.asarray(delta_r), rtol=1e-5, atol=1e-6
+            np.asarray(delta_p), np.asarray(delta_r), rtol=1e-5, atol=1e-6
         )
 
 
 @pytest.mark.parametrize("objective", OBJECTIVES)
-def test_training_fused_parity(objective, sparse_data):
-    """Multi-round scan training per objective: fused and ref loss curves
+def test_training_backend_parity(objective, sparse_data):
+    """Multi-round scan training per objective: pallas and ref loss curves
     agree to accumulated-f32 tolerance and both converge."""
     data = _objective_data(objective, sparse_data)
     losses = {}
-    for backend in ("ref", "fused"):
+    for backend in ("ref", "pallas"):
         _, losses[backend] = get_trainer(
             _objective_cfg(objective, backend)
         ).train_scan(data, ("round_robin", 2), seed=0)
-    ref_l, fus_l = (np.asarray(losses[b]) for b in ("ref", "fused"))
-    assert np.isfinite(ref_l).all() and np.isfinite(fus_l).all()
-    np.testing.assert_allclose(fus_l, ref_l, rtol=5e-3, atol=5e-4)
-    assert fus_l[-1] < fus_l[0]
+    ref_l, pal_l = (np.asarray(losses[b]) for b in ("ref", "pallas"))
+    assert np.isfinite(ref_l).all() and np.isfinite(pal_l).all()
+    np.testing.assert_allclose(pal_l, ref_l, rtol=5e-3, atol=5e-4)
+    assert pal_l[-1] < pal_l[0]
 
 
 # -------------------------------------------------- determinism + golden
-def test_threaded_replay_bitwise_fused(sparse_data):
-    """The PR-4 record-and-replay contract holds under backend='fused':
+def test_threaded_replay_bitwise_pallas(sparse_data):
+    """The record-and-replay contract holds under backend='pallas':
     threaded record -> deterministic replay, bit for bit."""
     cfg = SGBDTConfig(
         n_trees=10, step_length=0.3, sampling_rate=0.8,
-        learner=LearnerConfig(depth=3, n_bins=64, backend="fused"),
+        learner=LearnerConfig(depth=3, n_bins=64, backend="pallas"),
     )
     rt = AsyncRuntime(cfg, sparse_data, n_workers=3)
     state, trace = rt.run(seed=1)
@@ -309,11 +289,11 @@ def test_threaded_replay_bitwise_fused(sparse_data):
         )
 
 
-def test_golden_trace_replays_under_fused():
-    """The committed PR-5 golden trace replays to the committed forest with
-    backend='fused': structure exact, leaves atol 1e-6. The corpus was
-    recorded on the ref backend, so this pins fused-vs-ref drift through a
-    full threaded schedule, not just one tree."""
+def test_golden_trace_replays_under_pallas():
+    """The committed golden trace replays to the committed forest with
+    backend='pallas': structure exact, leaves atol 1e-6. The corpus was
+    recorded on the ref backend, so this pins pallas-vs-ref drift through
+    a full threaded schedule, not just one tree."""
     import importlib.util
     import pathlib
 
@@ -322,21 +302,21 @@ def test_golden_trace_replays_under_fused():
 
     golden = pathlib.Path(__file__).resolve().parent / "golden"
     spec = importlib.util.spec_from_file_location(
-        "golden_regen_fused", golden / "regen.py"
+        "golden_regen_pallas", golden / "regen.py"
     )
     regen = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(regen)
 
     cfg, data = regen.golden_config(), regen.golden_data()
-    fused_cfg = cfg._replace(
-        learner=cfg.learner._replace(backend="fused")
+    pallas_cfg = cfg._replace(
+        learner=cfg.learner._replace(backend="pallas")
     )
     like = init_state(cfg, data)
     forest = checkpoint.restore_pytree(
         golden / "ckpt", regen.GOLDEN_STEP, like, check_crc=True
     ).forest
     trace = RunTrace.load(golden / "run_trace.json")
-    state, _ = replay_trace(fused_cfg, data, trace)
+    state, _ = replay_trace(pallas_cfg, data, trace)
     np.testing.assert_array_equal(
         np.asarray(state.forest.feature), np.asarray(forest.feature)
     )
@@ -347,44 +327,3 @@ def test_golden_trace_replays_under_fused():
         np.asarray(state.forest.leaf_value), np.asarray(forest.leaf_value),
         rtol=0, atol=1e-6,
     )
-
-
-# ------------------------------------------------------- VMEM budget gate
-def test_vmem_model_monotone_and_gate():
-    """The budget model grows with every geometry axis, and the fits gate
-    admits small levels while rejecting ones whose resident set cannot fit
-    (the staged fallback then runs those levels)."""
-    base = fused_level_vmem_bytes(8, 8, 64, 64, 512, 8)
-    assert fused_level_vmem_bytes(16, 16, 64, 64, 512, 8) > base
-    assert fused_level_vmem_bytes(8, 8, 128, 64, 512, 8) > base
-    assert fused_level_vmem_bytes(8, 8, 64, 128, 512, 8) > base
-    assert fused_level_vmem_bytes(8, 8, 64, 64, 1024, 8) > base
-    assert fused_level_fits(4096, 8, 8, 64, 64)
-    # 64 nodes x 800 features x 64 bins: ~100 MiB resident, far over budget.
-    assert not fused_level_fits(2000, 64, 64, 800, 64)
-    assert fused_level_fits(
-        2000, 64, 64, 800, 64, budget=64 * FUSED_VMEM_BUDGET
-    )
-
-
-def test_budget_fallback_is_seamless(key):
-    """A tree whose deep levels exceed the budget (F=96 pushes level >= 4
-    past a deliberately tiny budget... checked via the public model) still
-    builds, and fused == pallas stays bitwise across the switch."""
-    n, f, n_bins = 600, 96, 32
-    bins = jax.random.randint(key, (n, f), 0, n_bins, dtype=jnp.int32)
-    g = jax.random.normal(jax.random.fold_in(key, 1), (n,))
-    h = (jax.random.uniform(jax.random.fold_in(key, 2), (n,)) < 0.8).astype(
-        jnp.float32
-    )
-    # At this width the depth-5 tree's last levels are near the real
-    # budget's edge; whichever side they land on, parity must hold.
-    cfg_f = LearnerConfig(depth=5, n_bins=n_bins, feature_fraction=1.0,
-                          backend="fused")
-    cfg_p = cfg_f._replace(backend="pallas")
-    t_f = build_tree(cfg_f, bins, g, h, key)
-    t_p = build_tree(cfg_p, bins, g, h, key)
-    for name in ("feature", "threshold", "leaf_value"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(t_f, name)), np.asarray(getattr(t_p, name))
-        )

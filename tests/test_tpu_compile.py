@@ -21,7 +21,6 @@ from repro.kernels import autotune
 from repro.kernels.forest_traversal import forest_traverse_pallas
 from repro.kernels.histogram import histogram_pallas
 from repro.kernels.histogram_sparse import histogram_sparse_pallas
-from repro.kernels.level_build import level_build_pallas
 from repro.kernels.split_scan import split_gain_pallas
 from repro.trees.learner import LearnerConfig, build_tree
 
@@ -164,27 +163,6 @@ def test_split_scan_fits_half_vmem(one_chip, monkeypatch, l, f):
         jax.clear_caches()
 
 
-@pytest.mark.parametrize("derive_sibling", [False, True], ids=["rebuild", "subtract"])
-def test_level_build_compiles(one_chip, derive_sibling):
-    f_pad, fb, sb = _tiling()
-    l_sub = L // 2 if derive_sibling else L
-    args = _shapes(
-        one_chip, ((N, f_pad), jnp.int32), ((N,), jnp.int32), ((N,), jnp.float32),
-        ((N,), jnp.float32), ((l_sub,), jnp.int32), ((2, l_sub, f_pad, B), jnp.float32),
-        ((f_pad,), jnp.float32),
-    )
-
-    def fn(bins, node, g, h, active, parent, mask):
-        return level_build_pallas(
-            bins, node, g, h, active, parent if derive_sibling else None, mask,
-            1.0, 1e-3, L, B, derive_sibling=derive_sibling, sample_block=sb,
-            feature_block=fb, interpret=False,
-        )
-
-    assert _kernels(fn, args) >= 1
-    assert BF16_DOT in _kernel_dots(fn, args)  # the histogram's, as staged
-
-
 def test_histogram_sparse_compiles(one_chip):
     args = _shapes(
         one_chip, ((F, ENTRIES), jnp.int32), ((F, ENTRIES), jnp.int32),
@@ -226,9 +204,8 @@ def test_forest_traversal_compiles(one_chip, leaves):
     assert _kernels(fn, args) >= 1
 
 
-@pytest.mark.parametrize("backend", ["pallas", "fused"])
 @pytest.mark.parametrize("hist_mode", ["subtract", "rebuild"])
-def test_build_tree_has_no_scatter(one_chip, monkeypatch, hist_mode, backend):
+def test_build_tree_has_no_scatter(one_chip, monkeypatch, hist_mode):
     """The whole tree build for the chip: its per-node row sums (child
     counts, leaf sums) are reductions, not scatters. A TPU runs a
     scatter-add of N rows into a few node slots almost serially."""
@@ -238,7 +215,7 @@ def test_build_tree_has_no_scatter(one_chip, monkeypatch, hist_mode, backend):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     jax.clear_caches()
     cfg = LearnerConfig(depth=DEPTH, n_bins=B, feature_fraction=0.8,
-                        backend=backend, hist_mode=hist_mode)
+                        backend="pallas", hist_mode=hist_mode)
     args = _shapes(
         one_chip, ((N, F), jnp.int32), ((N,), jnp.float32), ((N,), jnp.float32),
         ((2,), jnp.uint32),
