@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sgbdt import SGBDTConfig, TrainState, init_state
-from repro.data.sampling import bernoulli_weights
+from repro.data.sampling import DrawPlan, bernoulli_weights, count_draw, draw_plan
 from repro.ps.schedules import max_staleness, resolve_schedule
 from repro.trees.binning import BinnedData, SparseBins
 from repro.trees.forest import forest_push
@@ -61,6 +61,7 @@ def propose_tree(
     f_target: jax.Array,
     rng: jax.Array,
     builder: TreeBuilder | None = None,
+    plan: DrawPlan | jax.Array | None = None,
 ) -> tuple[Tree, jax.Array]:
     """Worker side: sample Q -> build target from F^{k(j)} -> fit tree(s).
 
@@ -78,13 +79,17 @@ def propose_tree(
     others (the fused scan body), which would break the threaded runtime's
     record-and-replay contract. ``round(v*leaf)[idx] == round(v*leaf[idx])``
     elementwise, so the trained values are unchanged.
+
+    ``plan`` is ``sampling.draw_plan(data.multiplicity, rate)``: the same
+    Q with less work where a plan applies. None draws from the multiplicity.
     """
     obj = cfg.obj
     # Named scopes label the device operations of each step in the
     # compiled program's metadata; they change no value.
     with jax.named_scope("sample"):
         r_sample, r_feat = jax.random.split(rng)
-        m_prime, _ = bernoulli_weights(r_sample, cfg.sampling_rate, data.multiplicity)
+        m_prime, _ = bernoulli_weights(
+            r_sample, cfg.sampling_rate, data.multiplicity if plan is None else plan)
     v = jnp.float32(cfg.step_length)
     if obj.n_outputs == 1:
         with jax.named_scope("gradient"):
@@ -172,7 +177,7 @@ def scale_push(cfg, data, tree, scale):
 
 
 def round_body(cfg, data, forest, f_live, f_target, rng, builder=None,
-               staleness=None):
+               staleness=None, plan=None):
     """One boosting round. Splitting ``f_target`` from ``f_live`` is what
     makes this body shared between every trainer: the tree is built against
     (possibly stale) ``f_target`` but folded into the live server state.
@@ -187,7 +192,7 @@ def round_body(cfg, data, forest, f_live, f_target, rng, builder=None,
     deflation lives on the server side of the barrier, exactly where the
     threaded runtime's fold program applies it.
     """
-    tree, delta = propose_tree(cfg, data, f_target, rng, builder)
+    tree, delta = propose_tree(cfg, data, f_target, rng, builder, plan)
     tree, delta = jax.lax.optimization_barrier((tree, delta))
     if cfg.adaptive_step and staleness is not None:
         scale = staleness_scale(cfg.adaptive_step, staleness)
@@ -265,6 +270,13 @@ class Trainer:
             qid=None if data.qid is None else put(data.qid, specs.labels),
         )
 
+    def draw_plan(self, data: BinnedData) -> DrawPlan | jax.Array:
+        """What ``data``'s rounds draw from (``sampling.draw_plan``): on a
+        mesh the rows are sharded, and each round draws in full."""
+        if self.mesh is not None:
+            return data.multiplicity
+        return draw_plan(data.multiplicity, self.cfg.sampling_rate)
+
     def collective_bytes(self, data: BinnedData) -> dict | None:
         """MEASURED per-tree-build collective bytes on this trainer's mesh
         (trace-time accounting; see ``ps.sharded.collective_bytes_per_build``).
@@ -285,13 +297,13 @@ class Trainer:
     def _step(self, ring_size: int):
         cfg, builder = self.cfg, self.builder
 
-        def step(data, carry, xs):
+        def step(data, plan, carry, xs):
             forest, f, ring = carry
             j, k_j, rng = xs
             f_target = ring[k_j % ring_size]
             staleness = (j - k_j) if cfg.adaptive_step else None
             forest, f = round_body(
-                cfg, data, forest, f, f_target, rng, builder, staleness
+                cfg, data, forest, f, f_target, rng, builder, staleness, plan
             )
             ring = jax.lax.dynamic_update_index_in_dim(
                 ring, f, (j + 1) % ring_size, 0
@@ -318,6 +330,7 @@ class Trainer:
     ) -> TrainState:
         """Python-loop execution with per-round eval hooks."""
         data = self.place(data)
+        plan = self.draw_plan(data)
         sched, ring_size, keys, state, ring = self._prep(data, schedule, seed)
         if ring_size not in self._loop_cache:
             self._loop_cache[ring_size] = jax.jit(self._step(ring_size))
@@ -325,8 +338,10 @@ class Trainer:
         forest, f = state.forest, state.f
         carry = (forest, f, ring)
         for j in range(self.cfg.n_trees):
+            count_draw(keys[j], self.cfg.sampling_rate, plan)
             carry = step(
                 data,
+                plan,
                 carry,
                 (
                     jnp.asarray(j, jnp.int32),
@@ -355,13 +370,14 @@ class Trainer:
         returns per-round train losses too. The program the dry-run lowers."""
         cfg = self.cfg
         data = self.place(data)
+        plan = self.draw_plan(data)
         if ring_size not in self._scan_cache:
             step = self._step(ring_size)
 
             @jax.jit
-            def run(data, schedule, rngs):
+            def run(data, plan, schedule, rngs):
                 def body(carry, xs):
-                    carry = step(data, carry, xs)
+                    carry = step(data, plan, carry, xs)
                     loss = cfg.obj.loss(
                         data.labels, carry[1], data.multiplicity, qid=data.qid
                     )
@@ -384,7 +400,8 @@ class Trainer:
                 )
 
             self._scan_cache[ring_size] = run
-        return self._scan_cache[ring_size](data, jnp.asarray(schedule), rngs)
+        count_draw(rngs[0], cfg.sampling_rate, plan, len(rngs))
+        return self._scan_cache[ring_size](data, plan, jnp.asarray(schedule), rngs)
 
     def train_scan(
         self, data: BinnedData, schedule=("round_robin", 1), seed: int = 0

@@ -78,7 +78,7 @@ import numpy as np
 from repro import obs
 from repro.checkpoint import store as ckpt_store
 from repro.core.sgbdt import SGBDTConfig, TrainState, init_state
-from repro.data.sampling import bernoulli_weights
+from repro.data.sampling import DrawPlan, bernoulli_weights, count_draw, draw_plan
 from repro.ps.engine import (
     Trainer,
     propose_tree,
@@ -416,7 +416,8 @@ class _LeafTableShards:
     objectives (squared error) are bitwise-equal up to zero signs.
     """
 
-    def __init__(self, cfg: SGBDTConfig, data: BinnedData, n_parts: int):
+    def __init__(self, cfg: SGBDTConfig, data: BinnedData, n_parts: int,
+                 plan: DrawPlan | jax.Array):
         n = data.n_samples
         if not 1 <= n_parts <= n:
             raise ValueError(
@@ -436,14 +437,14 @@ class _LeafTableShards:
         k_out = cfg.obj.n_outputs
         part_ids = jnp.asarray(self.part_ids)
         part_sizes = jnp.asarray(sizes, jnp.int32)
+        self._rate = cfg.sampling_rate
+        self._plan = plan
 
-        def pull(f, rng):
+        def pull(f, rng, plan):
             # The SAME split propose_tree does: the sample mask is a pure
             # function of the ticket key, so worker and replay agree on Q.
             r_sample, _ = jax.random.split(rng)
-            _, q_any = bernoulli_weights(
-                r_sample, cfg.sampling_rate, data.multiplicity
-            )
+            _, q_any = bernoulli_weights(r_sample, cfg.sampling_rate, plan)
             touched = (
                 jnp.zeros(n_parts, jnp.int32)
                 .at[part_ids]
@@ -458,8 +459,30 @@ class _LeafTableShards:
         self._pull = jax.jit(pull)
 
     def pull(self, f, rng) -> tuple[jax.Array, int]:
-        f_masked, nbytes = self._pull(f, rng)
+        count_draw(rng, self._rate, self._plan)
+        f_masked, nbytes = self._pull(f, rng, self._plan)
         return f_masked, int(nbytes)
+
+
+class _ProposeProgram:
+    """The worker's ``jit_propose`` with the runtime's draw plan bound:
+    called and lowered as ``(data, f_target, rng)`` on the runtime's data,
+    and each call counts its draw (``sampling.count_draw``)."""
+
+    def __init__(self, cfg: SGBDTConfig, plan: DrawPlan | jax.Array):
+        def propose(data, f_target, rng, plan):
+            return propose_tree(cfg, data, f_target, rng, plan=plan)
+
+        self._jit = jax.jit(propose)
+        self._rate = cfg.sampling_rate
+        self._plan = plan
+
+    def __call__(self, data, f_target, rng):
+        count_draw(rng, self._rate, self._plan)
+        return self._jit(data, f_target, rng, self._plan)
+
+    def lower(self, data, f_target, rng):
+        return self._jit.lower(data, f_target, rng, self._plan)
 
 
 class AsyncRuntime:
@@ -492,8 +515,11 @@ class AsyncRuntime:
         self.faults = faults if faults is not None else FaultPlan()
         if any(j > cfg.n_trees for j in self.faults.join_at.values()):
             raise ValueError("join_at fold count beyond the end of the run")
+        # Made once from the data: every draw of the run loops only over
+        # the rows that need it (``sampling.draw_plan``).
+        self.plan = draw_plan(data.multiplicity, cfg.sampling_rate)
         self.shards = (
-            _LeafTableShards(cfg, data, shard_pulls) if shard_pulls else None
+            _LeafTableShards(cfg, data, shard_pulls, self.plan) if shard_pulls else None
         )
         self.full_pull_bytes = 4 * cfg.obj.n_outputs * data.n_samples
         if worker_delay is None:
@@ -509,10 +535,7 @@ class AsyncRuntime:
         # happens exactly where the physical program boundary sits.
         # Named functions, so the device trace names their programs
         # (``jit_propose``, ``jit_fold``).
-        def propose(data, f_target, rng):
-            return propose_tree(cfg, data, f_target, rng)
-
-        self._propose = jax.jit(propose)
+        self._propose = _ProposeProgram(cfg, self.plan)
         if cfg.adaptive_step:
 
             def fold(forest, f, tree, delta, stale):

@@ -12,6 +12,9 @@ shapes off the (8, 128) tiling, primitives Mosaic cannot lower, VMEM
 overflow) without a chip. The topology is described inside a fixture, so
 only the test process that runs this file loads the TPU compiler.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -254,3 +257,42 @@ def test_sparse_build_tree_depth7_has_no_scatter(one_chip, monkeypatch):
         jax.clear_caches()
     assert text.count("tpu_custom_call") >= 2  # histogram_sparse, split_scan
     assert "scatter(" not in text
+
+
+def _indexed_rows(text: str) -> dict[str, int]:
+    """Index rows of each gather (output ÷ slice) and updates of each
+    scatter in compiled HLO text."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+    size = lambda dims: math.prod(int(d) for d in dims.split(",") if d)  # noqa: E731
+    rows = {}
+    for name, dims, op, args in re.findall(
+            r"(%[\w.\-]+) = \w+\[([\d,]*)\]\S* (gather|scatter)\(([^)]*)\)", text):
+        if op == "gather":
+            line = text[text.index(name + " = "):].split("\n", 1)[0]
+            slice_sizes = re.search(r"slice_sizes=\{([\d,]*)\}", line).group(1)
+            rows[name] = size(dims) // size(slice_sizes)
+        else:
+            rows[name] = size(shapes[args.split(",")[-1].strip()])
+    return rows
+
+
+def test_planned_draw_has_no_full_row_scatter(one_chip):
+    """The planned draw at HIGGS's 11,000,000 rows and the bench's Zipf
+    profile, compiled for the chip: no gather or scatter indexes more rows
+    than the plan's largest set. A compaction inside the program (a prefix
+    sum and a scatter of all N rows) would."""
+    from bench.data import zipf_multiplicity
+    from repro.data.sampling import DrawPlan, bernoulli_weights, draw_plan
+
+    n = 11_000_000
+    plan = draw_plan(zipf_multiplicity(n, n), 0.8)
+    sets = (plan.inversion_rows.shape[0], plan.btrs_rows.shape[0])
+    abstract = DrawPlan(*_shapes(one_chip, ((n,), jnp.float32), ((sets[0],), jnp.int32),
+                                 ((sets[1],), jnp.int32)), rate=0.8)
+    key = _shapes(one_chip, ((2,), jnp.uint32))[0]
+    text = jax.jit(lambda k, p: bernoulli_weights(k, 0.8, p)).lower(
+        key, abstract).compile().as_text()
+    rows = _indexed_rows(text)
+    assert sets == (423_779, 12_972)
+    assert sorted(v for k, v in rows.items() if "scatter" in k) == sorted(sets)
+    assert max(rows.values()) <= max(sets), rows
